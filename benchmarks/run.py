@@ -141,6 +141,8 @@ def main(argv=None) -> None:
                          "reported, one warmup excluded); threaded into "
                          "the tables that accept it")
     args = ap.parse_args(argv)
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     wanted = ([f.strip().lower() for f in args.tables.split(",") if f.strip()]
               if args.tables else None)
 

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.api import Session
+from repro.runtime import enable_compile_cache
 from repro.checkpoint import AsyncCheckpointer, latest_step, load_checkpoint
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLMData
@@ -25,6 +26,7 @@ def main() -> None:
     ap.add_argument("--fail-at", type=int, nargs="*", default=[7, 15])
     ap.add_argument("--ckpt-dir", default="/tmp/cello_elastic_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("granite-3-8b").reduced()
     plan = Session(cfg).default_plan(seq=32).plan

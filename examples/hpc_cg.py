@@ -17,6 +17,7 @@ import argparse
 import numpy as np
 
 from repro.api import Session
+from repro.runtime import enable_compile_cache
 from repro.frontends import evaluate, make_feeds
 
 
@@ -31,6 +32,7 @@ def main() -> None:
                     help="any registered workload that takes n/iters "
                          "(cg, bicgstab, power_iteration)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     sess = Session()                    # arch-less: frontend traces only
     traced = sess.trace(workload=args.workload, n=args.n, iters=args.iters)

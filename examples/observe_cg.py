@@ -23,6 +23,7 @@ import sys
 import tempfile
 
 from repro import obs
+from repro.runtime import enable_compile_cache
 from repro.api import Session
 
 
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--jsonl", default=None, metavar="PATH",
                     help="also write the JSONL span export to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
     trace_path = args.trace or str(pathlib.Path(tempfile.gettempdir())
                                    / "cello.trace.json")
 

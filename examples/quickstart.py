@@ -8,6 +8,7 @@ staged Session API and lower the result to an execution plan.
 import argparse
 
 from repro.api import CodesignConfig, Session
+from repro.runtime import enable_compile_cache
 from repro.configs import list_archs
 from repro.core.buffer import MiB
 
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--no-cache", action="store_true",
                     help="force a fresh search (skip the disk cache)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     sess = Session(args.arch, capacity_bytes=args.capacity_mib * MiB,
                    use_cache=not args.no_cache)

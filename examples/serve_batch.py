@@ -9,6 +9,7 @@ import time
 import jax
 
 from repro.api import Session
+from repro.runtime import enable_compile_cache
 from repro.configs import get_config, list_archs
 from repro.launch.serve import ServeStats
 from repro.models import init_params
@@ -21,6 +22,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()        # CPU-scale weights
     if cfg.encoder_only:

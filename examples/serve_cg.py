@@ -17,6 +17,7 @@ import argparse
 import numpy as np
 
 from repro.serve import ServeConfig, Server, request
+from repro.runtime import enable_compile_cache
 
 
 def main() -> None:
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--backend", default="reference",
                     help="execution backend (reference | pallas)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # autostart=False + submit-all + start(): every request is queued
     # before the first batch closes, so coalescing is deterministic —

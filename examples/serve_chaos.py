@@ -31,6 +31,7 @@ import time
 from repro.serve import (Overloaded, RetryPolicy, ServeConfig, Server,
                          WorkerCrashed,
                          request)
+from repro.runtime import enable_compile_cache
 from repro.testing import faults
 
 
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=8,
                     help="requests per incident")
     args = ap.parse_args()
+    enable_compile_cache()
 
     srv = Server(config=ServeConfig(
         max_batch_size=4, max_wait_us=500.0,

@@ -12,6 +12,7 @@ import dataclasses
 import jax
 
 from repro.api import Session
+from repro.runtime import enable_compile_cache
 from repro.checkpoint import AsyncCheckpointer
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLMData
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--ckpt-dir", default="/tmp/cello_train_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     L, D, H, KV, F, V, B, S = PRESETS[args.preset]
     cfg = dataclasses.replace(

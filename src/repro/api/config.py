@@ -6,7 +6,7 @@ Three frozen dataclasses consolidate the keyword sprawl that grew on
 
 * :class:`CodesignConfig` — the schedule × buffer search knobs.
 * :class:`ExecConfig` — lowering/execution: backend, device mesh,
-  buffer donation, pallas interpret mode.
+  buffer donation.
 * :class:`ServeConfig` — batching, admission control, and resilience
   (retry / fallback / circuit breaker) for :class:`repro.serve.Server`.
 
@@ -17,14 +17,14 @@ passing *both* a config and legacy kwargs is a :class:`TypeError`
 (there is no sensible merge order).  ``docs/api_migration.md`` maps
 every old name to its new field.
 
-``ExecConfig.interpret`` / ``ExecConfig.donate`` deserve a note: the
-pallas executor reads the process-level toggles
-``CELLO_PALLAS_INTERPRET`` / ``CELLO_PALLAS_DONATE`` when it builds a
-program, so these two fields *pin the process-level toggle* when set
-(a programmatic spelling of the env var, applied at ``lower()`` /
-``run()`` time) rather than acting per-plan.  ``donate`` additionally
-flows per-plan into ``CompiledPlan.batched``, which already threads an
-explicit donation flag.
+``ExecConfig.donate`` deserves a note: the pallas executor reads the
+process-level toggle ``CELLO_PALLAS_DONATE`` when it builds a program, so
+the field *pins the process-level toggle* when set (a programmatic
+spelling of the env var, applied at ``lower()`` / ``run()`` time) rather
+than acting per-plan; it additionally flows per-plan into
+``CompiledPlan.batched``, which already threads an explicit donation
+flag.  Whether kernels compile through Mosaic or run interpreted is not
+a knob: it follows the platform (``repro.exec.pallas.use_interpret``).
 """
 from __future__ import annotations
 
@@ -42,9 +42,9 @@ __all__ = [
 
 
 class _Unset:
-    """Sentinel for 'keyword not passed' (``None`` is meaningful for
-    several legacy defaults, e.g. ``Server(fallback=None)`` disables
-    fallback while omitting it means ``"reference"``)."""
+    """Sentinel for 'keyword not passed' (``None`` is a meaningful value
+    for several legacy keywords, e.g. ``Server(breaker_failures=None)``
+    disables the breaker while omitting it keeps the default)."""
 
     _inst = None
 
@@ -90,22 +90,17 @@ class ExecConfig:
     ``backend`` — any name registered in ``repro.exec`` (None keeps
     the surface's default).  ``mesh`` — shard count ``K`` or
     ``(axis_name, K)``; partitions the co-designed DAG across the
-    first ``K`` devices (see ``docs/distributed.md``).  ``donate`` /
-    ``interpret`` — pin the ``CELLO_PALLAS_DONATE`` /
-    ``CELLO_PALLAS_INTERPRET`` process toggles when not None (see the
-    module docstring; donation is additionally honoured per-plan by
+    first ``K`` devices (see ``docs/distributed.md``).  ``donate`` —
+    pins the ``CELLO_PALLAS_DONATE`` process toggle when not None (see
+    the module docstring; donation is additionally honoured per-plan by
     ``batched``).
     """
     backend: Optional[str] = None
     mesh: Optional[Union[int, Tuple[str, int]]] = None
     donate: Optional[bool] = None
-    interpret: Optional[bool] = None
 
     def apply_toggles(self) -> None:
-        """Pin the process-level pallas toggles this config sets."""
-        if self.interpret is not None:
-            os.environ["CELLO_PALLAS_INTERPRET"] = \
-                "1" if self.interpret else "0"
+        """Pin the process-level pallas toggle this config sets."""
         if self.donate is not None:
             os.environ["CELLO_PALLAS_DONATE"] = \
                 "1" if self.donate else "0"
@@ -117,7 +112,9 @@ class ServeConfig:
     :class:`repro.serve.Server`.
 
     ``retry`` takes a :class:`repro.serve.RetryPolicy`;
-    ``fallback=None`` disables backend fallback;
+    ``fallback`` names a backend that answers when the primary keeps
+    failing (``"reference"``: the op-by-op oracle; ``None``, the
+    default, serves no answer the caller did not ask for);
     ``breaker_failures=None`` disables the circuit breaker.
     """
     max_batch_size: int = 16
@@ -128,7 +125,7 @@ class ServeConfig:
     max_queue: Optional[int] = None
     overload: str = "block"
     retry: Optional[Any] = None
-    fallback: Optional[str] = "reference"
+    fallback: Optional[str] = None
     breaker_failures: Optional[int] = 3
     breaker_reset_s: float = 30.0
     max_worker_restarts: int = 2

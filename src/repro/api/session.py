@@ -376,7 +376,7 @@ class Session:
         its own row block) and execute via ``shard_map`` on the pallas
         backend or a bitwise simulated mesh on the reference backend —
         see ``docs/distributed.md``.  An :class:`ExecConfig` consolidates
-        these (plus the pallas donation/interpret toggles).
+        these (plus the pallas donation toggle).
         """
         if config is not None:
             if backend is not None or mesh is not None:
@@ -439,8 +439,7 @@ class Session:
         sched = designed.result.best.schedule
         partial = dict(getattr(sched.pins, "partial", None) or {})
         kernels = select_group_kernels(traced.graph, sched.groups,
-                                       sched.config.explicit_bytes,
-                                       partial=partial)
+                                       sched.config.explicit_bytes)
         # density-aware pin outcome: a CSR operand pins as one unit when
         # its nnz footprint fits, or as an overbooked row prefix — surface
         # the decision in explain()
@@ -461,7 +460,7 @@ class Session:
         # consumed by the single-program pallas executable
         exec_plan = plan_execution(traced.graph, kernels,
                                    sched.config.explicit_bytes,
-                                   program=traced.program, partial=partial)
+                                   program=traced.program)
         sharded = None
         if mesh is not None:
             # K=1 still goes through partition_plan so the degenerate

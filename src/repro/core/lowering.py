@@ -24,6 +24,7 @@ Conventions:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Dict, List, Optional, Tuple
 
@@ -273,26 +274,31 @@ def decode_graph(cfg: ArchConfig, batch: int, kv_len: int) -> OpGraph:
 #                the pass's shared streamed length; contraction right-hand
 #                sides stay resident in VMEM across every tile (constant
 #                index map), rank-0 dot/norm reductions accumulate across
-#                grid steps, and scalar epilogues run once on the final
-#                tile.  A group usually lowers to ONE pass; it splits into
-#                sequential passes exactly where a contraction reads a
-#                vector produced earlier in the same group (the value must
-#                fully materialize before it can be a resident operand).
-#   ``spmv-stream`` — a stream group whose passes include CSR SpMV ops:
-#                the same 1-D row-tile grid, but the sparse operand's
-#                indptr/indices/data triple AND the gathered x stay
-#                resident in VMEM across every tile (rows are ragged and
-#                column access is data-dependent); the output vector
-#                streams row tiles.  With an overbooked (partial) pin the
-#                residency is *fractional*: a :class:`ResidentSlice`
-#                records the indptr-aligned row prefix held resident
-#                while tail tiles stream their CSR slices per grid step.
+#                grid steps into SMEM, and scalar epilogues run after the
+#                pass.  A group usually lowers to ONE pass; it splits into
+#                sequential passes exactly where a contraction (or an
+#                spmv) reads a vector produced earlier in the same group
+#                (the value must fully materialize first).  A CSR spmv in
+#                a pass streams its entries in a padded per-tile layout
+#                (tile ``t`` owns exactly its own rows' entries, padded to
+#                ``B`` slots — static, from the operand's pattern meta):
+#                XLA gathers ``data * x[indices]`` into that layout and
+#                the kernel sums each tile's rows as one MXU product with
+#                a one-hot row matrix (Mosaic cannot gather in-kernel).
 #   ``block``  — one `pl.pallas_call` with whole arrays as single blocks:
 #                stencil sweeps need halo rows, so they cannot row-stream
 #                without overlap; the explicit region holds the full grid.
-#   ``jnp``    — jitted jax.numpy fallback for shapes the streamer cannot
-#                express (irregular gathers, scans, >2-operand einsums,
-#                mixed streamed lengths); ``reason`` records why.
+#   ``jnp``    — jax.numpy units inlined into the program for shapes no
+#                Mosaic kernel expresses (irregular gathers, scans,
+#                >2-operand einsums, mixed streamed lengths, CSR operands
+#                without pattern meta, working sets over one kernel's
+#                VMEM); ``reason`` records why, and ``explain()`` prints
+#                it.
+#
+# Tiles are planned for Mosaic (the TPU kernel compiler): rank-1 vectors
+# enter kernels as ``(1, n)`` rows, so a row tile is a multiple of 128
+# lanes unless one tile covers the whole pass, and every kernel's
+# double-buffered working set fits one kernel's VMEM budget.
 
 #: einsum specs the tile-streamer lowers: LHS streams row tiles, RHS stays
 #: resident (spec -> index of the resident operand)
@@ -300,26 +306,40 @@ STREAM_EINSUMS = {"ab,b->a": 1, "ab,bc->ac": 1}
 #: rank-0 contraction of two streamed vectors (rank-1 @ rank-1)
 REDUCE_EINSUMS = ("a,a->",)
 
-_TILE_ROW_CANDIDATES = (1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+#: lane-aligned row tiles, largest first
+_TILE_ROW_CANDIDATES = (1024, 512, 256, 128)
+_LANE, _SUBLANE = 128, 8
+#: element width kernels are planned for: TPU kernels run fp32 (an fp64
+#: program is refused before it reaches Mosaic — see ``repro.exec.pallas``)
+KERNEL_ITEMSIZE = 4
+#: VMEM one kernel may plan for.  A TPU v5e core has 128 MiB of VMEM and a
+#: 16 MiB default scoped limit; the executor raises the limit to the
+#: planned bytes plus headroom (``repro.exec.pallas._vmem_limit``).
+KERNEL_VMEM_BYTES = 32 << 20
+#: the least a kernel plans for, whatever the explicit region: streaming
+#: double buffers are not pins, and an all-implicit split still streams
+#: (4 MiB holds a 256-row spmv tile of a 5-point operand)
+KERNEL_VMEM_FLOOR = 4 << 20
+
+
+def kernel_block_bytes(shape) -> int:
+    """VMEM bytes of one buffer holding a block of ``shape``, padded to the
+    (8, 128) tiling (rank-0 values live in SMEM, rank-1 as ``(1, n)``)."""
+    if not shape:
+        return 0
+    dims = (1,) + tuple(shape) if len(shape) == 1 else tuple(shape)
+    lead = math.prod(dims[:-2])
+    sub = -(-dims[-2] // _SUBLANE) * _SUBLANE
+    lane = -(-dims[-1] // _LANE) * _LANE
+    return lead * sub * lane * KERNEL_ITEMSIZE
 
 
 @dataclasses.dataclass(frozen=True)
 class ResidentSlice:
-    """A contiguous, indptr-aligned row window of one operand (or of the
-    whole pass) held by a single residency domain.
-
-    Two producers, one record:
-
-    * **Overbooked pins** (``row0 == 0``): rows ``[0, rows)`` of the CSR
-      operand (the ``entries`` first indices/data entries) are held in
-      VMEM across every tile; the remaining ``total_rows - rows`` rows
-      stream their CSR slices through the grid per step.  Produced from
-      an overbooked pin's :class:`~repro.core.schedule.PartialPin`
-      records.
-    * **Mesh shards** (``row0 = k * rows``): shard ``k`` of a partitioned
-      plan owns rows ``[row0, row0 + rows)`` of the global problem — the
-      ``entries`` CSR entries starting at ``entry0``.  Produced by
-      :func:`partition_plan`."""
+    """A contiguous, indptr-aligned row window of a mesh-partitioned plan:
+    shard ``k`` owns rows ``[row0, row0 + rows)`` of the global problem —
+    the ``entries`` CSR entries starting at ``entry0`` (produced by
+    :func:`partition_plan`)."""
     tensors: Tuple[str, ...]        # the triple members covered (in order)
     rows: int                       # rows in this window (indptr-aligned)
     total_rows: int
@@ -333,9 +353,7 @@ class ResidentSlice:
         return self.rows / max(1, self.total_rows)
 
     def describe(self) -> str:
-        if self.row0:
-            return f"rows[{self.row0}:{self.row0 + self.rows}]"
-        return f"prefix({self.rows}/{self.total_rows}r)"
+        return f"rows[{self.row0}:{self.row0 + self.rows}]"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -346,9 +364,8 @@ class StreamPass:
     tile_rows: int                  # rows per grid step (divides ``rows``)
     resident: Tuple[str, ...]       # operands held in VMEM across all tiles
     reductions: Tuple[str, ...]     # rank-0 accumulators in this pass
-    # fractional residency of spmv operands (overbooked pins): members of
-    # ``resident`` named here hold only their row prefix in VMEM
-    slices: Tuple[ResidentSlice, ...] = ()
+    vmem_bytes: int = 0             # planned double-buffered working set
+    spmv: Tuple[str, ...] = ()      # CSR spmv ops (per-tile entry layout)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -360,17 +377,17 @@ class GroupKernel:
     reason: str = ""                # why a jnp fallback was selected
 
     def describe(self) -> str:
-        if self.kind in ("stream", "spmv-stream"):
+        if self.kind == "stream":
             bits = []
             for p in self.passes:
                 res = f" res={'+'.join(p.resident)}" if p.resident else ""
                 red = f" acc={'+'.join(p.reductions)}" if p.reductions \
                     else ""
-                part = "".join(f" {sl.describe()}" for sl in p.slices)
-                bits.append(f"{p.rows}r/{p.tile_rows}t{res}{red}{part}")
+                sp = f" spmv={'+'.join(p.spmv)}" if p.spmv else ""
+                bits.append(f"{p.rows}r/{p.tile_rows}t{res}{red}{sp}")
             tag = " | ".join(bits)
             n = len(self.passes)
-            label = ("pallas-spmv" if self.kind == "spmv-stream"
+            label = ("pallas-spmv" if any(p.spmv for p in self.passes)
                      else "pallas-stream")
             return (f"{label}[{tag}]" if n == 1
                     else f"{label}[{n} passes: {tag}]")
@@ -380,37 +397,104 @@ class GroupKernel:
 
 
 def _pick_tile_rows(rows: int, per_row_bytes: int, resident_bytes: int,
-                    explicit_bytes: int) -> int:
-    """Largest row tile (a divisor of ``rows``) whose streaming working set
-    fits the explicit region.  The co-design's own fusion-legality check
-    (`schedule.fusable`) guaranteed *some* tile fits; when the resident
-    operands already cover (or exceed) the budget, we still stream — at
-    the finest granularity, never a zero/negative tile."""
-    budget = max(explicit_bytes - resident_bytes, 0)
-    for t in _TILE_ROW_CANDIDATES:
-        if t <= rows and rows % t == 0 and t * per_row_bytes <= budget:
+                    budget: int, extra=None) -> Optional[int]:
+    """Largest Mosaic-legal row tile whose double-buffered working set
+    ``2 * (tile * per_row_bytes + resident_bytes)`` (plus ``extra(tile)``
+    when given) fits ``budget``, or ``None`` when none does.  Legal tiles
+    divide ``rows`` and are a multiple of 128 lanes, or cover all of
+    ``rows`` in one tile."""
+    cands = {t for t in _TILE_ROW_CANDIDATES if rows % t == 0}
+    if rows <= _TILE_ROW_CANDIDATES[0] or not cands:
+        cands.add(rows)
+    for t in sorted(cands, reverse=True):
+        need = 2 * (t * per_row_bytes + resident_bytes)
+        if extra is not None:
+            need += extra(t)
+        if need <= budget:
             return t
-    # over-budget fallback: the smallest divisor among the candidates
-    # (1 divides everything, so this always exists and is positive)
-    return next(t for t in reversed(_TILE_ROW_CANDIDATES)
-                if t <= rows and rows % t == 0)
+    return None
 
 
-def select_group_kernels(graph: OpGraph, groups, explicit_bytes: int,
-                         partial=None) -> Tuple[GroupKernel, ...]:
+def csr_tile_entries(params, rows: int, tile_rows: int) -> Optional[int]:
+    """Slots per tile of the padded per-tile CSR layout: the most entries
+    any ``tile_rows``-row tile holds, rounded up to 128 lanes — from the
+    operand's pattern meta (``params``: a CSR leaf's params or graph
+    meta), or ``None`` without it."""
+    get = params.get if hasattr(params, "get") else dict(params).get
+    if get("pattern") is None:
+        return None
+    return _tile_entries(get("pattern"), rows, tile_rows, get("density"),
+                         get("bandwidth"))
+
+
+def check_csr_feeds(units, program, feeds) -> None:
+    """Refuse CSR feeds that do not fit the per-tile layout their spmv
+    passes were planned with (``units``: an :class:`ExecPlan`'s units).
+    A row tile holding more entries than its padded slots — feeds whose
+    rows are spread differently from the pattern meta the plan was
+    traced with — would otherwise lose the excess entries silently."""
+    import numpy as np
+    checked = set()
+    for unit in units:
+        sp = unit.sp
+        for op in (sp.spmv if sp is not None else ()):
+            ipn = program.nodes[op].inputs[0]
+            if (ipn, sp.tile_rows) in checked or ipn not in feeds:
+                continue
+            checked.add((ipn, sp.tile_rows))
+            slots = csr_tile_entries(program.nodes[ipn].params, sp.rows,
+                                     sp.tile_rows)
+            starts = np.asarray(feeds[ipn][::sp.tile_rows])
+            most = int(np.max(np.diff(starts)))
+            if most > slots:
+                raise ValueError(
+                    f"CSR feed {ipn!r} puts {most} entries in one "
+                    f"{sp.tile_rows}-row tile; the plan was lowered for "
+                    f"at most {slots} (its pattern meta): trace the plan "
+                    f"for this operand's pattern")
+
+
+@functools.lru_cache(maxsize=256)
+def _tile_entries(pattern, rows, tile_rows, density, bandwidth) -> int:
+    from ..frontends.sparse import row_counts
+    counts = row_counts(pattern, rows, density=density, bandwidth=bandwidth)
+    per_tile = counts.reshape(-1, tile_rows).sum(axis=1)
+    return int(-(-max(int(per_tile.max()), 1) // _LANE) * _LANE)
+
+
+def _spmv_tile_bytes(tile_rows: int, entries: int) -> int:
+    """VMEM of one spmv in a pass: its double-buffered ``(1, B)`` values
+    block and ``(1, tile)`` row slot bounds (first, stop), and the
+    ``(B, tile)`` one-hot row matrix with its iota and mask temporaries."""
+    return (2 * kernel_block_bytes((entries,))
+            + 2 * 2 * kernel_block_bytes((tile_rows,))
+            + 3 * tile_rows * entries * KERNEL_ITEMSIZE)
+
+
+def _row_bytes(shape) -> int:
+    """VMEM bytes one streamed row of a tensor of ``shape`` takes in a
+    row-tiled block (a rank-1 row pads to 8 sublanes)."""
+    if len(shape) == 1:
+        return _SUBLANE * KERNEL_ITEMSIZE
+    return kernel_block_bytes((_SUBLANE,) + tuple(shape[1:])) // _SUBLANE
+
+
+def select_group_kernels(graph: OpGraph, groups, explicit_bytes: int
+                         ) -> Tuple[GroupKernel, ...]:
     """Pick a kernel shape for every fusion group of a frontend plan.
 
     Pure graph-level classification (shapes + op specs); the expression
     semantics needed to *execute* each shape live in ``repro.exec``.
-
-    ``partial`` maps tensor names to
-    :class:`~repro.core.schedule.PartialPin` records (an overbooked pin
-    set's ``.partial``): spmv operands named there carry a
-    :class:`ResidentSlice` on their pass instead of the whole-operand
-    residency assumption.
+    ``explicit_bytes`` (the plan's explicit region) bounds each kernel's
+    VMEM budget, kept between :data:`KERNEL_VMEM_FLOOR` and
+    :data:`KERNEL_VMEM_BYTES`.
     """
-    return tuple(_select_one(graph, list(g), explicit_bytes, partial)
+    return tuple(_select_one(graph, list(g), explicit_bytes)
                  for g in groups)
+
+
+def _kernel_budget(explicit_bytes: int) -> int:
+    return min(KERNEL_VMEM_BYTES, max(explicit_bytes, KERNEL_VMEM_FLOOR))
 
 
 def _finalizes_late(graph: OpGraph, op, late: set) -> bool:
@@ -448,9 +532,8 @@ def _segment_group(graph: OpGraph, group) -> list:
         if op.is_einsum and op.spec in STREAM_EINSUMS:
             needs_break = op.inputs[STREAM_EINSUMS[op.spec]] in produced
         if op.spec == "spmv":
-            # every spmv operand (CSR triple + gathered x) sits resident,
-            # so any of them produced in-pass must materialize first
-            needs_break = any(t in produced for t in op.inputs)
+            # x is gathered whole by column index: it must materialize
+            needs_break = op.inputs[3] in produced
         if not needs_break and graph.tensors[op.output].shape != ():
             needs_break = any(t in late for t in op.inputs)
         if needs_break and cur:
@@ -465,8 +548,7 @@ def _segment_group(graph: OpGraph, group) -> list:
     return segments
 
 
-def _select_one(graph: OpGraph, group, explicit_bytes: int,
-                partial=None) -> GroupKernel:
+def _select_one(graph: OpGraph, group, explicit_bytes: int) -> GroupKernel:
     ops = [graph.ops[o] for o in group]
     gops = tuple(group)
 
@@ -483,43 +565,46 @@ def _select_one(graph: OpGraph, group, explicit_bytes: int,
                                        for op in ops):
             return GroupKernel(gops, "jnp",
                                reason="stencil mixed with non-halo ops")
+        names = {t for op in ops for t in (*op.inputs, op.output)}
+        need = 2 * sum(kernel_block_bytes(graph.tensors[t].shape)
+                       for t in names)
+        if need > _kernel_budget(explicit_bytes):
+            return GroupKernel(gops, "jnp",
+                               reason=f"whole-array block needs "
+                               f"{need >> 20} MiB of VMEM")
         return GroupKernel(gops, "block")
 
     passes = []
     for seg in _segment_group(graph, group):
-        sp = _classify_pass(graph, seg, explicit_bytes, partial)
+        sp = _classify_pass(graph, seg, explicit_bytes)
         if isinstance(sp, str):                    # rejection reason
             return GroupKernel(gops, "jnp", reason=sp)
         passes.append(sp)
-    kind = ("spmv-stream" if any(op.spec == "spmv" for op in ops)
-            else "stream")
-    return GroupKernel(gops, kind, passes=tuple(passes))
+    return GroupKernel(gops, "stream", passes=tuple(passes))
 
 
-def _classify_pass(graph: OpGraph, seg, explicit_bytes: int, partial=None):
+def _classify_pass(graph: OpGraph, seg, explicit_bytes: int):
     """One segment -> :class:`StreamPass`, or a rejection-reason string."""
-    partial = partial or {}
     ops = [graph.ops[o] for o in seg]
     produced = {op.output for op in ops}
     rows = None
     per_row = 0
     resident = []
     reductions = []
-    slices = []
+    spmvs = []
     streamed_seen = set()
 
     def _stream(tname) -> bool:
         """Account ``tname`` as streamed; False on row-count clash."""
         nonlocal rows, per_row
-        spec = graph.tensors[tname]
-        n = spec.shape[0]
+        shape = graph.tensors[tname].shape
         if rows is None:
-            rows = n
-        elif rows != n:
+            rows = shape[0]
+        elif rows != shape[0]:
             return False
         if tname not in streamed_seen:
             streamed_seen.add(tname)
-            per_row += spec.bytes // max(1, n)
+            per_row += _row_bytes(shape)
         return True
 
     for op in ops:
@@ -539,27 +624,11 @@ def _classify_pass(graph: OpGraph, seg, explicit_bytes: int, partial=None):
             if op.inputs[rhs] not in resident:
                 resident.append(op.inputs[rhs])
         elif op.spec == "spmv":
-            # CSR SpMV: the output vector streams row tiles; the operand
-            # triple and the gathered x are held resident — rows are
-            # ragged and column access is data-dependent.  An overbooked
-            # pin relaxes this to a resident row *prefix* (ResidentSlice)
-            # with tail tiles streaming their CSR slices per grid step.
-            if any(t in produced for t in op.inputs):
+            if op.inputs[3] in produced:
                 return f"{op.name}: spmv operand produced in-pass"
             if not _stream(op.output):
                 return f"{op.name}: mixed row counts"
-            for t in op.inputs:
-                if t not in resident:
-                    resident.append(t)
-            part = tuple(t for t in op.inputs if t in partial)
-            if part:
-                pp = partial[part[0]]
-                sl = ResidentSlice(tensors=part, rows=pp.rows,
-                                   total_rows=pp.total_rows,
-                                   entries=pp.entries,
-                                   total_entries=pp.total_entries)
-                if sl not in slices:
-                    slices.append(sl)
+            spmvs.append(op)
         elif op.spec == "reduce":
             if any(len(graph.tensors[t].shape) != 1 for t in op.inputs):
                 return f"{op.name}: non-vector reduction"
@@ -582,14 +651,27 @@ def _classify_pass(graph: OpGraph, seg, explicit_bytes: int, partial=None):
     if rows is None:                # nothing streams: scalar-only group
         return "scalar-only group"
 
-    part_names = {t for sl in slices for t in sl.tensors}
-    res_bytes = sum(partial[t].resident_bytes if t in part_names
-                    else graph.tensors[t].bytes for t in resident)
-    tile = _pick_tile_rows(rows, per_row, res_bytes,
-                           max(explicit_bytes, 1 << 20))
+    metas = [graph.tensors[op.inputs[0]].meta for op in spmvs]
+    if any(dict(m).get("pattern") is None for m in metas):
+        return "spmv operand carries no CSR pattern meta"
+
+    def spmv_bytes(t: int) -> int:
+        return sum(_spmv_tile_bytes(t, csr_tile_entries(m, rows, t))
+                   for m in metas)
+
+    res_bytes = sum(kernel_block_bytes(graph.tensors[t].shape)
+                    for t in resident)
+    budget = _kernel_budget(explicit_bytes)
+    tile = _pick_tile_rows(rows, per_row, res_bytes, budget,
+                           spmv_bytes if spmvs else None)
+    if tile is None:
+        return (f"no {rows}-row tiling fits {budget >> 20} MiB of VMEM "
+                f"({res_bytes >> 20} MiB resident)")
     return StreamPass(ops=tuple(seg), rows=rows, tile_rows=tile,
                       resident=tuple(resident), reductions=tuple(reductions),
-                      slices=tuple(slices))
+                      vmem_bytes=(2 * (tile * per_row + res_bytes)
+                                  + (spmv_bytes(tile) if spmvs else 0)),
+                      spmv=tuple(op.name for op in spmvs))
 
 
 # ---------------------------------------------------------------------------
@@ -630,8 +712,6 @@ class ExecUnit:
             extra = f" {self.sp.rows}r/{self.sp.tile_rows}t"
             if self.sp.resident:
                 extra += f" res={'+'.join(self.sp.resident)}"
-            for sl in self.sp.slices:
-                extra += f" {sl.describe()}"
         if self.fused > 1:
             extra += f" (fused x{self.fused})"
         return f"{self.kind}[{'+'.join(self.ops)}]{extra}"
@@ -679,10 +759,7 @@ def flatten_units(kernels) -> Tuple[ExecUnit, ...]:
     contribute one unit per pass, in order)."""
     units: List[ExecUnit] = []
     for gi, gk in enumerate(kernels):
-        if gk.kind in ("stream", "spmv-stream"):
-            # spmv-stream passes dispatch exactly like plain stream passes
-            # (the pass's ops carry the spmv-ness); the distinct group
-            # kind only records which kernel family was selected
+        if gk.kind == "stream":
             for sp in gk.passes:
                 units.append(ExecUnit(sp.ops, "stream", sp, (gi,)))
         else:
@@ -702,8 +779,8 @@ def _merge_candidate(graph: OpGraph, unit: ExecUnit) -> bool:
                for o in unit.ops)
 
 
-def fuse_units(graph: OpGraph, units, explicit_bytes: int,
-               partial=None) -> Tuple[ExecUnit, ...]:
+def fuse_units(graph: OpGraph, units, explicit_bytes: int
+               ) -> Tuple[ExecUnit, ...]:
     """The cross-pass residency planner: greedily merge adjacent units into
     one streaming pass wherever re-segmentation proves no value has to
     materialize at the old boundary.  Merged units stream each operand once
@@ -717,7 +794,7 @@ def fuse_units(graph: OpGraph, units, explicit_bytes: int,
             ops = list(prev.ops) + list(unit.ops)
             segs = _segment_group(graph, ops)
             if len(segs) == 1:
-                sp = _classify_pass(graph, segs[0], explicit_bytes, partial)
+                sp = _classify_pass(graph, segs[0], explicit_bytes)
                 if isinstance(sp, StreamPass):
                     fused[-1] = ExecUnit(tuple(ops), "stream", sp,
                                          prev.groups + unit.groups,
@@ -919,15 +996,13 @@ class ExecPlan:
 
 
 def plan_execution(graph: OpGraph, kernels, explicit_bytes: int,
-                   program=None, partial=None) -> ExecPlan:
+                   program=None) -> ExecPlan:
     """Units → residency fusion → rolled-loop detection, in that order.
     ``program`` (the frontend expression DAG) is optional; without it the
-    plan is straight-line.  ``partial`` carries the overbooked pin set's
-    per-tensor :class:`~repro.core.schedule.PartialPin` records so merged
-    passes keep their :class:`ResidentSlice` annotations."""
+    plan is straight-line."""
     units = flatten_units(kernels)
     n_pre = len(units)
-    fused = fuse_units(graph, units, explicit_bytes, partial)
+    fused = fuse_units(graph, units, explicit_bytes)
     roll = detect_rolled_loop(program, fused)
     return ExecPlan(units=fused, roll=roll, spans=resident_spans(fused),
                     n_prefuse=n_pre)
@@ -1036,12 +1111,14 @@ class ShardedExecPlan:
 
 
 def _localize_tile(tile_rows: int, rows_loc: int) -> int:
-    """The per-shard row tile: the global tile when it still divides the
-    local row count, otherwise the largest divisor not exceeding it."""
-    t = min(tile_rows, rows_loc)
-    if rows_loc % t:
-        t = math.gcd(t, rows_loc)
-    return max(t, 1)
+    """The per-shard row tile: the largest Mosaic-legal tile of the local
+    row count (a lane-aligned divisor, or the whole shard) not exceeding
+    the global tile — the shard's working set never grows."""
+    for t in sorted({tile_rows, *_TILE_ROW_CANDIDATES}, reverse=True):
+        if t <= min(tile_rows, rows_loc) and rows_loc % t == 0 \
+                and (t % _LANE == 0 or t == rows_loc):
+            return t
+    return rows_loc
 
 
 def _localize_pass(sp: StreamPass, n_shards: int) -> StreamPass:
@@ -1103,11 +1180,9 @@ def partition_plan(exec_plan: ExecPlan, mesh_axes, *,
     lowered from — partitioning needs its op/shape/CSR-meta view.
 
     Raises :class:`PlanPartitionError` for anything the row-block story
-    cannot express: ragged row counts, einsums other than ``ab,b->a`` /
-    ``a,a->``, irregular gathers/scans, overbooked partial pins
-    (fractional residency and sharding both claim the row dimension),
-    non-scalar jnp fallbacks, or CSR operands without consistent
-    deterministic pattern meta."""
+    cannot express: ragged or mixed row counts, einsums other than
+    ``ab,b->a`` / ``a,a->``, irregular gathers/scans, or CSR operands
+    without consistent deterministic pattern meta."""
     axis, n_shards = (("shards", mesh_axes) if isinstance(mesh_axes, int)
                       else (mesh_axes[0], int(mesh_axes[1])))
     if n_shards < 1:
@@ -1133,64 +1208,44 @@ def partition_plan(exec_plan: ExecPlan, mesh_axes, *,
     halo: List[str] = []
     reduced: List[str] = []
 
+    def gather_whole(name: str) -> None:
+        shape = program.nodes[name].shape
+        if shape and shape[0] == rows and name not in gathered:
+            gathered.append(name)
+
     for unit in exec_plan.units:
         if unit.kind == "stream":
-            sp = unit.sp
-            if sp.slices:
+            claim_rows(unit.sp.rows, f"pass {'+'.join(unit.sp.ops)}")
+        for o in unit.ops:
+            nd = program.nodes[o]
+            if nd.irregular or nd.op in ("gather", "scan"):
                 raise PlanPartitionError(
-                    f"pass {'+'.join(sp.ops)} carries overbooked partial "
-                    f"pins; fractional residency and mesh sharding both "
-                    f"claim the row dimension — re-codesign with "
-                    f"overbook=0 to shard")
-            claim_rows(sp.rows, f"pass {'+'.join(sp.ops)}")
-            for o in sp.ops:
-                nd = program.nodes[o]
-                if nd.op == "spmv":
-                    data = nd.inputs[2]
-                    if data not in csr:
-                        csr[data] = _csr_layout(program, nd, n_shards)
-                    x = nd.inputs[3]
-                    if (program.nodes[x].shape
-                            and program.nodes[x].shape[0] == sp.rows
-                            and x not in gathered):
-                        gathered.append(x)
-                elif nd.op in ("matmul", "einsum") and nd.shape != ():
-                    spec = nd.param("spec")
-                    if spec != "ab,b->a":
-                        raise PlanPartitionError(
-                            f"op '{o}': einsum {spec!r} has no row-block "
-                            f"split (only 'ab,b->a' contractions and "
-                            f"'a,a->' reductions shard)")
-                    rhs = nd.inputs[1]
-                    if (program.nodes[rhs].shape
-                            and program.nodes[rhs].shape[0] == sp.rows
-                            and rhs not in gathered):
-                        gathered.append(rhs)
-                elif (nd.op in ("dot", "norm")
-                      or (nd.op in ("matmul", "einsum")
-                          and nd.shape == ())):
-                    # rank-0 reductions over streamed vectors: per-shard
-                    # partials combine with psum (scalar ew epilogues
-                    # recompute replicated from those, no exchange)
-                    if o not in reduced:
-                        reduced.append(o)
-        elif unit.kind == "block":
-            for o in unit.ops:
-                nd = program.nodes[o]
-                claim_rows(nd.shape[0], f"block op '{o}'")
-                if nd.op == "stencil2d":
-                    halo.append(o)
-        else:                                    # jnp fallback
-            for o in unit.ops:
-                nd = program.nodes[o]
-                if nd.irregular or nd.op in ("gather", "scan"):
+                    f"op '{o}' ({nd.op}) is data-dependent; "
+                    f"irregular addressing has no contiguous row split")
+            if nd.shape != ():
+                claim_rows(nd.shape[0], f"op '{o}'")
+            if nd.op == "spmv":
+                data = nd.inputs[2]
+                if data not in csr:
+                    csr[data] = _csr_layout(program, nd, n_shards)
+                gather_whole(nd.inputs[3])
+            elif nd.op in ("matmul", "einsum") and nd.shape != ():
+                spec = nd.param("spec")
+                if spec != "ab,b->a":
                     raise PlanPartitionError(
-                        f"op '{o}' ({nd.op}) is data-dependent; "
-                        f"irregular addressing has no contiguous row split")
-                if nd.shape != ():
-                    raise PlanPartitionError(
-                        f"jnp-fallback op '{o}' produces shape "
-                        f"{nd.shape}; only scalar fallbacks replicate")
+                        f"op '{o}': einsum {spec!r} has no row-block "
+                        f"split (only 'ab,b->a' contractions and "
+                        f"'a,a->' reductions shard)")
+                gather_whole(nd.inputs[1])
+            elif (nd.op in ("dot", "norm")
+                  or (nd.op in ("matmul", "einsum") and nd.shape == ())):
+                # rank-0 reductions over streamed vectors: per-shard
+                # partials combine with psum (scalar ew epilogues
+                # recompute replicated from those, no exchange)
+                if o not in reduced:
+                    reduced.append(o)
+            elif nd.op == "stencil2d":
+                halo.append(o)
 
     if rows is None:
         raise PlanPartitionError("plan has no streamed rows to shard")
@@ -1205,7 +1260,12 @@ def partition_plan(exec_plan: ExecPlan, mesh_axes, *,
         n for n, nd in program.nodes.items()
         if nd.shape and nd.shape[0] == rows and n not in csr_members)
 
+    # spmv passes run inline per shard: a shard's CSR entry window is
+    # dynamic (indexed by the mesh position), so no static per-tile
+    # layout exists for it
     local_units = tuple(
+        dataclasses.replace(u, kind="jnp", sp=None) if u.kind == "stream"
+        and u.sp.spmv else
         dataclasses.replace(u, sp=_localize_pass(u.sp, n_shards))
         if u.kind == "stream" else u
         for u in exec_plan.units)

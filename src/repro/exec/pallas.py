@@ -7,29 +7,33 @@ dispatch — no per-unit Python driver, no scalar round-trips between
 kernels, no per-call ``result_type``/``asarray`` conversion.  The pieces:
 
 * ``stream`` units run ``pl.pallas_call`` with a 1-D grid over row tiles of
-  the unit's shared streamed length.  Contraction right-hand sides (and any
-  other full-block operands) use a *constant index map*, so Pallas keeps
-  them resident in VMEM across every grid step — the execution-level image
-  of the plan's explicit-region pins.  Rank-0 dot/norm reductions
-  accumulate into a revisited ``(1,)`` output block across the pass;
-  *eager* scalars (rank-0 glue whose in-pass inputs are tile-invariant,
-  e.g. ``nalpha = -alpha``) are recomputed per tile so tiled ops can read
-  them without a pass break; reduction-derived scalar epilogues
-  (``beta = rs'/rs``) run once on the final tile.
-* CSR SpMV ops (``spmv-stream`` group kernels) run inside stream units as
-  row-tiled passes whose *entire* operand — the indptr/indices/data triple
-  plus the gathered ``x`` — is VMEM-resident across every tile (constant
-  index maps): rows are ragged and column access is data-dependent, so
-  only the output vector streams.
-* Prefix-sliced SpMV operands (overbooked pins — the pass carries a
-  ``core.lowering.ResidentSlice``) instead use a padded per-tile CSR
-  layout: the resident row-prefix blocks are held in VMEM across every
-  grid step via constant index maps, while each spill-tail tile streams
-  only its own ``(1, M)`` entry slice through the grid — per-step work is
-  ``O(M)`` instead of a masked scan over all ``nnz`` entries.
+  the unit's shared streamed length.  Rank-1 vectors enter every kernel as
+  ``(1, n)`` rows streamed in ``(1, tile)`` blocks (lane-dense, which is
+  what Mosaic tiles); matrices stream ``(tile, cols)`` blocks.  Contraction
+  right-hand sides use a *constant index map*, so Pallas keeps them
+  resident in VMEM across every grid step — the execution-level image of
+  the plan's explicit-region pins.  A matvec ``A @ x`` is computed as the
+  row ``x · A_tileᵀ`` so its result lands in the vector layout.  Rank-0
+  values live in SMEM: tile-invariant scalars are inputs, and dot/norm
+  reductions accumulate into ``(1, 1)`` SMEM outputs across the pass.
+  *Eager* scalars (rank-0 glue whose in-pass inputs are tile-invariant,
+  e.g. ``nalpha = -alpha``) are computed before the kernel and read from
+  SMEM, so tiled ops use them without a pass break; reduction-derived
+  scalar epilogues (``beta = rs'/rs``, a norm's ``sqrt``) run after it.
+* CSR SpMV ops run inside stream passes on a padded per-tile entry
+  layout: tile ``t`` owns exactly its own rows' entries, padded to ``B``
+  slots (static, from the operand's pattern meta).  The layout — column
+  ids and values as contiguous per-tile windows, and each row's slot
+  range inside its window — is derived once per dispatch from the CSR
+  leaves; each pass gathers ``values * x[cols]`` in XLA (Mosaic cannot
+  gather by column index in-kernel) and the kernel sums each tile's rows
+  as one MXU product with a one-hot row matrix built from those ranges —
+  no scatter.  Feeds must match the pattern meta the plan was built for
+  (:func:`core.lowering.check_csr_feeds` refuses those that do not).
 * ``block`` units hold whole arrays as single blocks (stencil halos).
-* ``jnp`` units — irregular gathers, >2-operand einsums — inline the
-  reference rules straight into the trace.
+* ``jnp`` units — irregular gathers, >2-operand einsums, working sets
+  over one kernel's VMEM — inline the reference rules straight into the
+  trace; ``explain()`` names each one's reason.
 * Adjacent units fused by the residency planner execute as one pass, so
   operands resident across former pass/group boundaries are not
   re-streamed (``core.lowering.fuse_units``).
@@ -48,17 +52,21 @@ The PR-3 per-unit driver is kept as the ``pallas-perunit`` backend — one
 dispatch per unit, runtime freeing — as the A/B baseline TABLE 8 measures
 the single-program speedup against.
 
-On CPU (and any non-TPU backend) kernels run with ``interpret=True``, so CI
-exercises the real lowering; on TPU they compile through Mosaic with the
-grid marked ``arbitrary`` (accumulation makes steps order-dependent).
-Override with ``CELLO_PALLAS_INTERPRET=0/1``; donation with
-``CELLO_PALLAS_DONATE=0/1``.
+On a TPU every kernel compiles through Mosaic with the grid marked
+``arbitrary`` (accumulation makes steps order-dependent) and a scoped-VMEM
+limit taken from the tile plan; on any other platform kernels run with
+``interpret=True``, so CI exercises the same lowering.
+``CELLO_PALLAS_INTERPRET=0`` forces Mosaic off the chip (compiling for a
+described TPU); interpret mode is never taken on a TPU.  Donation follows
+the platform too; ``CELLO_PALLAS_DONATE=0/1`` overrides it.  Kernels run
+up to 32-bit floats on the chip: a float64 program raises
+:class:`KernelDtypeError` before it reaches Mosaic.
 
-Numerics: tiled reductions re-associate the sum (per-tile partials), so
-outputs match the ``reference`` backend within the tolerances documented in
-``docs/execution_backends.md`` rather than bitwise.  Everything elementwise,
-matvec rows, block kernels, and jnp fallbacks use the reference rules
-verbatim.
+Numerics: tiled reductions re-associate the sum (per-tile partials), and
+contractions run on the MXU at ``Precision.HIGHEST``, so outputs match the
+``reference`` backend within the tolerances documented in
+``docs/execution_backends.md`` rather than bitwise.  Everything
+elementwise, block kernels and jnp units use the reference rules verbatim.
 """
 from __future__ import annotations
 
@@ -68,10 +76,11 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 from .. import obs
 from ..testing import faults
 from ..core.lowering import (STREAM_EINSUMS, ExecPlan, GroupKernel,
-                             StreamPass, flatten_units, plan_execution,
-                             select_group_kernels)
+                             StreamPass, check_csr_feeds, csr_tile_entries,
+                             flatten_units, kernel_block_bytes,
+                             plan_execution, select_group_kernels)
 from .base import Executor, plan_groups, plan_program
-from .reference import csr_row_ids, eval_node
+from .reference import eval_node
 
 _TRACES = obs.registry().counter(
     "exec.traces", "jit trace-time Python body executions, per compiled "
@@ -88,6 +97,18 @@ _UNITS = obs.registry().counter(
 
 _BACKEND_PROBE: Optional[str] = None
 
+#: headroom above a kernel's planned VMEM for Mosaic's internal scratch,
+#: and the ceiling no kernel asks past (a TPU v5e core has 128 MiB)
+_VMEM_HEADROOM = 8 << 20
+_VMEM_DEFAULT = 16 << 20
+_VMEM_CEILING = 100 << 20
+
+
+class KernelDtypeError(TypeError):
+    """A dtype the TPU kernels do not run reached Mosaic (float64: the
+    chip has no fp64 vector unit).  Raised at trace time, so no backend
+    substitutes for the kernel unless the caller configured one."""
+
 
 def _default_backend() -> str:
     """``jax.default_backend()``, probed once per process (the probe
@@ -99,6 +120,13 @@ def _default_backend() -> str:
     return _BACKEND_PROBE
 
 
+def default_solver_backend() -> str:
+    """The backend a solve that names none runs on: the compiled kernels
+    on a TPU, the op-by-op ``reference`` oracle on every other platform
+    (where pallas kernels could only be interpreted)."""
+    return "pallas" if _default_backend() == "tpu" else "reference"
+
+
 def _env_flag(name: str) -> Optional[bool]:
     env = os.environ.get(name)
     if env is None or not env.strip():
@@ -108,11 +136,16 @@ def _env_flag(name: str) -> Optional[bool]:
 
 def use_interpret() -> bool:
     """Interpret Pallas kernels unless we are actually on a TPU (CI and
-    laptops exercise the same lowering through the interpreter)."""
+    laptops exercise the same lowering through the interpreter).
+    ``CELLO_PALLAS_INTERPRET=0`` compiles through Mosaic anywhere (the
+    compile-only rehearsal for a described chip); asking for interpret
+    mode on a TPU is an error, never a silent slow path."""
     env = _env_flag("CELLO_PALLAS_INTERPRET")
-    if env is not None:
-        return env
-    return _default_backend() != "tpu"
+    on_tpu = _default_backend() == "tpu"
+    if env and on_tpu:
+        raise RuntimeError("CELLO_PALLAS_INTERPRET asks for interpret mode "
+                           "on a TPU; kernels compile through Mosaic there")
+    return (not on_tpu) if env is None else env
 
 
 def use_donation() -> bool:
@@ -124,14 +157,36 @@ def use_donation() -> bool:
     return _default_backend() != "cpu"
 
 
-def _pallas_call_kwargs(interpret: bool) -> Dict[str, Any]:
-    if interpret:
-        return {"interpret": True}
-    from ..kernels._compat import CompilerParams
-    # accumulating reductions make grid steps order-dependent: the grid
-    # dimension must not be parallelized across cores
-    return {"compiler_params": CompilerParams(
-        dimension_semantics=("arbitrary",))}
+def _vmem_limit(planned_bytes: int) -> int:
+    return min(max(planned_bytes + _VMEM_HEADROOM, _VMEM_DEFAULT),
+               _VMEM_CEILING)
+
+
+def _pallas_call_kwargs(name: str, dtype, planned_vmem: int,
+                        grid_dims: int) -> Dict[str, Any]:
+    """Interpret mode off the chip; otherwise Mosaic compiler params — the
+    grid stays sequential (accumulating reductions make grid steps
+    order-dependent) and the scoped-VMEM limit follows the tile plan."""
+    import jax.numpy as jnp
+    if use_interpret():
+        return {"interpret": True, "name": name}
+    if jnp.dtype(dtype).itemsize > 4:
+        raise KernelDtypeError(
+            f"kernel {name!r}: {jnp.dtype(dtype).name} does not run on the "
+            "TPU kernels (no fp64 vector unit); solve in float32, or run "
+            "the plan on the reference backend explicitly")
+    from jax.experimental.pallas import tpu as pltpu
+    return {"name": name, "compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",) * grid_dims,
+        vmem_limit_bytes=_vmem_limit(planned_vmem))}
+
+
+def _row_shape(shape) -> tuple:
+    """The kernel-side shape of an array: rank-1 vectors are ``(1, n)``
+    rows, rank-0 values ``(1, 1)`` SMEM cells."""
+    if len(shape) == 0:
+        return (1, 1)
+    return (1,) + tuple(shape) if len(shape) == 1 else tuple(shape)
 
 
 # --------------------------------------------------------------------------
@@ -141,9 +196,9 @@ def _pallas_call_kwargs(interpret: bool) -> Dict[str, Any]:
 def _classify_nodes(nodes) -> Dict[str, str]:
     """"tiled" | "reduce" | "eager" | "epilogue" per node of one pass.
 
-    ``eager`` scalars have tile-invariant in-pass inputs and are recomputed
-    per tile; ``epilogue`` scalars depend on an in-pass reduction and only
-    exist on the final tile.
+    ``eager`` scalars have tile-invariant in-pass inputs and are computed
+    before the kernel; ``epilogue`` scalars depend on an in-pass reduction
+    and are computed after it.
     """
     classes: Dict[str, str] = {}
     late: Set[str] = set()
@@ -170,419 +225,239 @@ def _classify_nodes(nodes) -> Dict[str, str]:
 class _StreamCall:
     """One tile-streaming ``pl.pallas_call`` for a :class:`StreamPass`.
 
-    With ``defer_finalize=True`` (sharded execution) the kernel emits raw
-    per-shard reduction partials and skips the in-kernel scalar finalize
-    work: no ``sqrt`` on norm accumulators, no final-tile epilogue — the
-    sharded driver combines partials with ``psum`` and replays the scalar
-    chain (:attr:`finalize_nodes`) outside the kernel, inside the
+    With ``defer_finalize=True`` (sharded execution) the call returns raw
+    per-shard reduction partials: no ``sqrt`` on norm accumulators, no
+    epilogue — the sharded program combines partials with ``psum`` and
+    replays the epilogue (:attr:`finalize_nodes`) inside the
     ``shard_map`` trace."""
 
     def __init__(self, program, sp: StreamPass, needed: Set[str], *,
-                 defer_finalize: bool = False,
-                 resident_rename: Optional[Dict[str, str]] = None):
+                 defer_finalize: bool = False):
         self.nodes = [program.nodes[o] for o in sp.ops]
         self.sp = sp
         self.defer = defer_finalize
         produced = {nd.name for nd in self.nodes}
-        shapes = {n: program.nodes[n].shape
-                  for nd in self.nodes for n in (*nd.inputs, nd.name)}
-        self.shapes = shapes
+        self.shapes = {n: tuple(program.nodes[n].shape)
+                       for nd in self.nodes for n in (*nd.inputs, nd.name)}
         self.classes = _classify_nodes(self.nodes)
 
+        in_names: List[str] = []
         stream_in: List[str] = []
+        res_in: List[str] = []
         scalar_in: List[str] = []
-        # sharded execution renames gathered operands to "<name>@g" view
-        # aliases; the pass's resident set must follow those renames
-        rename = resident_rename or {}
-        res_in = [rename.get(n, n) for n in sp.resident]
-        # derived resident inputs: per-entry CSR row ids, computed ONCE
-        # per dispatch from indptr (outside the kernel) instead of a
-        # searchsorted per grid step; keyed by indptr so spmv ops sharing
-        # an operand share one array
-        self.derived: Dict[str, Tuple[str, int]] = {}
-        self._spmv_rows: Dict[str, str] = {}
-        # fractional residency (overbooked pins): prefix-sliced operands
-        # are re-arranged into padded per-tile CSR blocks — resident
-        # prefix blocks plus streamed spill-tail blocks (``_arrange``)
-        self.arranged: Dict[str, Callable] = {}
-        self.tail_in: List[str] = []
-        self.tail_off: Dict[str, int] = {}
-        self.extra_in: List[str] = []
-        self._sliced: Dict[str, Dict[str, Any]] = {}
-        self._arr_cache: Dict[str, Optional[Dict[str, Any]]] = {}
-        slice_of = {}
-        for sl in getattr(sp, "slices", ()) or ():
-            for t in sl.tensors:
-                slice_of[t] = sl
 
         def _want(name: str, bucket: List[str]):
-            if name not in produced and name not in bucket:
+            if name not in bucket:
                 bucket.append(name)
 
+        tr = sp.tile_rows
+        # per spmv: per-tile entry values and each row's slot range, built
+        # from loop-invariant layouts derived once per dispatch
+        self.spmv: Dict[str, Tuple[str, str]] = {}
+        self.tile_in: List[str] = []
+        self.derived: Dict[str, Callable] = {}
         for nd in self.nodes:
+            for t in nd.inputs:
+                if t not in produced:
+                    _want(t, in_names)
             cls = self.classes[nd.name]
-            if cls == "tiled" and nd.op == "spmv":
-                sl = slice_of.get(nd.inputs[0])
-                am = self._arrange(program, nd, sl) if sl is not None \
-                    else None
-                if am is not None:
-                    self._sliced[nd.name] = am
-                    _want(nd.inputs[3], res_in)   # gathered x: resident
-                    for t in nd.inputs[:3]:
-                        # raw CSR leaves feed the arrangement but never
-                        # enter the kernel themselves
-                        if t not in self.extra_in:
-                            self.extra_in.append(t)
-                    for n in am["pre"]:
-                        _want(n, res_in)
-                    for n in am["tail"]:
-                        if n not in self.tail_in:
-                            self.tail_in.append(n)
-                    continue
-                for t in nd.inputs:         # CSR triple + x: all resident
-                    _want(t, res_in)
-                indptr, indices = nd.inputs[0], nd.inputs[1]
-                rows_name = f"{indptr}@rows"
-                nnz = shapes[indices][0]
-                self.derived[rows_name] = (indptr, nnz)
-                self._spmv_rows[nd.name] = rows_name
-                shapes[rows_name] = (nnz,)
-                _want(rows_name, res_in)
+            if nd.op == "spmv":
+                ipn = nd.inputs[0]
+                entries = csr_tile_entries(program.nodes[ipn].params,
+                                           sp.rows, tr)
+                lay = f"{ipn}@t{tr}"
+                self.derived[lay] = _csr_tiles_fn(*nd.inputs[:3], tr,
+                                                  entries)
+                self.spmv[nd.name] = (lay, nd.inputs[3])
+                self.shapes[f"{nd.name}@vals"] = \
+                    (sp.rows // tr, 1, entries)
+                self.tile_in += [f"{nd.name}@vals", f"{nd.name}@first",
+                                 f"{nd.name}@stop"]
             elif cls == "tiled" and nd.op in ("matmul", "einsum"):
                 rhs = STREAM_EINSUMS[nd.param("spec")]
-                _want(nd.inputs[1 - rhs], stream_in)
-            elif cls == "tiled":
+                if nd.inputs[1 - rhs] not in produced:
+                    _want(nd.inputs[1 - rhs], stream_in)
+                _want(nd.inputs[rhs], res_in)
+            elif cls in ("tiled", "reduce"):
                 for t in nd.inputs:
-                    _want(t, scalar_in if shapes[t] == () else stream_in)
-            elif cls == "reduce":
-                for t in nd.inputs:
-                    _want(t, stream_in)
-            else:                       # eager/epilogue: rank-0 operands
-                for t in nd.inputs:
-                    _want(t, scalar_in)
-
-        # sliced operands' raw CSR leaves were replaced by arranged
-        # blocks; only the arrangement (host side) reads them — keeping
-        # the full arrays kernel-resident would defeat the split
-        for t in self.extra_in:
-            if t in res_in:
-                res_in.remove(t)
+                    if self.shapes[t] == ():
+                        _want(t, scalar_in)     # external or eager scalar
+                    elif t not in produced:
+                        _want(t, stream_in)
+        self.in_names = in_names
         self.stream_in, self.res_in, self.scalar_in = \
             stream_in, res_in, scalar_in
-        # reductions always need an output block to accumulate into;
-        # streamed / scalar values only when read outside this pass
+        self.eager = [nd for nd in self.nodes
+                      if self.classes[nd.name] == "eager"]
+        self.epilogue = [nd for nd in self.nodes
+                         if self.classes[nd.name] == "epilogue"]
+        # reductions always need an output to accumulate into; streamed
+        # values only when read outside this pass
         self.red_out = [nd.name for nd in self.nodes
                         if self.classes[nd.name] == "reduce"]
-        self.sca_out = [] if defer_finalize else \
-            [nd.name for nd in self.nodes
-             if self.classes[nd.name] in ("eager", "epilogue")
-             and nd.name in needed]
         self.stream_out = [nd.name for nd in self.nodes
                            if self.classes[nd.name] == "tiled"
                            and nd.name in needed]
         self.needed = needed
         self._built: Dict[Any, Callable] = {}
 
-    @property
-    def in_names(self) -> List[str]:
-        """External inputs only (derived row-id and arranged per-tile
-        arrays are internal; ``extra_in`` raw CSR leaves feed the
-        arrangement without entering the kernel)."""
-        names = [n for n in self.stream_in + self.tail_in + self.res_in
-                 + self.scalar_in
-                 if n not in self.derived and n not in self.arranged]
-        for n in self.extra_in:
-            if n not in names:
-                names.append(n)
-        return names
-
-    # -- fractional residency (overbooked pins) -------------------------
-    def _arrange(self, program, nd, sl) -> Optional[Dict[str, Any]]:
-        """Padded per-tile CSR layout for a prefix-sliced spmv operand.
-
-        Tile boundaries are row boundaries, so tile ``t`` owns the entry
-        range ``cum[t*tr] .. cum[(t+1)*tr]`` — rows never split across
-        tiles and per-row summation order matches the reference rule.
-        The gather/mask matrices are *static* (numpy, from the operand's
-        build-time ``row_counts`` pattern meta), so arranging at dispatch
-        is two fixed-shape gathers; the searchsorted row-id pass of the
-        whole-resident kernel disappears entirely.  Returns ``None`` when
-        the static pattern meta is unavailable or inconsistent — the op
-        then falls back to the whole-resident kernel (correct, unsplit).
-        """
-        import numpy as np
-        ipn, ixn, dvn, _x = nd.inputs
-        if ipn in self._arr_cache:
-            return self._arr_cache[ipn]
-        self._arr_cache[ipn] = None          # default for early bail-outs
-        tr, n = self.sp.tile_rows, self.sp.rows
-        nnz = self.shapes[ixn][0]
-        leaf = program.nodes.get(ipn)
-        pattern = leaf.param("pattern") if leaf is not None else None
-        if n % tr or nnz <= 0 or pattern is None:
-            return None
-        from ..frontends.sparse import row_counts
-        try:
-            counts = row_counts(pattern, n, density=leaf.param("density"),
-                                bandwidth=leaf.param("bandwidth"))
-        except (TypeError, ValueError):
-            return None
-        cum = np.concatenate(([0], np.cumsum(counts)))
-        if int(cum[-1]) != nnz:
-            return None
-        n_tiles = n // tr
-        bounds = cum[::tr]                   # row-aligned tile starts
-        tcnt = bounds[1:] - bounds[:-1]
-        budget = -(-max(int(tcnt.max()), 1) // 8) * 8   # lanes % 8 == 0
-        pos = bounds[:-1, None] + np.arange(budget)[None, :]
-        valid = np.arange(budget)[None, :] < tcnt[:, None]
-        gat = np.minimum(pos, nnz - 1).astype(np.int32)
-        rows = np.searchsorted(cum, np.minimum(pos, nnz - 1),
-                               side="right") - 1
-        trow = np.where(valid, rows - (np.arange(n_tiles) * tr)[:, None],
-                        0).astype(np.int32)
-        # whole tiles covered by the resident row prefix; the boundary
-        # tile (partially resident) and everything after it stream
-        p = min(sl.rows // tr, n_tiles - 1)
-
-        def _vals(src, g, v, to_compute_dtype):
-            def build(env, dt, src=src, g=g, v=v,
-                      cast=to_compute_dtype):
-                import jax.numpy as jnp
-                a = jnp.asarray(env[src])
-                if cast:
-                    a = jnp.asarray(a, dt)
-                return jnp.where(jnp.asarray(v), a[jnp.asarray(g)],
-                                 jnp.zeros((), a.dtype))
-            return build
-
-        def _const(r):
-            def build(env, dt, r=r):
-                import jax.numpy as jnp
-                return jnp.asarray(r)
-            return build
-
-        base = ipn[:-len(".indptr")] if ipn.endswith(".indptr") else ipn
-        am: Dict[str, Any] = {"p": p, "budget": budget,
-                              "n_tiles": n_tiles, "pre": (), "tail": ()}
-        if p > 0:
-            pre = (f"{base}@pd", f"{base}@pc", f"{base}@pr")
-            self.arranged[pre[0]] = _vals(dvn, gat[:p], valid[:p], True)
-            self.arranged[pre[1]] = _vals(ixn, gat[:p], valid[:p], False)
-            self.arranged[pre[2]] = _const(trow[:p])
-            for nm in pre:
-                self.shapes[nm] = (p, budget)
-            am["pre"] = pre
-        tail = (f"{base}@td", f"{base}@tc", f"{base}@tr")
-        self.arranged[tail[0]] = _vals(dvn, gat[p:], valid[p:], True)
-        self.arranged[tail[1]] = _vals(ixn, gat[p:], valid[p:], False)
-        self.arranged[tail[2]] = _const(trow[p:])
-        for nm in tail:
-            self.shapes[nm] = (n_tiles - p, budget)
-            self.tail_off[nm] = p
-        am["tail"] = tail
-        self._arr_cache[ipn] = am
-        return am
-
-    # -- pallas plumbing ------------------------------------------------
-    def _specs(self, dtype):
+    def _build(self, dtype):
         import jax
         import jax.numpy as jnp
+        from jax import lax
         from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
         tr = self.sp.tile_rows
-
-        def stream_spec(shape):
-            if len(shape) == 1:
-                return pl.BlockSpec((tr,), lambda i: (i,))
-            return pl.BlockSpec((tr,) + shape[1:],
-                                lambda i: (i,) + (0,) * (len(shape) - 1))
-
-        def full_spec(shape):
-            shape = shape or (1,)            # rank-0 passed as (1,)
-            return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
-
-        def tail_spec(shape, off):
-            # one padded spill-tail tile per step; prefix steps (i < off)
-            # clamp to block 0 — loaded but unread (the kernel's selects
-            # pick the resident prefix block instead)
-            return pl.BlockSpec(
-                (1,) + shape[1:],
-                lambda i, off=off: (jnp.maximum(i - off, 0),)
-                + (0,) * (len(shape) - 1))
-
-        in_specs = ([stream_spec(self.shapes[n]) for n in self.stream_in]
-                    + [tail_spec(self.shapes[n], self.tail_off[n])
-                       for n in self.tail_in]
-                    + [full_spec(self.shapes[n]) for n in self.res_in]
-                    + [full_spec(()) for n in self.scalar_in])
-        out_specs, out_shape = [], []
-        for n in self.red_out + self.sca_out:
-            out_specs.append(full_spec(()))
-            out_shape.append(jax.ShapeDtypeStruct((1,), dtype))
-        for n in self.stream_out:
-            out_specs.append(stream_spec(self.shapes[n]))
-            out_shape.append(jax.ShapeDtypeStruct(self.shapes[n], dtype))
-        return in_specs, out_specs, out_shape
-
-    def _build(self, dtype):
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        n_tiles = self.sp.rows // self.sp.tile_rows
-        tile_rows = self.sp.tile_rows
-        nodes, shapes, classes = self.nodes, self.shapes, self.classes
-        n_stream, n_tail = len(self.stream_in), len(self.tail_in)
-        n_res, n_scal = len(self.res_in), len(self.scalar_in)
-        scalar_outs = self.red_out + self.sca_out
+        n_tiles = self.sp.rows // tr
+        nodes, classes = self.nodes, self.classes
+        ins = self.stream_in + self.tile_in + self.res_in + self.scalar_in
+        n_in = len(ins)
         stream_out_set = set(self.stream_out)
-        sca_out_set = set(self.sca_out)
-        red_set = set(self.red_out)
-        epi_nodes = [] if self.defer else \
-            [nd for nd in nodes if classes[nd.name] == "epilogue"]
-        defer = self.defer
+        hi = lax.Precision.HIGHEST
 
         def kernel(*refs):
             i = pl.program_id(0)
-            last = n_tiles - 1
-            sref = dict(zip(self.stream_in, refs[:n_stream]))
-            tref = dict(zip(self.tail_in,
-                            refs[n_stream:n_stream + n_tail]))
-            rref = dict(zip(self.res_in,
-                            refs[n_stream + n_tail:
-                                 n_stream + n_tail + n_res]))
-            cref = dict(zip(self.scalar_in,
-                            refs[n_stream + n_tail + n_res:
-                                 n_stream + n_tail + n_res + n_scal]))
-            oref = dict(zip(scalar_outs + self.stream_out,
-                            refs[n_stream + n_tail + n_res + n_scal:]))
+            # by position: a contraction's resident RHS may also stream
+            # elsewhere in the pass (``p`` in ``A @ p`` and ``x + a * p``)
+            it = iter(refs[:n_in])
+            sref, tref, rref, cref = (
+                {n: next(it) for n in names}
+                for names in (self.stream_in, self.tile_in, self.res_in,
+                              self.scalar_in))
+            oref = dict(zip(self.red_out + self.stream_out, refs[n_in:]))
             tiles: Dict[str, Any] = {}
-            scal: Dict[str, Any] = {}
 
-            def stv(name):                      # streamed tile value
+            def val(name):                 # streamed tile or SMEM scalar
+                if name in cref:
+                    return cref[name][0, 0]
                 if name not in tiles:
                     tiles[name] = sref[name][...]
                 return tiles[name]
 
-            def scv(name):                      # tile-invariant scalar
-                if name not in scal:
-                    scal[name] = cref[name][0]
-                return scal[name]
-
-            def opv(nd, t):                     # tiled-op operand value
-                return scv(t) if shapes[t] == () else stv(t)
-
             for nd in nodes:
                 cls = classes[nd.name]
-                if cls == "eager":
-                    scal[nd.name] = eval_node(
-                        nd, [scv(t) for t in nd.inputs])
-                elif cls == "tiled":
-                    if nd.op == "spmv" and nd.name in self._sliced:
-                        val = _spmv_sliced_tile(
-                            self._sliced[nd.name], tref, rref,
-                            rref[nd.inputs[3]][...], i, tile_rows, dtype)
-                    elif nd.op == "spmv":
-                        val = _spmv_row_tile(
-                            rref[self._spmv_rows[nd.name]][...],
-                            rref[nd.inputs[1]][...],
-                            rref[nd.inputs[2]][...],
-                            rref[nd.inputs[3]][...],
-                            i * tile_rows, tile_rows, dtype)
+                if cls == "tiled":
+                    if nd.op == "spmv":         # one-hot row sum, MXU
+                        vals = tref[f"{nd.name}@vals"][...]     # (1, B)
+                        first = tref[f"{nd.name}@first"][...]   # (1, tr)
+                        stop = tref[f"{nd.name}@stop"][...]
+                        slot = lax.broadcasted_iota(
+                            jnp.int32, (vals.shape[-1], tr), 0)
+                        onehot = (slot >= first) & (slot < stop)
+                        v = jnp.dot(vals, onehot.astype(dtype),
+                                    precision=hi,
+                                    preferred_element_type=dtype)
                     elif nd.op in ("matmul", "einsum"):
-                        rhs = STREAM_EINSUMS[nd.param("spec")]
-                        val = jnp.dot(stv(nd.inputs[1 - rhs]),
-                                      rref[nd.inputs[rhs]][...],
-                                      preferred_element_type=dtype)
+                        spec = nd.param("spec")
+                        rhs = STREAM_EINSUMS[spec]
+                        lhs = val(nd.inputs[1 - rhs])
+                        right = rref[nd.inputs[rhs]][...]
+                        if spec == "ab,b->a":   # row x (1, m) . A_tile^T
+                            v = lax.dot_general(
+                                right, lhs, (((1,), (1,)), ((), ())),
+                                precision=hi, preferred_element_type=dtype)
+                        else:
+                            v = jnp.dot(lhs, right, precision=hi,
+                                        preferred_element_type=dtype)
                     else:
-                        val = eval_node(nd, [opv(nd, t) for t in nd.inputs])
-                    tiles[nd.name] = val
+                        v = eval_node(nd, [val(t) for t in nd.inputs])
+                    tiles[nd.name] = v
                     if nd.name in stream_out_set:
-                        oref[nd.name][...] = val
+                        oref[nd.name][...] = v.astype(dtype)
                 elif cls == "reduce":
-                    if nd.op == "norm":
-                        x = stv(nd.inputs[0])
-                        part = jnp.dot(x, x, preferred_element_type=dtype)
-                    else:
-                        part = jnp.dot(stv(nd.inputs[0]),
-                                       stv(nd.inputs[1]),
-                                       preferred_element_type=dtype)
-                    _accumulate(oref[nd.name], part, i)
-                    if nd.op == "norm" and not defer:
-                        # deferred: the sqrt applies after the cross-shard
-                        # psum, not to this shard's partial
-                        _sqrt_at(oref[nd.name], i == last)
-            if epi_nodes or sca_out_set:
-                @pl.when(i == last)
-                def _():
-                    vals: Dict[str, Any] = {}
+                    a = val(nd.inputs[0])
+                    b = a if nd.op == "norm" else val(nd.inputs[1])
+                    _accumulate(oref[nd.name], jnp.sum(a * b), i)
 
-                    def sval(t):
-                        if t in vals:
-                            return vals[t]
-                        if t in red_set:
-                            return oref[t][0]
-                        if t in scal:
-                            return scal[t]
-                        return cref[t][0]
-                    for nd in epi_nodes:
-                        vals[nd.name] = eval_node(
-                            nd, [sval(t) for t in nd.inputs])
-                    for n in sca_out_set:
-                        oref[n][0] = vals[n] if n in vals else scal[n]
+        def stream_spec(shape):
+            if len(shape) == 1:
+                return pl.BlockSpec((1, tr), lambda i: (0, i))
+            return pl.BlockSpec((tr,) + shape[1:],
+                                lambda i: (i,) + (0,) * (len(shape) - 1))
 
-        in_specs, out_specs, out_shape = self._specs(dtype)
+        def full_spec(shape):
+            shape = _row_shape(shape)
+            return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+
+        def tile_spec(name):
+            if not name.endswith("@vals"):      # rows' slot bounds
+                return pl.BlockSpec((1, tr), lambda i: (0, i))
+            return pl.BlockSpec((None, 1, self.shapes[name][-1]),
+                                lambda i: (i, 0, 0))
+
+        smem = pl.BlockSpec((1, 1), lambda i: (0, 0),
+                            memory_space=pltpu.SMEM)
+        in_specs = ([stream_spec(self.shapes[n]) for n in self.stream_in]
+                    + [tile_spec(n) for n in self.tile_in]
+                    + [full_spec(self.shapes[n]) for n in self.res_in]
+                    + [smem] * len(self.scalar_in))
+        out_specs = ([smem] * len(self.red_out)
+                     + [stream_spec(self.shapes[n])
+                        for n in self.stream_out])
+        out_shape = ([jax.ShapeDtypeStruct((1, 1), dtype)
+                      for _ in self.red_out]
+                     + [jax.ShapeDtypeStruct(_row_shape(self.shapes[n]),
+                                             dtype)
+                        for n in self.stream_out])
         return pl.pallas_call(
             kernel, grid=(n_tiles,), in_specs=in_specs,
             out_specs=out_specs, out_shape=out_shape,
-            **_pallas_call_kwargs(use_interpret()))
+            **_pallas_call_kwargs(f"cello_stream_{self.sp.ops[0]}", dtype,
+                                  self.sp.vmem_bytes, 1))
 
     # -- drivers --------------------------------------------------------
     def apply(self, env: Dict[str, Any], dtype) -> Dict[str, Any]:
         """Run (or trace) this pass over ``env`` at a resolved ``dtype``."""
         import jax.numpy as jnp
-        call = self._built.get(dtype)
-        if call is None:
-            call = self._built[dtype] = self._build(dtype)
+        vals: Dict[str, Any] = {}
 
-        def arr(n):
-            b = self.arranged.get(n)
-            if b is not None:       # padded per-tile CSR blocks
-                return b(env, dtype)
-            d = self.derived.get(n)
-            if d is not None:       # per-entry CSR row ids, from indptr
-                indptr, nnz = d
-                return csr_row_ids(jnp.asarray(env[indptr]), nnz)
-            v = jnp.asarray(env[n])
-            if jnp.issubdtype(v.dtype, jnp.integer):
-                return v            # CSR indptr/indices stay integer
-            return jnp.asarray(v, dtype)
+        def get(n):
+            return vals[n] if n in vals else env[n]
 
-        args = ([arr(n) for n in self.stream_in]
-                + [arr(n) for n in self.tail_in]
-                + [arr(n) for n in self.res_in]
-                + [jnp.reshape(jnp.asarray(env[n], dtype), (1,))
-                   for n in self.scalar_in])
-        outs = call(*args)
-        names = self.red_out + self.sca_out + self.stream_out
-        keep = (self.needed | set(self.red_out)) if self.defer \
-            else self.needed
-        result = {}
-        for n, v in zip(names, outs):
-            if n in keep:
-                result[n] = v[0] if self.shapes[n] == () else v
-        return result
+        for nd in self.eager:
+            vals[nd.name] = eval_node(nd, [get(t) for t in nd.inputs])
+        if self.red_out or self.stream_out:
+            call = self._built.get(dtype)
+            if call is None:
+                call = self._built[dtype] = self._build(dtype)
+            tile_args = []
+            for lay, x in self.spmv.values():
+                cols, data, first, stop = (
+                    env[lay] if lay in env else self.derived[lay](env, dtype))
+                tile_args += [data * jnp.asarray(env[x], dtype)[cols],
+                              first, stop]
+            row = [jnp.reshape(jnp.asarray(env[n], dtype),
+                               _row_shape(self.shapes[n]))
+                   for n in self.stream_in + self.res_in]
+            args = (row[:len(self.stream_in)] + tile_args
+                    + row[len(self.stream_in):]
+                    + [jnp.reshape(jnp.asarray(get(n), dtype), (1, 1))
+                       for n in self.scalar_in])
+            outs = call(*args)
+            for n, v in zip(self.red_out + self.stream_out, outs):
+                vals[n] = jnp.reshape(v, self.shapes[n])
+        if not self.defer:
+            for n in self.norm_reductions:
+                vals[n] = jnp.sqrt(vals[n])
+            for nd in self.epilogue:
+                vals[nd.name] = eval_node(nd, [get(t) for t in nd.inputs])
+            keep = self.needed
+        else:           # the caller finalizes: partials + eager scalars
+            keep = (self.needed | set(self.red_out)
+                    | {nd.name for nd in self.eager})
+        return {n: v for n, v in vals.items() if n in keep}
 
     @property
     def finalize_nodes(self):
-        """The scalar (eager + epilogue) nodes a deferring driver must
-        replay after combining reduction partials, in pass order."""
-        return [nd for nd in self.nodes
-                if self.classes[nd.name] in ("eager", "epilogue")]
+        """The epilogue nodes a deferring caller must replay after
+        combining reduction partials, in pass order."""
+        return list(self.epilogue)
 
     @property
     def norm_reductions(self) -> Set[str]:
-        """Reduction outputs that are *squared* partials when deferred
-        (the sqrt applies after the cross-shard sum)."""
+        """Reduction outputs that are *squared* partials until the sqrt
+        (after the pass, or after the cross-shard sum when deferred)."""
         return {nd.name for nd in self.nodes
                 if nd.op == "norm" and self.classes[nd.name] == "reduce"}
 
@@ -592,53 +467,36 @@ class _StreamCall:
         return self.apply(env, dtype)
 
 
-def _spmv_row_tile(row_of, indices, data, x, row0, tile_rows, dtype):
-    """CSR SpMV for the output rows ``[row0, row0 + tile_rows)``.
+def _csr_tiles_fn(indptr: str, indices: str, data: str, tile_rows: int,
+                  entries: int) -> Callable:
+    """A function building one CSR operand's padded per-tile layout:
+    column ids and values as ``(tiles, 1, entries)`` windows, each
+    starting at its tile's first entry, and every row's slot range
+    ``[first, stop)`` inside its tile's window as ``(1, rows)`` vectors.
+    Slots past a tile's last row hold the next tile's entries (or zeros),
+    which no row's range covers.  Windows are contiguous slices, so the
+    layout needs no per-entry search."""
+    def build(env, dtype):
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+        ip = jnp.asarray(env[indptr])
+        rows = ip.shape[0] - 1
+        start = ip[:-1:tile_rows]                     # (tiles,)
+        base = jnp.broadcast_to(start[:, None], (rows // tile_rows,
+                                                 tile_rows)).reshape(rows)
 
-    The whole CSR operand and ``x`` are VMEM-resident (rows are ragged
-    and column access is data-dependent — nothing of the operand
-    streams); ``row_of`` is the per-entry row-id array, derived from
-    indptr once per dispatch (``csr_row_ids``) rather than per grid
-    step.  Each step keeps only its own rows' contributions via a mask
-    and a per-tile segment sum, so per-row summation order matches the
-    reference rule exactly.
-    """
-    import jax
-    import jax.numpy as jnp
-    contrib = (data * jnp.take(x, indices, axis=0)).astype(dtype)
-    local = row_of - row0
-    in_tile = (local >= 0) & (local < tile_rows)
-    return jax.ops.segment_sum(
-        jnp.where(in_tile, contrib, jnp.zeros((), dtype)),
-        jnp.clip(local, 0, tile_rows - 1), num_segments=tile_rows)
+        def windows(a):
+            # padded so no window is clamped back into the array
+            a = jnp.concatenate([a, jnp.zeros((entries,), a.dtype)])
+            return jax.vmap(lambda s: lax.dynamic_slice(
+                a, (s,), (entries,)))(start)[:, None, :]
 
-
-def _spmv_sliced_tile(am, tref, rref, x, i, tile_rows, dtype):
-    """CSR SpMV tile for a prefix-sliced (overbooked-pin) operand.
-
-    Entries live in a padded per-tile layout ``(tiles, budget)``: the
-    resident row-prefix blocks sit in VMEM across every grid step
-    (constant index maps, dynamically indexed by the step id) while
-    spill-tail blocks stream one ``(1, budget)`` slice per step.  Tile
-    boundaries are row boundaries, so per-row summation order matches
-    the reference rule; padding carries ``data == 0`` and contributes
-    nothing.  Per-step work is ``O(budget)`` — the whole-resident
-    kernel's masked scan over all ``nnz`` entries never happens here.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    td, tc, tw = (tref[n][...][0] for n in am["tail"])
-    if am["pre"]:
-        j = jnp.minimum(i, am["p"] - 1)
-        pd, pc, pw = (pl.load(r_, (pl.dslice(j, 1), slice(None)))[0]
-                      for r_ in (rref[n] for n in am["pre"]))
-        use_pre = i < am["p"]
-        td = jnp.where(use_pre, pd, td)
-        tc = jnp.where(use_pre, pc, tc)
-        tw = jnp.where(use_pre, pw, tw)
-    contrib = (td * jnp.take(x, tc, axis=0)).astype(dtype)
-    return jax.ops.segment_sum(contrib, tw, num_segments=tile_rows)
+        first = (ip[:-1] - base)[None, :].astype(jnp.int32)
+        stop = (ip[1:] - base)[None, :].astype(jnp.int32)
+        return (windows(jnp.asarray(env[indices])),
+                windows(jnp.asarray(env[data], dtype)), first, stop)
+    return build
 
 
 def _accumulate(ref, part, i):
@@ -646,20 +504,11 @@ def _accumulate(ref, part, i):
 
     @pl.when(i == 0)
     def _():
-        ref[0] = part
+        ref[0, 0] = part
 
     @pl.when(i > 0)
     def _():
-        ref[0] = ref[0] + part
-
-
-def _sqrt_at(ref, cond):
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    @pl.when(cond)
-    def _():
-        ref[0] = jnp.sqrt(ref[0])
+        ref[0, 0] = ref[0, 0] + part
 
 
 def _group_io(program, nodes, needed: Set[str]):
@@ -680,36 +529,49 @@ class _BlockCall:
         self.nodes = [program.nodes[o] for o in ops]
         self.in_names, self.out_names = _group_io(program, self.nodes,
                                                   needed)
-        self.shapes = {n: program.nodes[n].shape
+        self.shapes = {n: tuple(program.nodes[n].shape)
                        for nd in self.nodes for n in (*nd.inputs, nd.name)}
         self._built: Dict[Any, Callable] = {}
 
     def _build(self, dtype):
         import jax
         from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
         n_in = len(self.in_names)
+        scalars = {n for n in self.in_names if self.shapes[n] == ()}
 
         def kernel(*refs):
-            vals = {n: r[...] for n, r in zip(self.in_names, refs[:n_in])}
+            vals = {n: r[0, 0] if n in scalars else r[...]
+                    for n, r in zip(self.in_names, refs[:n_in])}
             for nd in self.nodes:
                 vals[nd.name] = eval_node(nd,
                                           [vals[t] for t in nd.inputs])
             for n, r in zip(self.out_names, refs[n_in:]):
                 r[...] = vals[n]
 
+        planned = 2 * sum(kernel_block_bytes(self.shapes[n])
+                          for n in (*self.in_names, *self.out_names))
         return pl.pallas_call(
             kernel,
-            out_shape=[jax.ShapeDtypeStruct(self.shapes[n], dtype)
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM if n in scalars
+                                   else pltpu.VMEM)
+                      for n in self.in_names],
+            out_shape=[jax.ShapeDtypeStruct(_row_shape(self.shapes[n]),
+                                            dtype)
                        for n in self.out_names],
-            **_pallas_call_kwargs(use_interpret()))
+            **_pallas_call_kwargs(f"cello_block_{self.nodes[0].name}",
+                                  dtype, planned, 0))
 
     def apply(self, env: Dict[str, Any], dtype) -> Dict[str, Any]:
         import jax.numpy as jnp
         call = self._built.get(dtype)
         if call is None:
             call = self._built[dtype] = self._build(dtype)
-        outs = call(*[jnp.asarray(env[n], dtype) for n in self.in_names])
-        return dict(zip(self.out_names, outs))
+        outs = call(*[jnp.reshape(jnp.asarray(env[n], dtype),
+                                  _row_shape(self.shapes[n]))
+                      for n in self.in_names])
+        return {n: jnp.reshape(v, self.shapes[n])
+                for n, v in zip(self.out_names, outs)}
 
     def __call__(self, env: Dict[str, Any]) -> Dict[str, Any]:
         import jax.numpy as jnp
@@ -823,6 +685,7 @@ class _SingleProgram:
         kernels = _plan_kernels(plan, groups)
         ep = _plan_exec(plan, program, kernels)
         self.exec_plan = ep
+        self._program = program
         units, roll = ep.units, ep.roll
         needed, _ = _unit_needed(program, units)
         if roll is not None:
@@ -859,7 +722,7 @@ class _SingleProgram:
             reads = {sl.read for sl in roll.slots if sl.read is not None}
             ext: List[str] = []
             for call in self._tmpl:
-                for n in call.in_names:
+                for n in (*call.in_names, *getattr(call, "derived", ())):
                     if n not in tmpl_ops and n not in reads \
                             and n not in ext:
                         ext.append(n)
@@ -902,6 +765,12 @@ class _SingleProgram:
         for name, v in zip(self.leaf_names, leaf_vals):
             env[name] = (jnp.asarray(v, dtype)
                          if jnp.issubdtype(v.dtype, jnp.floating) else v)
+        # loop-invariant layouts (CSR per-tile entries) once per dispatch,
+        # outside any rolled loop
+        for call in (*self._pro, *self._tmpl, *self._epi):
+            for name, build in getattr(call, "derived", {}).items():
+                if name not in env:
+                    env[name] = build(env, dtype)
         for call in self._pro:
             env.update(call.apply(env, dtype))
         if self.roll is not None:
@@ -954,6 +823,8 @@ class _SingleProgram:
         for leaf in self.leaf_names:
             if leaf not in feeds:
                 raise KeyError(f"feeds missing leaf {leaf!r}")
+        check_csr_feeds(self.exec_plan.units, self._program, feeds)
+        for leaf in self.leaf_names:
             v = feeds[leaf]
             if self._donate:
                 import jax
@@ -1036,6 +907,7 @@ class PerUnitPallasExecutor(Executor):
                 if leaf not in feeds:
                     raise KeyError(f"feeds missing leaf {leaf!r}")
                 env[leaf] = jnp.asarray(feeds[leaf])
+            check_csr_feeds(units, program, env)
             for ui, call in enumerate(calls):
                 env.update(call(env))
                 for t in [t for t, lu in last_use.items() if lu == ui]:

@@ -24,8 +24,8 @@ from .base import Executor, plan_order, plan_program
 
 def csr_row_ids(indptr, nnz: int):
     """Row id of every stored CSR entry, from ``indptr`` — the one rule
-    both the reference spmv and the pallas ``spmv-stream`` kernel use, so
-    their per-row summation order can never drift apart."""
+    both the reference spmv and the sharded spmv use, so their
+    per-row summation order can never drift apart."""
     import jax.numpy as jnp
     return jnp.searchsorted(indptr, jnp.arange(nnz, dtype=indptr.dtype),
                             side="right") - 1

@@ -51,7 +51,7 @@ from types import SimpleNamespace
 from typing import Any, Dict, List, Set
 
 from .. import obs
-from ..launch.mesh import make_solver_mesh, shard_map_compat
+from ..launch.mesh import make_solver_mesh
 from .base import plan_program
 from .pallas import (_DISPATCHES, _TRACES, _UNITS, _StreamCall,
                      _unit_needed)
@@ -298,28 +298,46 @@ def _local_view(program, sharded):
 
 class _InlineUnit:
     """A block/jnp unit inlined into the shard body: reference rules per
-    op, stencil sweeps through the halo exchange.  (Sharded plans skip
-    ``_BlockCall``: a whole-array pallas block would need the full grid,
-    which is exactly what sharding removes.)"""
+    op on the shard's rows, stencil sweeps through the halo exchange, and
+    rank-0 reductions ``psum``-combined across the mesh.  (Sharded plans
+    skip ``_BlockCall``: a whole-array pallas block would need the full
+    grid, which is exactly what sharding removes.)"""
 
     def __init__(self, view, ops, needed: Set[str], halo: Set[str],
                  axis: str, n_shards: int):
         from .pallas import _group_io
 
         self.nodes = [view.nodes[o] for o in ops]
-        self.in_names, self.out_names = _group_io(view, self.nodes,
-                                                  needed)
+        in_names, self.out_names = _group_io(view, self.nodes, needed)
+        # a value gathered whole after this unit produced it is gathered
+        # here, not before the unit runs
+        produced = {nd.name for nd in self.nodes}
+        self.in_names = [n for n in in_names
+                         if not (n.endswith("@g") and n[:-2] in produced)]
         self.halo = halo
         self.axis = axis
         self.n_shards = n_shards
 
     def apply(self, env: Dict[str, Any], dtype=None) -> Dict[str, Any]:
+        import jax.numpy as jnp
+        from jax import lax
         vals = {n: env[n] for n in self.in_names}
         for nd in self.nodes:
+            for t in nd.inputs:
+                if t not in vals:           # gathered in-unit product
+                    vals[t] = lax.all_gather(vals[t[:-2]], self.axis,
+                                             tiled=True)
             if nd.name in self.halo:
                 vals[nd.name] = _stencil_shard(
                     nd, [vals[t] for t in nd.inputs], self.axis,
                     self.n_shards)
+            elif nd.op == "norm":
+                x = jnp.ravel(vals[nd.inputs[0]])
+                vals[nd.name] = jnp.sqrt(lax.psum(jnp.dot(x, x), self.axis))
+            elif nd.op == "dot" or (nd.op in ("matmul", "einsum")
+                                    and nd.shape == ()):
+                vals[nd.name] = lax.psum(
+                    eval_node(nd, [vals[t] for t in nd.inputs]), self.axis)
             else:
                 vals[nd.name] = eval_node(nd,
                                           [vals[t] for t in nd.inputs])
@@ -359,14 +377,12 @@ class ShardedProgram:
 
         view = _local_view(program, sharded)
         halo = set(sharded.halo)
-        g_rename = {g: g + "@g" for g in sharded.gathered}
 
         def build(i):
             u = units[i]
             if u.kind == "stream":
                 return _StreamCall(view, u.sp, needed[i],
-                                   defer_finalize=True,
-                                   resident_rename=g_rename)
+                                   defer_finalize=True)
             return _InlineUnit(view, u.ops, needed[i], halo,
                                sharded.axis, sharded.n_shards)
 
@@ -402,9 +418,11 @@ class ShardedProgram:
         mesh = make_solver_mesh(sharded.n_shards, axis=sharded.axis)
         # no donation: the replicated CSR triples and gathered operands
         # outlive their first read inside the shard body
-        self._jit = jax.jit(shard_map_compat(self._traced, mesh,
-                                             tuple(in_specs),
-                                             tuple(out_specs)))
+        # the replication check cannot see through pallas calls mixed
+        # with collectives, so it stays off
+        self._jit = jax.jit(jax.shard_map(
+            self._traced, mesh=mesh, in_specs=tuple(in_specs),
+            out_specs=tuple(out_specs), check_vma=False))
 
     @property
     def stats(self) -> Dict[str, int]:

@@ -132,16 +132,17 @@ def _components(pattern: str, n: int, density: Optional[float],
     data = np.empty(nnz, np.float64)
 
     if pattern == "laplacian5":
+        # row r = (i, j) holds columns r-g, r-1, r, r+1, r+g in that
+        # order, each present when the grid neighbour exists
         g = _grid_side(n)
-        pos = 0
-        for r in range(n):
-            i, j = divmod(r, g)
-            cols = [r - g] * (i > 0) + [r - 1] * (j > 0) + [r] \
-                + [r + 1] * (j < g - 1) + [r + g] * (i < g - 1)
-            k = len(cols)
-            indices[pos:pos + k] = cols
-            data[pos:pos + k] = np.where(np.asarray(cols) == r, 4.0, -1.0)
-            pos += k
+        r = np.arange(n)
+        i, j = np.divmod(r, g)
+        offsets = np.array([-g, -1, 0, 1, g])
+        present = np.stack([i > 0, j > 0, np.ones(n, bool), j < g - 1,
+                            i < g - 1], axis=1)
+        indices[:] = (r[:, None] + offsets[None, :])[present]
+        data[:] = np.broadcast_to(np.where(offsets == 0, 4.0, -1.0),
+                                  (n, 5))[present]
     elif pattern == "banded":
         # symmetric off-diagonal values: v(i, j) = V[min(i, j), |i - j|]
         V = rng.standard_normal((n, bandwidth + 1))

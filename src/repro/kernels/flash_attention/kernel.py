@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -132,7 +130,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((q_block, 1), jnp.float32),       # running denom
             pltpu.VMEM((q_block, E), jnp.float32),       # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 
 def _act(h: jnp.ndarray, kind: str) -> jnp.ndarray:
     if kind == "silu":
@@ -102,7 +100,7 @@ def fused_mlp(x: jnp.ndarray, w_gate: Optional[jnp.ndarray],
     wrow_spec = pl.BlockSpec((f_block, D), lambda im, jf: (jf, 0))
     o_spec = pl.BlockSpec((m_block, D), lambda im, jf: (im, 0))
     scratch = [pltpu.VMEM((m_block, D), jnp.float32)]
-    params = CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"))
 
     if w_gate is not None:

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 from .ref import RGLRU_C
 
 
@@ -76,7 +74,7 @@ def rglru(x: jnp.ndarray, gate_r: jnp.ndarray, gate_i: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct((B, S, Dp), x.dtype),
                    jax.ShapeDtypeStruct((B, Dp), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((1, d_block), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, gate_r, gate_i, ap2, h0)

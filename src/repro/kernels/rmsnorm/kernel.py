@@ -14,8 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -40,7 +39,7 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
                   pl.BlockSpec((1, D), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((row_block, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, D), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w[None, :])

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
                  s_scr, *, seq_len: int):
@@ -64,7 +62,7 @@ def wkv6(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, w: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct((B, H, S, E), r.dtype),
                    jax.ShapeDtypeStruct((B, H, E, E), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((E, E), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(r, k, v, w, u, s0)

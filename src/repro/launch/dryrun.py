@@ -51,6 +51,11 @@ from .roofline import model_flops, parse_collectives, roofline
 from .train import TrainConfig, jit_train_step
 
 
+#: the chip the production meshes stand for (the placeholder host devices
+#: report the CPU, so the roofline is costed against the target's peaks)
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
 def _plan_for(cfg: ArchConfig, shape: ShapeSpec, attention: str,
               ) -> CelloPlan:
     plan = Session(cfg).default_plan(seq=shape.seq_len).plan
@@ -151,7 +156,8 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, *,
 
     terms = roofline(float(ca.get("flops", 0.0)),
                      float(ca.get("bytes accessed", 0.0)),
-                     coll["total"], n_chips, model_flops(cfg, shape))
+                     coll["total"], n_chips, model_flops(cfg, shape),
+                     device_kind=TARGET_DEVICE_KIND)
     result = {
         "arch": arch, "shape": shape_name,
         "mesh": "multi" if multi_pod else "single",

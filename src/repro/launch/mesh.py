@@ -40,21 +40,3 @@ def make_solver_mesh(n_shards: int, *, axis: str = "shards") -> Mesh:
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n_shards})")
     import numpy as np
     return Mesh(np.array(devs[:n_shards]), (axis,))
-
-
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """`shard_map` across the jax versions this repo supports: the entry
-    point moved from ``jax.experimental.shard_map`` to ``jax.shard_map``,
-    and the replication-check kwarg was renamed ``check_rep`` ->
-    ``check_vma``.  The check is disabled either way: solver shard bodies
-    mix pallas calls and collectives the checker cannot see through."""
-    import inspect
-    try:
-        from jax import shard_map                          # jax >= 0.6
-    except ImportError:                                    # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-    params = inspect.signature(shard_map).parameters
-    kw = ({"check_vma": False} if "check_vma" in params
-          else {"check_rep": False})
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)

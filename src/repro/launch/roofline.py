@@ -1,7 +1,7 @@
 """Roofline accounting from compiled dry-run artifacts.
 
-Hardware constants (TPU v5e-class, also used by core.costmodel):
-  197 TFLOP/s bf16 per chip · 819 GB/s HBM · ~50 GB/s/link ICI.
+Per-chip peaks come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``;
+a device kind missing from the table is an error, never a default.
 
 Conventions:
   * XLA's post-SPMD module is per-device, so cost_analysis flops/bytes are
@@ -27,9 +27,28 @@ from typing import Dict
 
 from ..configs.base import ArchConfig, ShapeSpec
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float            # dense bf16 FLOP/s
+    hbm_bw: float           # HBM bytes/s
+    ici_bw: float           # bytes/s over one inter-chip link
+
+
+#: per-chip peaks by ``device_kind``.  TPU v5e (reported as "TPU v5 lite"):
+#: 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links (Google
+#: Cloud documentation, "TPU v5e").
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; raises for a kind not in the table."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak figures for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8, "c64": 8,
@@ -153,11 +172,13 @@ class RooflineTerms:
 
 def roofline(flops_per_chip: float, bytes_per_chip: float,
              coll_bytes_per_chip: float, n_chips: int,
-             model_flops_total: float) -> RooflineTerms:
+             model_flops_total: float, *,
+             device_kind: str) -> RooflineTerms:
+    peaks = chip_peaks(device_kind)
     return RooflineTerms(
-        compute_s=flops_per_chip / PEAK_FLOPS,
-        memory_s=bytes_per_chip / HBM_BW,
-        collective_s=coll_bytes_per_chip / ICI_BW,
+        compute_s=flops_per_chip / peaks.flops,
+        memory_s=bytes_per_chip / peaks.hbm_bw,
+        collective_s=coll_bytes_per_chip / peaks.ici_bw,
         flops_per_chip=flops_per_chip,
         bytes_per_chip=bytes_per_chip,
         coll_bytes_per_chip=coll_bytes_per_chip,

@@ -32,6 +32,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Mapping, Optional, Sequence
 
 from .. import obs
+from ..core.lowering import check_csr_feeds
 from ..exec import get_backend
 from ..exec.base import plan_program
 from ..testing import faults
@@ -144,6 +145,9 @@ class BatchedPlan:
             if self.donate:
                 v = _own(v)
             batched_vals.append(v)
+        if self.backend == "pallas":
+            # shared CSR operands must fit the kernels' per-tile layout
+            check_csr_feeds(self.plan.exec_plan.units, self.program, feeds)
         _BP_DISPATCHES.inc(backend=self.backend, scope=self._scope)
         with obs.span("serve.batch_dispatch", backend=self.backend,
                       batch=batch):

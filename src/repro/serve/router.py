@@ -86,7 +86,7 @@ class SolveRequest:
     workload: str
     params: Tuple[Tuple[str, Any], ...] = ()
     dtype: str = "float32"
-    backend: str = "reference"
+    backend: Optional[str] = None   # None: the platform's device path
     seed: int = 0
     feeds: Optional[Mapping[str, Any]] = dataclasses.field(
         default=None, compare=False)
@@ -124,14 +124,15 @@ class SolveRequest:
         if dt.kind != "f":
             raise ValueError(f"request dtype must be a float dtype, "
                              f"got {self.dtype}")
+        from ..exec.pallas import default_solver_backend
         return BucketKey(workload=self.workload,
                          params=tuple(sorted(params.items())),
                          dtype=dt.name, density=dlabel,
-                         backend=self.backend)
+                         backend=self.backend or default_solver_backend())
 
 
 def request(workload: str, *, dtype: str = "float32",
-            backend: str = "reference", seed: int = 0,
+            backend: Optional[str] = None, seed: int = 0,
             feeds: Optional[Mapping[str, Any]] = None,
             deadline_s: Optional[float] = None,
             **params) -> SolveRequest:
@@ -139,6 +140,9 @@ def request(workload: str, *, dtype: str = "float32",
 
         request("cg", n=256, iters=4, seed=7)
         request("cg_sparse", n=256, density=1e-3, dtype="float64")
+
+    ``backend=None`` serves on the compiled kernels (``"pallas"``) on a
+    TPU and on the ``"reference"`` oracle elsewhere.
     """
     dt = np.dtype(dtype)
     if dt.kind != "f":

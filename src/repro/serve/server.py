@@ -787,7 +787,7 @@ class Server:
         import contextlib
         if key.dtype == "float64":
             import jax
-            x64 = jax.experimental.enable_x64()
+            x64 = jax.enable_x64(True)
         else:
             x64 = contextlib.nullcontext()
         with x64:

@@ -71,11 +71,17 @@ def test_padded_vocab_shards_over_tp():
 def test_roofline_terms_math():
     t = roofline(flops_per_chip=197e12, bytes_per_chip=819e9,
                  coll_bytes_per_chip=0.0, n_chips=256,
-                 model_flops_total=197e12 * 256)
+                 model_flops_total=197e12 * 256, device_kind="TPU v5 lite")
     assert t.compute_s == pytest.approx(1.0)
     assert t.memory_s == pytest.approx(1.0)
     assert t.dominant in ("compute", "memory")
     assert t.useful_flops_ratio == pytest.approx(1.0)
+
+
+def test_roofline_unknown_device_kind_raises():
+    # peaks are per device kind: an unlisted chip (or the CPU) has none
+    with pytest.raises(KeyError, match="no peak figures"):
+        roofline(1.0, 1.0, 0.0, 1, 1.0, device_kind="cpu")
 
 
 def test_model_flops_modes():
@@ -109,3 +115,34 @@ def test_production_mesh_requires_512(monkeypatch):
     from repro.launch.mesh import make_production_mesh
     with pytest.raises(Exception):
         make_production_mesh(multi_pod=True)
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir_is_env_or_fixed_checkout_dir(monkeypatch,
+                                                        tmp_path, env_dir):
+    """The persistent compile cache sits where $JAX_COMPILATION_CACHE_DIR
+    says, else at one fixed directory of the checkout — never a path that
+    changes between runs."""
+    import jax
+    from repro.runtime import compile_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        again = compile_cache.enable_compile_cache()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+    assert got == again
+    if env_dir:
+        assert got == str(tmp_path / env_dir)
+    else:
+        assert got == str(compile_cache.CHECKOUT_CACHE_DIR)
+        assert compile_cache.CHECKOUT_CACHE_DIR.parent == \
+            compile_cache.pathlib.Path(__file__).resolve().parents[1]

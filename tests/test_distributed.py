@@ -74,17 +74,8 @@ def sync(grads, err):
     total = jax.lax.psum(sent, "pod") / jax.lax.psum(1.0, "pod")
     return total, new_err
 
-import inspect
-try:
-    from jax import shard_map
-except ImportError:                      # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
-# the replication-check kwarg was renamed check_rep -> check_vma; pick
-# whichever this shard_map actually accepts
-sig = inspect.signature(shard_map).parameters
-kw = {"check_vma": False} if "check_vma" in sig else {"check_rep": False}
-f = shard_map(sync, mesh=mesh, in_specs=(P("pod"), P("pod")),
-              out_specs=(P(None), P("pod")), **kw)
+f = jax.shard_map(sync, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                  out_specs=(P(None), P("pod")), check_vma=False)
 
 rng = np.random.default_rng(0)
 g = jnp.asarray(rng.standard_normal((2, 1024)) * 0.01, jnp.float32)

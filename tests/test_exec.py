@@ -170,7 +170,7 @@ class TestFeedsDtype:
         import jax
         prog = build_workload("cg", n=32, iters=2)
         feeds = make_feeds(prog, seed=1, dtype=np.float64)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             out = evaluate(prog, feeds)
             assert np.asarray(out["x2"]).dtype == np.float64
             # fp64 CG at n=32 is essentially exact: residual identity holds
@@ -542,6 +542,22 @@ class TestSingleProgram:
         monkeypatch.setenv("CELLO_PALLAS_INTERPRET", "0")
         assert pal.use_interpret() is False
 
+    def test_interpret_mode_follows_the_platform(self, monkeypatch):
+        import repro.exec.pallas as pal
+        monkeypatch.delenv("CELLO_PALLAS_INTERPRET", raising=False)
+        monkeypatch.setattr(pal, "_BACKEND_PROBE", "cpu")
+        assert pal.use_interpret() is True
+        assert pal.default_solver_backend() == "reference"
+        monkeypatch.setattr(pal, "_BACKEND_PROBE", "tpu")
+        assert pal.use_interpret() is False
+        assert pal.default_solver_backend() == "pallas"
+        # on the chip the env var may force Mosaic, never interpretation
+        monkeypatch.setenv("CELLO_PALLAS_INTERPRET", "0")
+        assert pal.use_interpret() is False
+        monkeypatch.setenv("CELLO_PALLAS_INTERPRET", "1")
+        with pytest.raises(RuntimeError, match="interpret mode on a TPU"):
+            pal.use_interpret()
+
     def test_perunit_backend_matches_single_program(self, tmp_path):
         traced, plan = _lowered(tmp_path, workload="bicgstab", n=64,
                                 iters=2)
@@ -560,35 +576,46 @@ class TestSingleProgram:
 
 class TestTileBudget:
     def test_resident_over_budget_degrades_to_finest_tile(self):
-        # resident operands already exceed the explicit budget: stream at
-        # the finest granularity rather than blowing the region (or
-        # producing a zero/negative tile)
+        # resident operands already exceed the VMEM budget: no tile fits,
+        # and the pass degrades to a jnp unit whose reason says so
         assert _pick_tile_rows(1024, per_row_bytes=8192,
                                resident_bytes=2 << 20,
-                               explicit_bytes=1 << 20) == 1
+                               budget=1 << 20) is None
         assert _pick_tile_rows(96, per_row_bytes=1 << 30,
                                resident_bytes=0,
-                               explicit_bytes=1 << 20) == 1
+                               budget=1 << 20) is None
+        p = Program("wide_rhs")
+        x = p.input("x", (256, 8192))
+        w = p.operator("w", (8192, 8192))            # 256 MiB resident
+        p.output(p.matmul(x, w, name="y"))
+        graph = p.to_graph()
+        (gk,) = select_group_kernels(graph, [["y"]], 64 << 20)
+        assert gk.kind == "jnp" and "VMEM" in gk.reason
 
     def test_budget_boundary_is_inclusive(self):
-        # budget exactly equal to the working set of a candidate: taken
+        # double-buffered working set exactly equal to the budget: taken
         rows, per_row = 1024, 1024
-        assert _pick_tile_rows(rows, per_row, 0, 256 * per_row) == 256
-        assert _pick_tile_rows(rows, per_row, 0, 256 * per_row - 1) == 128
+        assert _pick_tile_rows(rows, per_row, 0, 2 * 256 * per_row) == 256
+        assert _pick_tile_rows(rows, per_row, 0,
+                               2 * 256 * per_row - 1) == 128
         # resident bytes eat the budget down to the boundary
         assert _pick_tile_rows(rows, per_row, 256 * per_row,
-                               512 * per_row) == 256
+                               2 * 512 * per_row) == 256
 
     def test_prime_row_count_still_positive(self):
+        # no lane-aligned divisor: the whole pass is the only legal tile
+        assert _pick_tile_rows(97, per_row_bytes=32, resident_bytes=0,
+                               budget=1 << 20) == 97
         assert _pick_tile_rows(97, per_row_bytes=1 << 30,
-                               resident_bytes=1 << 30,
-                               explicit_bytes=0) == 1
+                               resident_bytes=1 << 30, budget=0) is None
 
     def test_tiles_always_positive_divisors(self):
-        for rows in (1, 2, 50, 96, 97, 1024):
-            for explicit in (0, 1 << 10, 1 << 20):
-                t = _pick_tile_rows(rows, 4096, 1 << 22, explicit)
-                assert t >= 1 and rows % t == 0
+        for rows in (1, 2, 50, 96, 97, 1024, 1152, 4096):
+            for budget in (0, 1 << 10, 1 << 20, 1 << 25):
+                t = _pick_tile_rows(rows, 32, 1 << 12, budget)
+                if t is not None:
+                    assert t >= 1 and rows % t == 0
+                    assert t % 128 == 0 or t == rows     # Mosaic-legal
 
     def test_zero_explicit_budget_plan_still_streams_and_matches(
             self, tmp_path):

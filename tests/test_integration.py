@@ -137,7 +137,8 @@ ca = compiled.cost_analysis()
 ca = ca[0] if isinstance(ca, list) else ca
 coll = parse_collectives(compiled.as_text())
 terms = roofline(ca.get("flops", 0.0), ca.get("bytes accessed", 0.0),
-                 coll["total"], 8, model_flops(cfg, SHAPES["train_4k"]))
+                 coll["total"], 8, model_flops(cfg, SHAPES["train_4k"]),
+                 device_kind="TPU v5 lite")
 print(json.dumps({"ok": True, "flops": ca.get("flops", 0.0),
                   "coll_total": coll["total"],
                   "dominant": terms.dominant,

@@ -130,7 +130,8 @@ class TestFallbackChain:
             srv = Server(session=Session(cache_dir=tmp_path),
                          max_batch_size=4, max_wait_us=500,
                          autostart=False, breaker_failures=2,
-                         retry=RetryPolicy(max_retries=1, backoff_s=0.001))
+                         retry=RetryPolicy(max_retries=1, backoff_s=0.001),
+                         fallback="reference")
             with ctx:
                 futs = [srv.submit(request("cg", n=32, iters=2, seed=s,
                                            backend=backend))
@@ -167,7 +168,7 @@ class TestFallbackChain:
     def test_breaker_opens_and_is_visible_in_stats(self, tmp_path):
         srv = Server(session=Session(cache_dir=tmp_path), max_batch_size=2,
                      max_wait_us=200, breaker_failures=2,
-                     breaker_reset_s=60.0)
+                     breaker_reset_s=60.0, fallback="reference")
         with faults.inject("exec.compile@pallas", kind="fail") as rule:
             # each solve is its own failed batch: 2 failures open the
             # breaker; later batches skip pallas entirely
@@ -198,6 +199,23 @@ class TestFallbackChain:
             with pytest.raises(CircuitOpen):
                 srv.solve(request("cg", n=32, iters=2, seed=1,
                                   backend="pallas"))
+        srv.close()
+
+    def test_float64_on_mosaic_fails_typed_without_fallback(
+            self, tmp_path, monkeypatch):
+        # kernels compiled by Mosaic run fp32: a float64 bucket is refused
+        # before it reaches the compiler, and no other backend answers
+        # unless the caller configured a fallback
+        from repro.exec.pallas import KernelDtypeError
+        from repro.serve import ServeConfig
+        monkeypatch.setenv("CELLO_PALLAS_INTERPRET", "0")
+        srv = Server(session=Session(cache_dir=tmp_path),
+                     config=ServeConfig(max_batch_size=1, fallback=None))
+        with pytest.raises(KernelDtypeError):
+            srv.solve(request("cg", n=32, iters=2, dtype="float64",
+                              backend="pallas"))
+        st = srv.stats()
+        assert st["fallbacks"] == 0 and st["errors"] == 1
         srv.close()
 
     def test_transient_failure_recovered_by_retry_not_fallback(

@@ -69,7 +69,7 @@ class TestBatchedParity:
 
     def test_sparse_bitwise_fp64(self, tmp_path):
         import jax
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             traced, plan = _plan(tmp_path, "cg_sparse", n=64, iters=2)
             bp = plan.batched()
             shared, per_req = _batch_feeds(bp, traced.program, 4,
@@ -86,7 +86,7 @@ class TestBatchedParity:
     def test_dense_cg_close(self, tmp_path, fp64):
         import jax
         import contextlib
-        ctx = (jax.experimental.enable_x64() if fp64
+        ctx = (jax.enable_x64(True) if fp64
                else contextlib.nullcontext())
         dtype = np.float64 if fp64 else None
         rtol, atol = ((SERVE_RTOL64, SERVE_ATOL64) if fp64

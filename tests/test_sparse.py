@@ -4,7 +4,7 @@ Covers the contract end-to-end:
 
 * **CSR lowering goldens** — the sub-leaf triple's shapes/dtypes/bytes are
   nnz-based, spmv carries ``2·nnz`` FLOPs, and kernel selection lowers
-  spmv groups to ``spmv-stream`` passes with the whole operand resident.
+  spmv groups to stream passes over a padded per-tile entry layout.
 * **Generators** — exact nnz counts, valid CSR structure, and the
   promised numerics (laplacian5/banded SPD, random/skewed diagonally
   dominant), all against the scipy-free :func:`csr_to_dense` densifier.
@@ -22,6 +22,7 @@ import pytest
 
 from repro.api import Session
 from repro.core import select_group_kernels
+from repro.core.lowering import flatten_units
 from repro.core.reuse import analyze
 from repro.core.schedule import choose_pins, sparse_operand_groups
 from repro.frontends import (Program, build_workload, csr_to_dense,
@@ -90,10 +91,11 @@ class TestCsrLowering:
         p.output(p.dot(y, y, name="yy"))
         g = p.to_graph()
         (gk,) = select_group_kernels(g, [["y", "yy"]], 16 << 20)
-        assert gk.kind == "spmv-stream"
+        assert gk.kind == "stream"
         (sp,) = gk.passes
-        # the whole operand (CSR triple + gathered x) is resident
-        assert set(sp.resident) == {"A.indptr", "A.indices", "A.data", "x"}
+        # the spmv runs on the per-tile entry layout: nothing of the
+        # operand is held resident, x is gathered by column index
+        assert sp.spmv == ("y",) and not sp.resident
         assert sp.reductions == ("yy",)
         assert "pallas-spmv" in gk.describe()
 
@@ -105,7 +107,8 @@ class TestCsrLowering:
         y2 = p.spmv(A, y1, name="y2")                  # y1 must materialize
         p.output(y2)
         (gk,) = select_group_kernels(p.to_graph(), [["y1", "y2"]], 16 << 20)
-        assert gk.kind == "spmv-stream" and len(gk.passes) == 2
+        assert gk.kind == "stream" and len(gk.passes) == 2
+        assert [u.ops for u in flatten_units([gk])] == [("y1",), ("y2",)]
 
     def test_spmv_validation(self):
         p = Program("bad")
@@ -179,6 +182,27 @@ class TestGenerators:
         off = np.abs(D - np.diag(np.diag(D))).sum(axis=1)
         assert np.all(np.diag(D) > off - 1e-9)
 
+    def test_laplacian5_vectorized_matches_row_loop(self):
+        """The vectorized laplacian5 generator is bit-identical to the
+        per-row construction it replaced."""
+        from repro.frontends.sparse import _components
+        n, g = 4096, 64
+        indices, data = [], []
+        for r in range(n):                       # the per-row original
+            i, j = divmod(r, g)
+            cols = [r - g] * (i > 0) + [r - 1] * (j > 0) + [r] \
+                + [r + 1] * (j < g - 1) + [r + g] * (i < g - 1)
+            indices += cols
+            data += [4.0 if c == r else -1.0 for c in cols]
+        comp = _components("laplacian5", n, None, None, 0, "A")
+        np.testing.assert_array_equal(comp["indices"],
+                                      np.asarray(indices, np.int32))
+        assert comp["data"].dtype == np.float64
+        assert comp["data"].tobytes() == np.asarray(data).tobytes()
+        want_ptr = np.concatenate(
+            ([0], np.cumsum(row_counts("laplacian5", n)))).astype(np.int32)
+        np.testing.assert_array_equal(comp["indptr"], want_ptr)
+
     def test_dinv_matches_diagonal(self):
         n = 36
         prog = build_workload("jacobi_sparse", n=n, sweeps=1)
@@ -210,7 +234,7 @@ class TestSparseCG:
         import jax
         prog = build_workload("cg_sparse", n=64, iters=4)
         feeds = make_feeds(prog, seed=1, dtype=np.float64)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             vals = evaluate(prog, feeds, return_all=True)
         D = _dense_A(feeds, 64)
         x4, r4 = np.asarray(vals["x4"]), np.asarray(vals["r4"])
@@ -230,7 +254,7 @@ class TestSparseCG:
             x = p.input("x", (n,))
             p.output(p.spmv(A, x, name="y"))
             feeds = make_feeds(p, seed=5, dtype=np.float64)
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 out = evaluate(p, feeds)
             np.testing.assert_allclose(
                 np.asarray(out["y"]), _dense_A(feeds, n) @ feeds["x"],
@@ -266,7 +290,7 @@ class TestSparseParity:
         import jax
         traced, plan = _lowered(tmp_path, workload, **params)
         feeds = make_feeds(traced.program, seed=11, dtype=np.float64)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             want = evaluate(traced.program, feeds)
             pal = plan.run(feeds, backend="pallas")
         for k in want:
@@ -280,6 +304,29 @@ class TestSparseParity:
         traced, plan = _lowered(tmp_path, "cg_sparse", n=64, iters=4)
         assert plan.exec_plan.roll is not None
         assert plan.exec_plan.roll.n_iters >= 2
+
+    def test_feeds_off_the_traced_pattern_are_refused(self, tmp_path):
+        """The per-tile layout is sized from the pattern meta: a CSR feed
+        with the same nnz but its entries crowded into one row tile
+        would lose entries, so the pallas paths refuse it."""
+        n = 4096                                   # several row tiles
+        traced, plan = _lowered(tmp_path, "cg_sparse", n=n, iters=2)
+        feeds = make_feeds(traced.program, seed=0)
+        nnz = feeds["A.indices"].shape[0]
+        counts = np.ones(n, np.int64)
+        counts[0] = nnz - (n - 1)                  # row 0 holds the rest
+        feeds["A.indptr"] = np.concatenate(
+            ([0], np.cumsum(counts))).astype(np.int32)
+        feeds["A.indices"] = (np.arange(nnz) % n).astype(np.int32)
+        with pytest.raises(ValueError, match="CSR feed 'A.indptr'"):
+            plan.run(feeds, backend="pallas")
+        bp = plan.batched(backend="pallas")
+        shared = {n: feeds[n] for n in bp.shared_leaves}
+        with pytest.raises(ValueError, match="CSR feed 'A.indptr'"):
+            bp.run_many([{n: feeds[n] for n in bp.batched_leaves}], shared)
+        # the reference backend has no layout and answers as before
+        assert np.all(np.isfinite(np.asarray(
+            plan.run(feeds, backend="reference")["x2"])))
 
 
 # ---------------------------------------------------------------------------
@@ -403,8 +450,10 @@ class TestOverbookedPins:
         text = plan.explain()
         assert "pinned=prefix(rows=" in text
         assert "pin overbook" in text
-        assert any("prefix(" in gk.describe()
-                   for gk in plan.group_kernels)
+        # the prefix pin is a buffer decision; the spmv passes stream
+        # the per-tile entry layout either way
+        assert any(p.spmv for gk in plan.group_kernels
+                   for p in gk.passes)
         feeds = make_feeds(traced.program, seed=3)
         want = evaluate(traced.program, feeds)
         ref = plan.run(feeds, backend="reference")

@@ -1,0 +1,149 @@
+"""Compile-only rehearsal of the solver path for a TPU v5e (no chip needed).
+
+The TPU compiler is installed with jaxlib, so these tests compile the
+real-size programs ``chip_smoke.py`` runs — for a described ``v5e:2x2``
+topology, from shapes alone — and assert that every pallas unit reached
+Mosaic as a ``tpu_custom_call``.  They catch what interpret mode cannot:
+layouts Mosaic refuses, scalars outside SMEM, VMEM over the scoped
+limit.  Nothing here executes, so nothing here measures speed.
+
+The topology is described inside a module fixture (never at import): only
+one process may load the TPU library at a time, and the worker that runs
+this file keeps it until it exits.
+"""
+import re
+
+import numpy as np
+import pytest
+
+from repro.api import Session
+from repro.exec import get_backend
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # pragma: no cover
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compiles for a described chip cannot be read back from the
+    # persistent cache here: keep them out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Compile pallas kernels through Mosaic even though the host is CPU."""
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    monkeypatch.setenv("CELLO_PALLAS_INTERPRET", "0")
+
+
+def _plan(workload, mesh=None, **params):
+    sess = Session(use_cache=False)
+    traced = sess.trace(workload=workload, **params)
+    return traced, sess.lower(sess.codesign(traced), backend="pallas",
+                              mesh=mesh)
+
+
+def _shapes(program, names, sharding, batch=None):
+    import jax
+    import jax.numpy as jnp
+    out = []
+    for n in names:
+        nd = program.nodes[n]
+        dtype = (jnp.int32 if nd.param("role") in ("indptr", "indices")
+                 else jnp.float32)
+        shape = tuple(nd.shape) if batch is None \
+            else (batch,) + tuple(nd.shape)
+        out.append(jax.ShapeDtypeStruct(shape, dtype, sharding=sharding))
+    return out
+
+
+def _assert_every_kernel_compiled(prog, text):
+    """Each stream/block unit the program traces is a Mosaic custom call
+    (kernels are named ``cello_<kind>_<first op>``, ``vmap_``-prefixed
+    when batched).  A pass whose values nothing reads emits no kernel."""
+    from repro.exec.pallas import _BlockCall, _StreamCall
+    calls = {m for line in text.splitlines() if "tpu_custom_call" in line
+             for m in re.findall(r"%(?:vmap_)?(cello_\w+?)_?(?:\.\d+)? = ",
+                                 line)}
+    units = (*prog._pro, *prog._tmpl, *prog._epi)
+    want = {"cello_stream_" + c.sp.ops[0] for c in units
+            if isinstance(c, _StreamCall) and (c.red_out or c.stream_out)}
+    want |= {"cello_block_" + c.nodes[0].name for c in units
+             if isinstance(c, _BlockCall)}
+    assert want, "plan has no pallas units"
+    assert want <= calls, sorted(want - calls)
+
+
+def test_dense_cg_n8192_compiles(topo, mosaic):
+    from jax.sharding import SingleDeviceSharding
+    traced, plan = _plan("cg", n=8192, iters=32)
+    assert all(u.kind == "stream" for u in plan.exec_plan.units)
+    prog = get_backend("pallas").compile(plan)
+    args = _shapes(traced.program, prog.leaf_names,
+                   SingleDeviceSharding(topo.devices[0]))
+    compiled = prog._jit.lower(*args).compile()
+    _assert_every_kernel_compiled(prog, compiled.as_text())
+
+
+def test_sparse_cg_n1m_compiles(topo, mosaic):
+    from jax.sharding import SingleDeviceSharding
+    traced, plan = _plan("cg_sparse", n=1 << 20, iters=32)
+    # every unit streams, the spmv ones on the padded per-tile layout
+    assert all(u.kind == "stream" for u in plan.exec_plan.units)
+    spmv_ops = {nd.name for nd in traced.program.nodes.values()
+                if nd.op == "spmv"}
+    assert spmv_ops
+    assert spmv_ops <= {o for u in plan.exec_plan.units
+                        for o in u.sp.spmv}
+    prog = get_backend("pallas").compile(plan)
+    args = _shapes(traced.program, prog.leaf_names,
+                   SingleDeviceSharding(topo.devices[0]))
+    compiled = prog._jit.lower(*args).compile()
+    _assert_every_kernel_compiled(prog, compiled.as_text())
+
+
+def test_batched_core_b8_compiles(topo, mosaic):
+    from jax.sharding import SingleDeviceSharding
+    traced, plan = _plan("cg", n=4096, iters=16)
+    bp = plan.batched()
+    one = SingleDeviceSharding(topo.devices[0])
+    shared = _shapes(traced.program, bp.shared_leaves, one)
+    batched = _shapes(traced.program, bp.batched_leaves, one, batch=8)
+    compiled = bp._build().lower(shared, batched).compile()
+    prog = get_backend("pallas").compile(plan)      # same units, unbatched
+    _assert_every_kernel_compiled(prog, compiled.as_text())
+
+
+def test_sharded_cg_4_devices_compiles(topo, mosaic, monkeypatch):
+    import jax
+    from jax.sharding import Mesh, NamedSharding
+
+    import repro.exec.sharded as sharded_mod
+    traced, plan = _plan("cg", mesh=4, n=8192, iters=32)
+    devices = np.array(topo.devices[:4])
+    # the executor builds its mesh from jax.devices(); hand it the
+    # described chips instead
+    monkeypatch.setattr(sharded_mod, "make_solver_mesh",
+                        lambda n, axis="shards": Mesh(devices[:n], (axis,)))
+    prog = sharded_mod.ShardedProgram(plan)
+    mesh = Mesh(devices, (plan.sharded.axis,))
+    _, in_specs, _, _ = sharded_mod._partition_specs(traced.program,
+                                                     plan.sharded)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for a, spec in zip(_shapes(traced.program, prog.leaf_names,
+                                       None), in_specs)]
+    compiled = prog._jit.lower(*args).compile()
+    text = compiled.as_text()
+    _assert_every_kernel_compiled(prog, text)
+    assert "all-gather" in text and "all-reduce" in text
