@@ -11,8 +11,10 @@ Accepts any of the three export formats and auto-detects which one it got:
 * a **metrics snapshot JSON** (``repro.obs.snapshot()`` serialized, or a
   ``benchmarks.run --json`` dump carrying it under its ``obs`` key).
 
-Span renders show the nested timeline (indent = depth) plus a per-name
-aggregate; metrics renders show one row per labeled cell, histograms with
+Span renders show the nested timeline (indent = depth, times in ms from
+the first span; spans carry the profiler's clock, so ``t0_us`` is the
+offset to line them up with a device profile of the same run) plus a
+per-name aggregate; metrics renders show one row per labeled cell, histograms with
 count/mean/p50/p90/p99/max.
 
 ``--validate`` checks the file against the documented export schema
@@ -91,13 +93,18 @@ def _fmt_args(args: Dict[str, Any]) -> str:
 
 
 def _span_lines(spans: List[Dict[str, Any]]) -> List[str]:
-    lines = [f"{'ts_ms':>10}  {'dur_ms':>10}  span"]
+    t0 = spans[0]["ts_us"] if spans else 0.0
+    lines = [f"t0_us={t0:.3f}",
+             f"{'ts_ms':>10}  {'dur_ms':>10}  {'id':>6}  {'parent':>6}  span"]
     totals: Dict[str, List[float]] = {}
     for rec in spans:
         name, dur_ms = rec["name"], rec["dur_us"] / 1e3
         indent = "  " * rec.get("depth", 0)
         args = _fmt_args(rec.get("args") or {})
-        lines.append(f"{rec['ts_us'] / 1e3:10.3f}  {dur_ms:10.3f}  "
+        parent = rec.get("parent")
+        lines.append(f"{(rec['ts_us'] - t0) / 1e3:10.3f}  {dur_ms:10.3f}  "
+                     f"{rec.get('id', '-'):>6}  "
+                     f"{'-' if parent is None else parent:>6}  "
                      f"{indent}{name}" + (f"  [{args}]" if args else ""))
         totals.setdefault(name, []).append(dur_ms)
     lines.append("")
@@ -119,17 +126,16 @@ def render_chrome(path: str) -> List[str]:
         doc = json.load(f)
     spans = [{"name": ev.get("name", "?"), "ts_us": ev.get("ts", 0),
               "dur_us": ev.get("dur", 0), "depth": 0,
+              "id": ev.get("id"), "parent": ev.get("parent"),
               "args": ev.get("args") or {}}
              for ev in doc.get("traceEvents", [])]
     spans.sort(key=lambda r: r["ts_us"])
-    # reconstruct nesting from interval containment per tid-less stream:
-    # a span is one deeper than the enclosing not-yet-closed span
-    open_until: List[float] = []
+    # nesting from each span's parent (parents start first)
+    depth: Dict[Any, int] = {}
     for rec in spans:
-        while open_until and rec["ts_us"] >= open_until[-1] - 1e-9:
-            open_until.pop()
-        rec["depth"] = len(open_until)
-        open_until.append(rec["ts_us"] + rec["dur_us"])
+        rec["depth"] = depth[rec["id"]] = (
+            depth.get(rec["parent"], -1) + 1
+            if rec["parent"] is not None else 0)
     return _span_lines(spans)
 
 

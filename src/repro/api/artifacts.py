@@ -288,6 +288,22 @@ class CompiledPlan:
         return BatchedPlan(self, backend=backend, donate=donate_val)
 
     # -- introspection --------------------------------------------------
+    def device_scopes(self, dtype: str = "float32", *,
+                      backend: Optional[str] = None) -> Dict[str, str]:
+        """``{HLO instruction name: scope}`` for the device work this
+        plan's executable runs outside its kernels: the spmv gather
+        (``spmv_gather``) and the CSR per-tile layout (``csr_layout``).
+        A device trace names each op by its HLO instruction alone
+        (``fusion.6``); this maps those names back.  Compiled for every
+        leaf at its traced shape, ``dtype`` values and int32 CSR indices.
+        Empty for a backend that names no such scopes."""
+        if self.trace is None or self.trace.program is None:
+            raise ValueError("device_scopes() needs a frontend-traced plan")
+        from ..exec import get_backend                   # lazy: pulls in jax
+        fn = get_backend(backend or self.backend).compiled(self)
+        scopes = getattr(fn, "device_scopes", None)
+        return {} if scopes is None else scopes(dtype)
+
     def report(self) -> Dict[str, Any]:
         """Headline co-design metrics (empty-ish for default plans)."""
         out: Dict[str, Any] = {

@@ -34,9 +34,11 @@ CompiledFn = Callable[[Feeds], Dict[str, Any]]
 _COMPILE_S = obs.registry().histogram(
     "exec.compile_s", "plan -> callable compile wall-clock (memoized: one "
     "observation per distinct plan per executor)", unit="s")
-_RUN_S = obs.registry().histogram(
-    "exec.run_s", "compiled-callable dispatch wall-clock (submit-side; jax "
-    "dispatch is async, so device time may extend past this)", unit="s")
+_DISPATCH_S = obs.registry().histogram(
+    "exec.dispatch_s", "host time to enqueue one run (feed checks and "
+    "copies, the executable's launch), not device time: JAX dispatch is "
+    "async; traced=True marks runs that traced (and compiled) the "
+    "program", unit="s")
 
 
 class Executor:
@@ -71,10 +73,9 @@ class Executor:
         return self.compile(plan)
 
     # -- shared driver --------------------------------------------------
-    def run(self, plan, feeds: Optional[Feeds] = None, *,
-            seed: int = 0) -> Dict[str, Any]:
-        """Compile (memoized, thread-safe) and execute ``plan``."""
-        program = plan_program(plan)
+    def compiled(self, plan) -> CompiledFn:
+        """``plan``'s callable, compiled once per plan (memoized,
+        thread-safe)."""
         with self._compile_lock:
             entry = self._compiled.get(id(plan))
             fn = (entry[1] if entry is not None and entry[0]() is plan
@@ -96,19 +97,34 @@ class Executor:
                     pass
                 else:
                     self._compiled[id(plan)] = (ref, fn)
+        return fn
+
+    def run(self, plan, feeds: Optional[Feeds] = None, *,
+            seed: int = 0) -> Dict[str, Any]:
+        """Compile (memoized, thread-safe) and execute ``plan``."""
+        program = plan_program(plan)
+        fn = self.compiled(plan)
         if feeds is None:
             from ..frontends.reference import make_feeds
             feeds = make_feeds(program, seed)
+        traces = _traces(fn)
         t0 = time.perf_counter()
         with obs.span("exec.dispatch", backend=self.name):
             # fault-injection site: exec.dispatch@<backend>
             faults.check("exec.dispatch", backend=self.name)
             out = fn(feeds)
-        _RUN_S.observe(time.perf_counter() - t0, backend=self.name)
+        _DISPATCH_S.observe(time.perf_counter() - t0, backend=self.name,
+                            traced=_traces(fn) != traces)
         return out
 
     def __repr__(self) -> str:
         return f"{type(self).__name__}({self.name!r})"
+
+
+def _traces(fn) -> Optional[int]:
+    """How often ``fn`` has traced its program, where it counts that."""
+    stats = getattr(fn, "stats", None)
+    return None if stats is None else stats["traces"]
 
 
 # --------------------------------------------------------------------------

@@ -71,6 +71,7 @@ elementwise, block kernels and jnp units use the reference rules verbatim.
 from __future__ import annotations
 
 import os
+import re
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 from .. import obs
@@ -88,14 +89,27 @@ _TRACES = obs.registry().counter(
 _DISPATCHES = obs.registry().counter(
     "exec.dispatches", "device dispatches, per compiled program "
     "(scope label)")
-_DONATED_B = obs.registry().counter(
-    "exec.donated_bytes", "leaf feed bytes donated into the executable "
-    "(copies of caller-owned device buffers included)", unit="B")
+_FEED_COPY_B = obs.registry().counter(
+    "exec.feed_copy_bytes", "bytes of caller-owned device feeds copied "
+    "before the executable consumes (donates) them, per compiled program "
+    "(scope label)", unit="B")
 _UNITS = obs.registry().counter(
     "exec.units", "execution units built at compile, by kind "
     "(stream | block | jnp)")
 
 _BACKEND_PROBE: Optional[str] = None
+
+#: ``jax.named_scope`` names of the device work that runs outside the
+#: kernels: the spmv's ``values * x[cols]`` gather, and the CSR operand's
+#: per-tile layout.  They must not start with ``cello_``, the kernels'
+#: prefix.  A device trace names ops by their HLO instruction alone;
+#: :meth:`_SingleProgram.device_scopes` maps those names back to scopes.
+GATHER_SCOPE = "spmv_gather"
+LAYOUT_SCOPE = "csr_layout"
+DEVICE_SCOPES = (GATHER_SCOPE, LAYOUT_SCOPE)
+
+_HLO_OP = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?'
+                     r'metadata=\{[^}]*?op_name="([^"]*)"')
 
 #: headroom above a kernel's planned VMEM for Mosaic's internal scratch,
 #: and the ceiling no kernel asks past (a TPU v5e core has 128 MiB)
@@ -409,6 +423,7 @@ class _StreamCall:
     # -- drivers --------------------------------------------------------
     def apply(self, env: Dict[str, Any], dtype) -> Dict[str, Any]:
         """Run (or trace) this pass over ``env`` at a resolved ``dtype``."""
+        import jax
         import jax.numpy as jnp
         vals: Dict[str, Any] = {}
 
@@ -425,8 +440,9 @@ class _StreamCall:
             for lay, x in self.spmv.values():
                 cols, data, first, stop = (
                     env[lay] if lay in env else self.derived[lay](env, dtype))
-                tile_args += [data * jnp.asarray(env[x], dtype)[cols],
-                              first, stop]
+                with jax.named_scope(GATHER_SCOPE):
+                    vals_t = data * jnp.asarray(env[x], dtype)[cols]
+                tile_args += [vals_t, first, stop]
             row = [jnp.reshape(jnp.asarray(env[n], dtype),
                                _row_shape(self.shapes[n]))
                    for n in self.stream_in + self.res_in]
@@ -477,6 +493,11 @@ def _csr_tiles_fn(indptr: str, indices: str, data: str, tile_rows: int,
     which no row's range covers.  Windows are contiguous slices, so the
     layout needs no per-entry search."""
     def build(env, dtype):
+        import jax
+        with jax.named_scope(LAYOUT_SCOPE):
+            return _csr_tiles(env, dtype)
+
+    def _csr_tiles(env, dtype):
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -818,27 +839,82 @@ class _SingleProgram:
 
     # -- the dispatch ---------------------------------------------------
     def __call__(self, feeds: Dict[str, Any]) -> Dict[str, Any]:
-        args = []
-        donated = 0
         for leaf in self.leaf_names:
             if leaf not in feeds:
                 raise KeyError(f"feeds missing leaf {leaf!r}")
-        check_csr_feeds(self.exec_plan.units, self._program, feeds)
-        for leaf in self.leaf_names:
-            v = feeds[leaf]
-            if self._donate:
-                import jax
-                import jax.numpy as jnp
-                if isinstance(v, jax.Array):
-                    # donation must never consume a caller-owned buffer
-                    v = jnp.array(v, copy=True)
-                donated += int(getattr(v, "nbytes", 0) or 0)
-            args.append(v)
+        with obs.span("exec.feed_check"):
+            check_csr_feeds(self.exec_plan.units, self._program, feeds)
+        args = [feeds[leaf] for leaf in self.leaf_names]
+        if self._donate:
+            with obs.span("exec.feed_copy"):
+                # donation must never consume a caller-owned buffer
+                args, copied = _own_feeds(args)
+            if copied:
+                _FEED_COPY_B.inc(copied, backend="pallas",
+                                 scope=self._scope)
         _DISPATCHES.inc(backend="pallas", scope=self._scope)
-        if donated:
-            _DONATED_B.inc(donated, backend="pallas", scope=self._scope)
-        outs = self._jit(*args)
+        with obs.span("exec.launch"):
+            outs = self._jit(*args)
         return dict(zip(self.out_names, outs))
+
+    # -- what the device trace cannot name ------------------------------
+    def leaf_shapes(self, dtype="float32") -> list:
+        """``jax.ShapeDtypeStruct`` per leaf at its traced shape: int32
+        CSR row pointers and column ids, ``dtype`` for every other leaf."""
+        import jax
+        import jax.numpy as jnp
+        out = []
+        for name in self.leaf_names:
+            nd = self._program.nodes[name]
+            dt = (jnp.int32 if nd.param("role") in ("indptr", "indices")
+                  else dtype)
+            out.append(jax.ShapeDtypeStruct(tuple(nd.shape), dt))
+        return out
+
+    def device_scopes(self, dtype="float32") -> Dict[str, str]:
+        """``{HLO instruction name: scope}`` for the instructions of this
+        program's compiled executable that carry one of
+        :data:`DEVICE_SCOPES`, compiled for :meth:`leaf_shapes` (the
+        compilation caches make this a lookup after a run at those
+        shapes).  A device trace names each op by its instruction
+        (``fusion.6``) and nothing else; this says which are the gather
+        and which the layout build."""
+        text = self._jit.lower(*self.leaf_shapes(dtype)).compile().as_text()
+        return hlo_scopes(text)
+
+
+def _own_feeds(args: list) -> Tuple[list, int]:
+    """``args`` with every ``jax.Array`` replaced by a copy, and the bytes
+    copied: the executable may then donate all of them."""
+    import jax
+    import jax.numpy as jnp
+    out, copied = [], 0
+    for v in args:
+        if isinstance(v, jax.Array):
+            v = jnp.array(v, copy=True)
+            copied += v.nbytes
+        out.append(v)
+    return out, copied
+
+
+def hlo_scopes(text: str, scopes: Sequence[str] = DEVICE_SCOPES
+               ) -> Dict[str, str]:
+    """``{instruction: scope}`` for each instruction of a compiled HLO
+    module's text whose ``op_name`` metadata passes through one of
+    ``scopes`` (the innermost, where they nest)."""
+    pats = [(sc, re.compile(r"(?:^|[/(])" + re.escape(sc) + r"(?=[/)]|$)"))
+            for sc in scopes]
+    out: Dict[str, str] = {}
+    for line in text.splitlines():
+        m = _HLO_OP.match(line)
+        if m is None:
+            continue
+        name, op_name = m.groups()
+        found = [(hit.start(), sc) for sc, pat in pats
+                 for hit in pat.finditer(op_name)]
+        if found:
+            out[name] = max(found)[1]
+    return out
 
 
 # --------------------------------------------------------------------------

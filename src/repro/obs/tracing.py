@@ -23,20 +23,29 @@ Export schema (documented contract — ``scripts/obs_report.py --validate``
 and the CI ``obs-smoke`` job check it):
 
 * **JSONL** — one JSON object per line with exactly the keys
-  ``name`` (str), ``ts_us`` (float, µs since tracer start), ``dur_us``
-  (float ≥ 0), ``tid`` (int), ``pid`` (int), ``depth`` (int ≥ 0) and
-  ``args`` (object).
+  ``name`` (str), ``ts_us`` (float, µs on the profiler's clock, below),
+  ``dur_us`` (float ≥ 0), ``tid`` (int), ``pid`` (int), ``depth`` (int ≥
+  0), ``id`` (int ≥ 1, unique per tracer), ``parent`` (the ``id`` of the
+  enclosing span on the same thread, or null) and ``args`` (object).
 * **Chrome** — ``{"displayTimeUnit": "ms", "traceEvents": [...]}`` where
   every event is a complete-duration event: ``ph == "X"`` with ``name``,
-  ``ts``/``dur`` (µs), ``pid``, ``tid``, and the span metadata under
-  ``args``.
+  ``ts``/``dur`` (µs), ``pid``, ``tid``, ``id``, ``parent``, and the span
+  metadata under ``args``.
+
+Clock: spans are stamped with the clock ``jax.profiler`` stamps its host
+events with, ``CLOCK_REALTIME`` (:func:`time.time_ns`): ``ts_us`` is
+microseconds since the Unix epoch.  A device profile gives its events'
+offsets from its ``profile_start_time`` (the ``Task Environment``
+plane), on the same clock, so an export from a run lines up with a
+profile of that run without the mirror below.
 
 An opt-in ``jax.profiler`` hook mirrors every span into a
-``jax.profiler.TraceAnnotation``, so CELLO pipeline stages line up with XLA
-events inside a device profile.
+``jax.profiler.TraceAnnotation``, so CELLO pipeline stages also show
+inside the device profile itself.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -49,7 +58,12 @@ __all__ = [
 ]
 
 #: exactly the keys every exported JSONL span carries
-JSONL_KEYS = ("name", "ts_us", "dur_us", "tid", "pid", "depth", "args")
+JSONL_KEYS = ("name", "ts_us", "dur_us", "tid", "pid", "depth", "id",
+              "parent", "args")
+
+#: the tracer's clock, in ns: the one ``jax.profiler`` stamps host events
+#: with (``CLOCK_REALTIME``)
+_clock_ns = time.time_ns
 
 
 class _NullSpan:
@@ -73,14 +87,17 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One live span: records itself on exit."""
 
-    __slots__ = ("tracer", "name", "args", "_t0", "_depth", "_jax_ctx")
+    __slots__ = ("tracer", "name", "args", "id", "parent", "_t0",
+                 "_depth", "_jax_ctx")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  args: Dict[str, Any]):
         self.tracer = tracer
         self.name = name
         self.args = args
-        self._t0 = 0.0
+        self.id = next(tracer._ids)
+        self.parent: Optional[int] = None
+        self._t0 = 0
         self._depth = 0
         self._jax_ctx = None
 
@@ -88,12 +105,14 @@ class _Span:
         tr = self.tracer
         stack = tr._stack()
         self._depth = len(stack)
+        if stack:
+            self.parent = stack[-1].id
         stack.append(self)
         if tr.jax_profiler:
             self._jax_ctx = tr._jax_annotation(self.name)
             if self._jax_ctx is not None:
                 self._jax_ctx.__enter__()
-        self._t0 = time.perf_counter()
+        self._t0 = _clock_ns()
         return self
 
     def annotate(self, **kv) -> "_Span":
@@ -102,15 +121,15 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        t1 = _clock_ns()
         if self._jax_ctx is not None:
             self._jax_ctx.__exit__(*exc)
         tr = self.tracer
         stack = tr._stack()
         if stack and stack[-1] is self:
             stack.pop()
-        tr._record(self.name, self._t0 - tr._epoch, t1 - self._t0,
-                   self._depth, self.args)
+        tr._record(self.name, self._t0 * 1e-9, max(t1 - self._t0, 0) * 1e-9,
+                   self._depth, self.args, self.id, self.parent)
         return False
 
 
@@ -121,8 +140,7 @@ class SpanTracer:
                  jax_profiler: bool = False):
         self.enabled = enabled
         self.jax_profiler = jax_profiler
-        self._epoch = time.perf_counter()
-        self._epoch_unix = time.time()
+        self._ids = itertools.count(1)
         self._records: List[Dict[str, Any]] = []
         self._lock = threading.Lock()
         self._local = threading.local()
@@ -137,18 +155,22 @@ class SpanTracer:
 
     def record(self, name: str, start_s: float, dur_s: float, *,
                depth: Optional[int] = None, **args) -> None:
-        """Record a synthetic (already-timed) span.  ``start_s`` is tracer
-        time (:meth:`now`).  Used where real intervals are not observable —
-        e.g. the lazily-streamed search passes report aggregate self-time."""
+        """Record a synthetic (already-timed) span, a child of the span
+        open on this thread.  ``start_s`` is tracer time (:meth:`now`).
+        Used where real intervals are not observable — e.g. the
+        lazily-streamed search passes report aggregate self-time."""
         if not self.enabled:
             return
+        stack = self._stack()
         if depth is None:
-            depth = len(self._stack())
-        self._record(name, start_s, max(dur_s, 0.0), depth, args)
+            depth = len(stack)
+        self._record(name, start_s, max(dur_s, 0.0), depth, args,
+                     next(self._ids), stack[-1].id if stack else None)
 
     def now(self) -> float:
-        """Seconds since this tracer's epoch (span timestamps' timebase)."""
-        return time.perf_counter() - self._epoch
+        """Seconds on the tracer's clock (span timestamps' timebase: the
+        profiler's, seconds since the Unix epoch)."""
+        return _clock_ns() * 1e-9
 
     # -- lifecycle -------------------------------------------------------
     def enable(self, *, jax_profiler: Optional[bool] = None) -> "SpanTracer":
@@ -173,7 +195,8 @@ class SpanTracer:
         return stack
 
     def _record(self, name: str, start_s: float, dur_s: float, depth: int,
-                args: Dict[str, Any]) -> None:
+                args: Dict[str, Any], span_id: int,
+                parent: Optional[int]) -> None:
         rec = {
             "name": name,
             "ts_us": start_s * 1e6,
@@ -181,6 +204,8 @@ class SpanTracer:
             "tid": threading.get_ident(),
             "pid": os.getpid(),
             "depth": depth,
+            "id": span_id,
+            "parent": parent,
             "args": _jsonable(args),
         }
         with self._lock:
@@ -222,11 +247,14 @@ class SpanTracer:
                 "dur": rec["dur_us"],
                 "pid": rec["pid"],
                 "tid": rec["tid"],
+                "id": rec["id"],
+                "parent": rec["parent"],
                 "cat": rec["name"].split(".", 1)[0],
                 "args": rec["args"],
             })
         return {"displayTimeUnit": "ms", "traceEvents": events,
-                "otherData": {"unix_epoch_s": self._epoch_unix}}
+                "otherData": {"clock": "CLOCK_REALTIME, us since the Unix "
+                                       "epoch (jax.profiler's)"}}
 
     def export_chrome(self, path: os.PathLike) -> int:
         doc = self.to_chrome()
@@ -273,8 +301,21 @@ def _check_span(rec: Dict[str, Any], where: str) -> None:
     for k in ("tid", "pid", "depth"):
         if not isinstance(rec[k], int) or rec[k] < 0:
             raise ValueError(f"{where}: {k} must be an int >= 0")
+    _check_ids(rec, where)
     if not isinstance(rec["args"], dict):
         raise ValueError(f"{where}: args must be an object")
+
+
+def _check_ids(rec: Dict[str, Any], where: str) -> None:
+    if not _is_int(rec.get("id")) or rec["id"] < 1:
+        raise ValueError(f"{where}: id must be an int >= 1")
+    parent = rec.get("parent", 0)
+    if parent is not None and (not _is_int(parent) or parent < 1):
+        raise ValueError(f"{where}: parent must be an int >= 1 or null")
+
+
+def _is_int(v: Any) -> bool:
+    return isinstance(v, int) and not isinstance(v, bool)
 
 
 def validate_jsonl(path: os.PathLike) -> int:
@@ -313,17 +354,23 @@ def validate_chrome(path: os.PathLike) -> int:
         for k in ("pid", "tid"):
             if not isinstance(ev.get(k), int):
                 raise ValueError(f"{where}: {k} must be an int")
+        _check_ids(ev, where)
         if "args" in ev and not isinstance(ev["args"], dict):
             raise ValueError(f"{where}: args must be an object")
     return len(events)
 
 
 def _jsonable(args: Dict[str, Any]) -> Dict[str, Any]:
-    """Span metadata must serialize: keep JSON scalars, repr the rest."""
+    """Span metadata must serialize: keep JSON scalars and lists of them
+    (a batch's request ids), repr the rest."""
     out = {}
     for k, v in args.items():
-        if isinstance(v, (str, int, float, bool)) or v is None:
+        if _scalar(v) or (isinstance(v, list) and all(map(_scalar, v))):
             out[k] = v
         else:
             out[k] = repr(v)
     return out
+
+
+def _scalar(v: Any) -> bool:
+    return isinstance(v, (str, int, float, bool)) or v is None

@@ -29,6 +29,7 @@ within the tolerances already documented in ``docs/execution_backends.md``.
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence
 
 from .. import obs
@@ -45,6 +46,19 @@ _BP_TRACES = obs.registry().counter(
 _BP_DISPATCHES = obs.registry().counter(
     "serve.batch_dispatches", "BatchedPlan coalesced-batch device "
     "dispatches, per plan (scope label)")
+_H2D_B = obs.registry().counter(
+    "serve.h2d_bytes", "bytes of host (non-device) arrays passed into "
+    "batched dispatches, shared and batched leaves, per plan (scope "
+    "label)", unit="B")
+_LAUNCH_S = obs.registry().histogram(
+    "serve.launch_s", "the batched executable's launch, per batch: its "
+    "host arguments' transfers are enqueued here and run on; traced=True "
+    "marks launches that traced (and compiled) a new batch width",
+    unit="s")
+_READBACK_S = obs.registry().histogram(
+    "serve.readback_s", "the batch's outputs read back to the host, per "
+    "batch: waits for the argument transfers and the solve to finish",
+    unit="s")
 
 
 class BatchedPlan:
@@ -149,13 +163,21 @@ class BatchedPlan:
             # shared CSR operands must fit the kernels' per-tile layout
             check_csr_feeds(self.plan.exec_plan.units, self.program, feeds)
         _BP_DISPATCHES.inc(backend=self.backend, scope=self._scope)
-        with obs.span("serve.batch_dispatch", backend=self.backend,
-                      batch=batch):
+        h2d = _host_bytes(shared_vals) + _host_bytes(batched_vals)
+        if h2d:
+            _H2D_B.inc(h2d, backend=self.backend, scope=self._scope)
+        traces = self.stats["traces"]
+        t0 = time.perf_counter()
+        with obs.span("serve.launch", backend=self.backend, batch=batch):
             # fault-injection site (docs/robustness.md):
             # serve.dispatch@<backend> — fail or slow the coalesced
             # dispatch itself
             faults.check("serve.dispatch", backend=self.backend)
-            return dict(self._jit(shared_vals, batched_vals))
+            out = dict(self._jit(shared_vals, batched_vals))
+        _LAUNCH_S.observe(time.perf_counter() - t0, backend=self.backend,
+                          scope=self._scope,
+                          traced=self.stats["traces"] != traces)
+        return out
 
     def run_many(self, requests: Sequence[Mapping[str, Any]],
                  shared: Mapping[str, Any], *,
@@ -181,11 +203,17 @@ class BatchedPlan:
         n_real = len(requests)
         n_lanes = _next_pow2(n_real) if pad else n_real
         feeds: Dict[str, Any] = dict(shared)
-        for n in self.batched_leaves:
-            vals = [np.asarray(_require(r, n)) for r in requests]
-            vals += [vals[-1]] * (n_lanes - n_real)
-            feeds[n] = np.stack(vals)
-        out = {k: np.asarray(v) for k, v in self.run_batch(feeds).items()}
+        with obs.span("serve.stack", lanes=n_lanes):
+            for n in self.batched_leaves:
+                vals = [np.asarray(_require(r, n)) for r in requests]
+                vals += [vals[-1]] * (n_lanes - n_real)
+                feeds[n] = np.stack(vals)
+        dev = self.run_batch(feeds)
+        t0 = time.perf_counter()
+        with obs.span("serve.readback"):
+            out = {k: np.asarray(v) for k, v in dev.items()}
+        _READBACK_S.observe(time.perf_counter() - t0, backend=self.backend,
+                            scope=self._scope)
         return [{k: v[i] for k, v in out.items()} for i in range(n_real)]
 
     def run_one(self, feeds: Mapping[str, Any]) -> Dict[str, Any]:
@@ -210,6 +238,14 @@ def _require(feeds: Mapping[str, Any], name: str):
 
 def _next_pow2(n: int) -> int:
     return 1 << (n - 1).bit_length()
+
+
+def _host_bytes(vals) -> int:
+    """Bytes of the values in ``vals`` that are not on a device yet: the
+    dispatch transfers them."""
+    import jax
+    return sum(int(getattr(v, "nbytes", 0)) for v in vals
+               if not isinstance(v, jax.Array))
 
 
 def _own(v):

@@ -70,6 +70,7 @@ worker or an unrelated submitter.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import threading
 import time
@@ -128,6 +129,11 @@ _WORKER_RESTARTS = obs.registry().counter(
     "serve.worker_restarts", "supervised worker-thread restarts, per "
     "server (scope label)")
 
+#: request ids, unique in the process: a request's spans (``serve.submit``
+#: ``req``, ``serve.batch_open`` / ``serve.batch`` / ``serve.settle``
+#: ``reqs``) join on it
+_REQUEST_IDS = itertools.count(1)
+
 #: a batch closes early this far before its tightest member deadline, so
 #: the request is dispatched *before* it would expire (the margin is the
 #: larger of 2 ms and 10% of the request's whole deadline window)
@@ -153,12 +159,13 @@ class SolveResult:
 
 @dataclasses.dataclass
 class _Item:
-    """One queued request: the payload, its future, and its deadline
-    (absolute ``time.monotonic`` seconds; ``inf`` = none)."""
+    """One queued request: the payload, its future, its deadline
+    (absolute ``time.monotonic`` seconds; ``inf`` = none) and its id."""
     req: SolveRequest
     fut: "Future[SolveResult]"
     t_submit: float
     deadline: float = math.inf
+    rid: int = 0
 
     def close_by(self) -> float:
         """When the worker should stop coalescing on this item's account:
@@ -308,13 +315,19 @@ class Server:
         Passing a dict instead of a :class:`SolveRequest` is deprecated
         since 0.10 (``docs/api_migration.md``).
         """
+        rid = next(_REQUEST_IDS)
+        with obs.span("serve.submit", req=rid):
+            return self._submit(req, deadline_s, rid)
+
+    def _submit(self, req: SolveRequest, deadline_s: Optional[float],
+                rid: int) -> "Future[SolveResult]":
         if isinstance(req, dict):
             import warnings
             warnings.warn(
                 "Server.submit(dict) is deprecated since 0.10 and will "
                 "be removed in 0.11; pass a SolveRequest (see "
                 "repro.serve.request and docs/api_migration.md)",
-                DeprecationWarning, stacklevel=2)
+                DeprecationWarning, stacklevel=3)
             req = request(**req)
         if deadline_s is None:
             deadline_s = req.deadline_s
@@ -354,7 +367,7 @@ class Server:
                 self._cv.wait(timeout=None if deadline == math.inf
                               else deadline - now)
             self._pending.setdefault(key, deque()).append(
-                _Item(req, fut, t_submit, deadline))
+                _Item(req, fut, t_submit, deadline, rid))
             _REQUESTS.inc(bucket=key.label, scope=self._scope)
             self._cv.notify_all()
         return fut
@@ -545,96 +558,111 @@ class Server:
             self._on_worker_crash(e)
 
     def _loop(self) -> None:
-        max_wait_s = self.max_wait_us * 1e-6
+        """Serve batches until closed.  Every instant of the worker's loop
+        lies in one of its spans: ``serve.wait`` (nothing pending),
+        ``serve.batch_open`` (a batch held open to fill), ``serve.batch``
+        and ``serve.settle`` (:meth:`_serve_batch`)."""
         while True:
             with self._cv:
-                while True:
-                    now = time.monotonic()
-                    self._expire_locked(now)
-                    if self._pending or self._closing:
-                        break
-                    self._cv.wait(timeout=self._expiry_timeout_locked(now))
+                with obs.span("serve.wait"):
+                    while True:
+                        now = time.monotonic()
+                        self._expire_locked(now)
+                        if self._pending or self._closing:
+                            break
+                        self._cv.wait(
+                            timeout=self._expiry_timeout_locked(now))
                 if not self._pending and self._closing:
                     return
                 if not self._pending:      # everything just expired
                     continue
-                live = [k for k, d in self._pending.items() if d]
-                if self.policy == "round_robin":
-                    # least-recently-served non-empty bucket (never-served
-                    # sorts first); ties break oldest-head-first so the
-                    # first pass through fresh buckets is still fair
-                    key = min(live, key=lambda k: (
-                        self._last_served.get(k, -1),
-                        self._pending[k][0].t_submit))
-                else:
-                    # serve the bucket whose head request waited longest
-                    key = min(live,
-                              key=lambda k: self._pending[k][0].t_submit)
-                self._serve_seq += 1
-                self._last_served[key] = self._serve_seq
-                # the max_wait window is anchored to the oldest request at
-                # batch open, NOT the live head: if a deadline-bearing head
-                # expires mid-wait the window must not restart, or requests
-                # behind a chain of expiring heads wait >> max_wait
-                anchor = self._pending[key][0].t_submit
-                while (len(self._pending.get(key, ())) < self.max_batch_size
-                       and not self._closing):
-                    now = time.monotonic()
-                    self._expire_locked(now)
-                    d = self._pending.get(key)
-                    if not d:
-                        break
-                    # close when the window anchor hits max_wait OR any
-                    # member approaches its deadline (early, with margin,
-                    # so it dispatches rather than expires)
-                    close_at = min(anchor + max_wait_s,
-                                   min(it.close_by() for it in d))
-                    remaining = close_at - now
-                    if remaining <= 0:
-                        break
-                    timeout = remaining
-                    other = self._expiry_timeout_locked(now)
-                    if other is not None:
-                        timeout = min(timeout, other)
-                    self._cv.wait(timeout=timeout)
-                d = self._pending.get(key)
-                if not d:
-                    continue               # the whole bucket expired away
-                batch = [d.popleft()
-                         for _ in range(min(self.max_batch_size, len(d)))]
-                if not d:
-                    del self._pending[key]
-                now = time.monotonic()
-                lb = key.label
-                kept = []
-                for it in batch:
-                    if now > it.deadline:
-                        _EXPIRED.inc(bucket=lb, scope=self._scope)
-                        _ERRORS.inc(bucket=lb, scope=self._scope)
-                        self._settle_error(it.fut, DeadlineExceeded(
-                            f"deadline exceeded after "
-                            f"{now - it.t_submit:.3f}s in queue ({lb})"))
-                    elif not it.fut.set_running_or_notify_cancel():
-                        # client cancelled while queued: the cancel IS the
-                        # settlement — drop the item, tally it as an error
-                        _ERRORS.inc(bucket=lb, scope=self._scope)
-                    else:
-                        kept.append(it)
-                batch = kept
-                # queue space was freed: wake blocked submitters
-                self._cv.notify_all()
+                with obs.span("serve.batch_open") as sp:
+                    key, batch = self._open_batch_locked()
+                    sp.annotate(bucket=key.label,
+                                reqs=[it.rid for it in batch])
                 if not batch:
                     continue
-                # queued -> in_flight atomically with the pop, so stats()
-                # never sees these requests in neither state
-                self._in_flight[lb] = self._in_flight.get(lb, 0) \
-                    + len(batch)
-                self._current = _InFlightBatch(key, batch)
             # crash-injection site: outside the lock, outside
             # _serve_batch's own error containment — exercises the
             # supervisor, not the per-batch error path
             faults.check("serve.worker", bucket=key.label)
             self._serve_batch(key, batch, time.monotonic())
+
+    def _open_batch_locked(self):
+        """Pick the next bucket, hold its batch open until it fills, its
+        window closes or a member nears its deadline, and pop it.  Returns
+        ``(key, live items)``; the items are in flight from here."""
+        max_wait_s = self.max_wait_us * 1e-6
+        live = [k for k, d in self._pending.items() if d]
+        if self.policy == "round_robin":
+            # least-recently-served non-empty bucket (never-served
+            # sorts first); ties break oldest-head-first so the
+            # first pass through fresh buckets is still fair
+            key = min(live, key=lambda k: (
+                self._last_served.get(k, -1),
+                self._pending[k][0].t_submit))
+        else:
+            # serve the bucket whose head request waited longest
+            key = min(live, key=lambda k: self._pending[k][0].t_submit)
+        self._serve_seq += 1
+        self._last_served[key] = self._serve_seq
+        # the max_wait window is anchored to the oldest request at
+        # batch open, NOT the live head: if a deadline-bearing head
+        # expires mid-wait the window must not restart, or requests
+        # behind a chain of expiring heads wait >> max_wait
+        anchor = self._pending[key][0].t_submit
+        while (len(self._pending.get(key, ())) < self.max_batch_size
+               and not self._closing):
+            now = time.monotonic()
+            self._expire_locked(now)
+            d = self._pending.get(key)
+            if not d:
+                break
+            # close when the window anchor hits max_wait OR any
+            # member approaches its deadline (early, with margin,
+            # so it dispatches rather than expires)
+            close_at = min(anchor + max_wait_s,
+                           min(it.close_by() for it in d))
+            remaining = close_at - now
+            if remaining <= 0:
+                break
+            timeout = remaining
+            other = self._expiry_timeout_locked(now)
+            if other is not None:
+                timeout = min(timeout, other)
+            self._cv.wait(timeout=timeout)
+        d = self._pending.get(key)
+        if not d:
+            return key, []                 # the whole bucket expired away
+        batch = [d.popleft()
+                 for _ in range(min(self.max_batch_size, len(d)))]
+        if not d:
+            del self._pending[key]
+        now = time.monotonic()
+        lb = key.label
+        kept = []
+        for it in batch:
+            if now > it.deadline:
+                _EXPIRED.inc(bucket=lb, scope=self._scope)
+                _ERRORS.inc(bucket=lb, scope=self._scope)
+                self._settle_error(it.fut, DeadlineExceeded(
+                    f"deadline exceeded after "
+                    f"{now - it.t_submit:.3f}s in queue ({lb})"))
+            elif not it.fut.set_running_or_notify_cancel():
+                # client cancelled while queued: the cancel IS the
+                # settlement — drop the item, tally it as an error
+                _ERRORS.inc(bucket=lb, scope=self._scope)
+            else:
+                kept.append(it)
+        batch = kept
+        # queue space was freed: wake blocked submitters
+        self._cv.notify_all()
+        if batch:
+            # queued -> in_flight atomically with the pop, so stats()
+            # never sees these requests in neither state
+            self._in_flight[lb] = self._in_flight.get(lb, 0) + len(batch)
+            self._current = _InFlightBatch(key, batch)
+        return key, batch
 
     @staticmethod
     def _settle_error(fut: "Future[SolveResult]",
@@ -845,7 +873,8 @@ class Server:
         fell_back = False
         entry = outs = None
         primary_exc: Optional[BaseException] = None
-        with obs.span("serve.batch", bucket=lb, size=n):
+        reqs = [it.rid for it in batch]
+        with obs.span("serve.batch", bucket=lb, size=n, reqs=reqs):
             breaker = self._breaker_for(lb)
             if breaker is None or breaker.allow():
                 try:
@@ -898,19 +927,20 @@ class Server:
                 self._exec_stats[lb] = dict(entry.bplan.stats)
                 if self._current is not None:
                     self._current.accounted = True
-        rname = entry.residual_output
-        backend = entry.key.backend
-        for it, out in zip(batch, outs):
-            residual = None
-            if rname is not None:
-                import numpy as np
-                residual = float(np.linalg.norm(np.asarray(out[rname])))
-            try:
-                it.fut.set_result(SolveResult(
-                    outputs=out, residual=residual, bucket=lb,
-                    batch_size=n, latency_s=done - it.t_submit,
-                    backend=backend, degraded=fell_back))
-            except InvalidStateError:  # pragma: no cover — running futures
-                pass                   # cannot be cancelled; defensive only
-        with self._cv:
-            self._current = None
+        with obs.span("serve.settle", bucket=lb, reqs=reqs):
+            rname = entry.residual_output
+            backend = entry.key.backend
+            for it, out in zip(batch, outs):
+                residual = None
+                if rname is not None:
+                    import numpy as np
+                    residual = float(np.linalg.norm(np.asarray(out[rname])))
+                try:
+                    it.fut.set_result(SolveResult(
+                        outputs=out, residual=residual, bucket=lb,
+                        batch_size=n, latency_s=done - it.t_submit,
+                        backend=backend, degraded=fell_back))
+                except InvalidStateError:  # pragma: no cover — running
+                    pass           # futures cannot be cancelled; defensive
+            with self._cv:
+                self._current = None

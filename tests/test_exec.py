@@ -629,3 +629,114 @@ class TestTileBudget:
             for sp in gk.passes:
                 assert sp.tile_rows >= 1
                 assert sp.rows % sp.tile_rows == 0
+
+
+# ---------------------------------------------------------------------------
+# the dispatch's instruments: child spans, copied feed bytes, host time,
+# and the device scopes a trace cannot name
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def spans():
+    """The global tracer, on and empty for the test."""
+    from repro import obs
+    tr = obs.tracer()
+    was = tr.enabled
+    tr.clear()
+    tr.enable()
+    yield tr
+    if not was:
+        tr.disable()
+    tr.clear()
+
+
+def _dispatch_counts():
+    from repro import obs
+    cells = obs.snapshot().get("exec.dispatch_s", {}).get("cells", [])
+    out = {"True": 0, "False": 0}
+    for c in cells:
+        if c["labels"].get("backend") == "pallas":
+            out[c["labels"]["traced"]] += c["value"]["count"]
+    return out
+
+
+class TestDispatchInstruments:
+    def test_dispatch_children_and_feed_copy_bytes(self, tmp_path, spans,
+                                                   monkeypatch):
+        import warnings
+
+        import jax.numpy as jnp
+        from repro import obs
+        monkeypatch.setenv("CELLO_PALLAS_DONATE", "1")
+        traced, plan = _lowered(tmp_path, workload="cg", n=32, iters=2)
+        host = make_feeds(traced.program, seed=0)
+        feeds = {k: jnp.asarray(v) for k, v in host.items()}
+        feeds["b"] = np.asarray(host["b"])     # transfers: nothing copies it
+        before = _dispatch_counts()
+        with warnings.catch_warnings():
+            warnings.filterwarnings("ignore", message=".*[Dd]onat")
+            for _ in range(2):
+                plan.run(feeds, backend="pallas")
+        ex = get_backend("pallas").compiled(plan)
+        assert ex.stats["dispatches"] == 2
+        copied = sum(v.nbytes for k, v in feeds.items() if k != "b")
+        snap = obs.snapshot(ex._scope)
+        (cell,) = snap["exec.feed_copy_bytes"]["cells"]
+        assert cell["value"] == 2 * copied
+        # exec.dispatch_s: the first run traced the program, the second
+        # only enqueued it
+        after = _dispatch_counts()
+        assert after["True"] - before["True"] == 1
+        assert after["False"] - before["False"] == 1
+        recs = spans.spans()
+        dispatches = [s for s in recs if s["name"] == "exec.dispatch"]
+        assert len(dispatches) == 2
+        for d in dispatches:
+            kids = [s["name"] for s in recs if s["parent"] == d["id"]]
+            assert kids == ["exec.feed_check", "exec.feed_copy",
+                            "exec.launch"]
+
+    def test_device_scopes_name_the_gather_and_the_layout(self, tmp_path):
+        import re
+
+        from repro.exec.pallas import DEVICE_SCOPES, GATHER_SCOPE
+        traced = Session(cache_dir=tmp_path).trace(workload="cg_sparse",
+                                                   n=256, iters=2)
+        plan = traced.analyze().codesign().lower(backend="pallas")
+        scopes = plan.device_scopes()
+        assert set(scopes.values()) == set(DEVICE_SCOPES)
+        # the names are instructions of the executable the plan runs
+        ex = get_backend("pallas").compiled(plan)
+        text = ex._jit.lower(*ex.leaf_shapes()).compile().as_text()
+        defined = set(re.findall(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", text,
+                                 re.M))
+        assert set(scopes) <= defined
+        # at least one gather instruction runs under the gather scope
+        assert any(re.search(rf"%?{re.escape(n)} = \S+ gather\(", text)
+                   for n, sc in scopes.items() if sc == GATHER_SCOPE)
+        # neither scope reads as a kernel name (cello_*)
+        assert not any(sc.startswith("cello_") for sc in DEVICE_SCOPES)
+        assert plan.device_scopes(backend="reference") == {}
+        dense = Session(cache_dir=tmp_path).trace(
+            workload="cg", n=32, iters=2).analyze().codesign() \
+            .lower(backend="pallas")
+        assert dense.device_scopes() == {}
+
+    def test_hlo_scopes_takes_the_innermost_scope(self):
+        from repro.exec.pallas import hlo_scopes
+        text = "\n".join([
+            'ENTRY %main {',
+            '  %fusion.6 = f32[8]{0} fusion(%p), kind=kLoop, '
+            'metadata={op_name="jit(f)/while/body/spmv_gather/mul" '
+            'source_line=3}',
+            '  ROOT %g.1 = s32[4]{0} gather(%a, %b), metadata={op_name='
+            '"jit(f)/csr_layout/vmap(spmv_gather)/gather"}',
+            '  %w.2 = s32[4]{0} while(%t), metadata={op_name='
+            '"jit(f)/csr_layout/vmap()/gather"}',
+            '  %copy.1 = f32[8]{0} copy(%x)',
+            '  %n.3 = f32[8]{0} add(%x, %y), metadata={op_name='
+            '"jit(f)/spmv_gathered/add"}',
+            '}'])
+        assert hlo_scopes(text) == {"fusion.6": "spmv_gather",
+                                    "g.1": "spmv_gather",
+                                    "w.2": "csr_layout"}

@@ -320,6 +320,83 @@ class TestSpans:
         depth = {s["name"]: s["depth"] for s in spans}
         for i in range(8):
             assert depth[f"outer{i}"] == 0 and depth[f"inner{i}"] == 1
+        # parents never cross threads
+        by_name = {s["name"]: s for s in spans}
+        for i in range(8):
+            assert by_name[f"outer{i}"]["parent"] is None
+            assert by_name[f"inner{i}"]["parent"] == by_name[f"outer{i}"]["id"]
+
+    def test_span_ids_and_parents(self, tmp_path):
+        tr = SpanTracer(enabled=True)
+        with tr.span("outer"):
+            with tr.span("inner", reqs=[3, 4]):
+                tr.record("synthetic", tr.now(), 0.0)
+            with tr.span("inner2"):
+                pass
+        with tr.span("root2"):
+            pass
+        spans = {s["name"]: s for s in tr.spans()}
+        ids = [s["id"] for s in spans.values()]
+        assert len(set(ids)) == len(ids) and min(ids) >= 1
+        assert spans["outer"]["parent"] is None
+        assert spans["root2"]["parent"] is None
+        assert spans["inner"]["parent"] == spans["outer"]["id"]
+        assert spans["inner2"]["parent"] == spans["outer"]["id"]
+        assert spans["synthetic"]["parent"] == spans["inner"]["id"]
+        # a list of scalars (a batch's request ids) stays a list
+        assert spans["inner"]["args"] == {"reqs": [3, 4]}
+        jsonl, chrome = tmp_path / "s.jsonl", tmp_path / "s.json"
+        tr.export_jsonl(jsonl)
+        tr.export_chrome(chrome)
+        assert validate_jsonl(jsonl) == validate_chrome(chrome) == 5
+        events = json.loads(chrome.read_text())["traceEvents"]
+        assert {(e["name"], e["id"], e["parent"]) for e in events} == \
+            {(s["name"], s["id"], s["parent"]) for s in spans.values()}
+
+    @pytest.mark.parametrize("bad", [
+        {"id": 0}, {"id": "1"}, {"id": True}, {"parent": 0},
+        {"parent": "x"}])
+    def test_validators_reject_bad_ids(self, tmp_path, bad):
+        rec = {"name": "x", "ts_us": 1.0, "dur_us": 0.0, "tid": 1, "pid": 1,
+               "depth": 0, "id": 1, "parent": None, "args": {}} | bad
+        path = tmp_path / "bad.jsonl"
+        path.write_text(json.dumps(rec) + "\n")
+        with pytest.raises(ValueError, match="id must be|parent must be"):
+            validate_jsonl(path)
+        ev = {"name": "x", "ph": "X", "ts": 1.0, "dur": 0.0, "pid": 1,
+              "tid": 1, "id": 1, "parent": None} | bad
+        path = tmp_path / "bad.json"
+        path.write_text(json.dumps({"traceEvents": [ev]}))
+        with pytest.raises(ValueError, match="id must be|parent must be"):
+            validate_chrome(path)
+
+    def test_spans_carry_the_profilers_clock(self, tmp_path):
+        """A span mirrored into a CPU ``jax.profiler`` trace starts, on the
+        profile's host plane, where the tracer's own record says."""
+        import glob
+        import time
+
+        import jax
+        from jax.profiler import ProfileData
+        tr = SpanTracer(enabled=True, jax_profiler=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("obs.clock_probe"):
+                time.sleep(0.005)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        data = ProfileData.from_file(path)
+        env = dict(data.find_plane_with_name("Task Environment").stats)
+        (ev,) = [e for plane in data.planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name == "obs.clock_probe"]
+        (rec,) = tr.spans()
+        start_us = (env["profile_start_time"] + ev.start_ns) / 1e3
+        assert abs(start_us - rec["ts_us"]) < 1e3
+        assert rec["dur_us"] == pytest.approx(ev.duration_ns / 1e3, abs=1e3)
 
 
 # ---------------------------------------------------------------------------
@@ -369,7 +446,7 @@ class TestFacade:
         for needed in ("session.stage_s", "session.stage_runs",
                        "codesign.search_s", "codesign.points",
                        "codesign.cache.hits", "codesign.cache.misses",
-                       "exec.compile_s", "exec.run_s",
+                       "exec.compile_s", "exec.dispatch_s",
                        "serve.requests", "serve.e2e_latency_s"):
             assert needed in names
         with pytest.raises(TypeError):

@@ -869,3 +869,109 @@ class TestTypedRequestsAndConfig:
             assert any("float32" in lb for lb in labels)
         finally:
             srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the worker's spans, request ids, and the batched dispatch's instruments
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def spans():
+    """The global tracer, on and empty for the test."""
+    from repro import obs
+    tr = obs.tracer()
+    was = tr.enabled
+    tr.clear()
+    tr.enable()
+    yield tr
+    if not was:
+        tr.disable()
+    tr.clear()
+
+
+WORKER_SPANS = ("serve.wait", "serve.batch_open", "serve.batch",
+                "serve.settle")
+
+
+class TestWorkerSpans:
+    def test_request_ids_join_submit_to_settle(self, tmp_path, spans):
+        srv = Server(config=ServeConfig(max_batch_size=4, autostart=False),
+                     session=Session(cache_dir=tmp_path))
+        futs = [srv.submit(request("cg", n=32, iters=2, seed=s))
+                for s in range(6)]
+        srv.start()
+        for f in futs:
+            f.result(timeout=300)
+        srv.close()
+        recs = spans.spans()
+        submits = [s for s in recs if s["name"] == "serve.submit"]
+        rids = [s["args"]["req"] for s in submits]
+        assert len(set(rids)) == 6
+        for name in ("serve.batch_open", "serve.batch", "serve.settle"):
+            joined = [r for s in recs if s["name"] == name
+                      for r in s["args"]["reqs"]]
+            assert sorted(joined) == sorted(rids), name
+        # each batch: open, then the batch, then its settle, on the worker
+        for b in (s for s in recs if s["name"] == "serve.batch"):
+            (settle,) = [s for s in recs if s["name"] == "serve.settle"
+                         and s["args"]["reqs"] == b["args"]["reqs"]]
+            assert settle["tid"] == b["tid"]
+            assert settle["parent"] is None and b["parent"] is None
+            assert settle["ts_us"] >= b["ts_us"] + b["dur_us"] - 1.0
+        # the dispatch's children: stack, launch, readback
+        for d in (s for s in recs if s["name"] == "serve.dispatch"):
+            kids = [s["name"] for s in recs if s["parent"] == d["id"]]
+            assert kids == ["serve.stack", "serve.launch", "serve.readback"]
+
+    def test_worker_spans_cover_its_loop(self, tmp_path, spans):
+        """Every instant of the worker's loop lies in one of its top-level
+        spans: they cover at least 99% of the thread's life."""
+        srv = Server(config=ServeConfig(max_batch_size=4, max_wait_us=2000,
+                                        autostart=False),
+                     session=Session(cache_dir=tmp_path))
+        t0 = spans.now()
+        srv.start()
+        futs = []
+        for s in range(20):
+            futs.append(srv.submit(request("cg", n=32, iters=2, seed=s)))
+            time.sleep(0.025)
+        for f in futs:
+            f.result(timeout=300)
+        srv.close()
+        t1 = spans.now()
+        recs = spans.spans()
+        (tid,) = {s["tid"] for s in recs if s["name"] == "serve.wait"}
+        top = [s for s in recs if s["tid"] == tid and s["parent"] is None]
+        assert {s["name"] for s in top} == set(WORKER_SPANS)
+        covered = sum(s["dur_us"] for s in top) * 1e-6
+        assert covered >= 0.99 * (t1 - t0)
+        # top-level spans of one thread never overlap
+        top.sort(key=lambda s: s["ts_us"])
+        for a, b in zip(top, top[1:]):
+            assert a["ts_us"] + a["dur_us"] <= b["ts_us"] + 1.0
+
+    def test_h2d_bytes_launch_and_readback(self, tmp_path):
+        import jax.numpy as jnp
+        from repro import obs
+        traced, plan = _plan(tmp_path, "cg", n=64, iters=2)
+        bp = plan.batched()
+        shared, per_req = _batch_feeds(bp, traced.program, 3)
+        bp.run_many(per_req, shared)                  # 3 -> 4 lanes
+        lanes = sum(4 * np.asarray(per_req[0][n]).nbytes
+                    for n in bp.batched_leaves)
+        shared_b = sum(np.asarray(v).nbytes for v in shared.values())
+
+        def h2d():
+            (cell,) = obs.snapshot(bp._scope)["serve.h2d_bytes"]["cells"]
+            return cell["value"]
+        assert h2d() == shared_b + lanes
+        # an operator already on the device is not transferred again
+        dev = {k: jnp.asarray(v) for k, v in shared.items()}
+        bp.run_many(per_req, dev)
+        assert h2d() == shared_b + 2 * lanes
+        snap = obs.snapshot(bp._scope)
+        launches = {c["labels"]["traced"]: c["value"]["count"]
+                    for c in snap["serve.launch_s"]["cells"]}
+        assert launches == {"True": 1, "False": 1}
+        (rb,) = snap["serve.readback_s"]["cells"]
+        assert rb["value"]["count"] == 2
