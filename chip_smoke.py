@@ -35,9 +35,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 #: runs in fp32 (unit roundoff 6e-8) and every iteration re-rounds n-term
 #: dot products and update vectors.  The dense matvec runs on the MXU at
 #: Precision.HIGHEST (fp32-accurate; XLA's default would round operands
-#: to bf16, 4e-3 relative); the sparse one multiplies gathered entries in
-#: fp32 and sums each tile's rows on the MXU against an exact one-hot
-#: matrix, also at Precision.HIGHEST.
+#: to bf16, 4e-3 relative); the sparse one (the Laplacian, on the diagonal
+#: layout) sums each row's fp32 products with shifted x on the VPU.
 #: * dense cg (kappa ~ 5, converged after 32 iterations): fp32 sits at
 #:   its floor — an XLA:CPU fp32 solve is 5.7e-7 from float64 with a true
 #:   residual of 5.9e-7;

@@ -278,13 +278,19 @@ def decode_graph(cfg: ArchConfig, batch: int, kv_len: int) -> OpGraph:
 #                pass.  A group usually lowers to ONE pass; it splits into
 #                sequential passes exactly where a contraction (or an
 #                spmv) reads a vector produced earlier in the same group
-#                (the value must fully materialize first).  A CSR spmv in
-#                a pass streams its entries in a padded per-tile layout
-#                (tile ``t`` owns exactly its own rows' entries, padded to
-#                ``B`` slots — static, from the operand's pattern meta):
-#                XLA gathers ``data * x[indices]`` into that layout and
-#                the kernel sums each tile's rows as one MXU product with
-#                a one-hot row matrix (Mosaic cannot gather in-kernel).
+#                (the value must fully materialize first).  A CSR spmv
+#                whose pattern meta fixes every row's column offsets to a
+#                small static set (``laplacian5``, ``banded``) streams a
+#                diagonal layout: ``(K, n)`` values, one row per offset,
+#                built once per dispatch from the CSR entries, and the
+#                kernel sums ``diag_k * x[i + d_k]`` over static slices of
+#                ``x``'s tile and its neighbours.  Any other spmv streams
+#                its entries in a padded per-tile layout (tile ``t`` owns
+#                exactly its own rows' entries, padded to ``B`` slots —
+#                static, from the operand's pattern meta): XLA gathers
+#                ``data * x[indices]`` into that layout and the kernel sums
+#                each tile's rows as one MXU product with a one-hot row
+#                matrix (Mosaic cannot gather in-kernel).
 #   ``block``  — one `pl.pallas_call` with whole arrays as single blocks:
 #                stencil sweeps need halo rows, so they cannot row-stream
 #                without overlap; the explicit region holds the full grid.
@@ -320,6 +326,15 @@ KERNEL_VMEM_BYTES = 32 << 20
 #: double buffers are not pins, and an all-implicit split still streams
 #: (4 MiB holds a 256-row spmv tile of a 5-point operand)
 KERNEL_VMEM_FLOOR = 4 << 20
+#: most diagonals an spmv operand may have and still take the diagonal
+#: layout: its kernel unrolls one static slice of ``x`` per diagonal
+DIA_MAX_OFFSETS = 32
+#: rows per grid step, at most, of the pass building a diagonal layout:
+#: its one-hot row matrix is ``(B + 128, tile)`` with ``B`` the tile's
+#: entries, so its work per row grows with the tile, while a smaller tile
+#: pays more grid steps (the 1024² Laplacian's layout on one TPU v5e:
+#: 4.4, 4.2 and 5.8 ms at 128, 256 and 512 rows)
+DIA_LAYOUT_TILE_ROWS = 256
 
 
 def kernel_block_bytes(shape) -> int:
@@ -365,7 +380,9 @@ class StreamPass:
     resident: Tuple[str, ...]       # operands held in VMEM across all tiles
     reductions: Tuple[str, ...]     # rank-0 accumulators in this pass
     vmem_bytes: int = 0             # planned double-buffered working set
-    spmv: Tuple[str, ...] = ()      # CSR spmv ops (per-tile entry layout)
+    spmv: Tuple[str, ...] = ()      # CSR spmv ops
+    dia: Tuple[str, ...] = ()       # those on the diagonal layout (the
+    #                                 rest: the per-tile entry layout)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -383,7 +400,9 @@ class GroupKernel:
                 res = f" res={'+'.join(p.resident)}" if p.resident else ""
                 red = f" acc={'+'.join(p.reductions)}" if p.reductions \
                     else ""
-                sp = f" spmv={'+'.join(p.spmv)}" if p.spmv else ""
+                sp = (" spmv=" + "+".join(
+                    f"{o}:{'dia' if o in p.dia else 'csr'}"
+                    for o in p.spmv)) if p.spmv else ""
                 bits.append(f"{p.rows}r/{p.tile_rows}t{res}{red}{sp}")
             tag = " | ".join(bits)
             n = len(self.passes)
@@ -427,29 +446,66 @@ def csr_tile_entries(params, rows: int, tile_rows: int) -> Optional[int]:
                          get("bandwidth"))
 
 
+def spmv_offsets(params, rows: int) -> Optional[Tuple[int, ...]]:
+    """The diagonal offsets an spmv operand runs on — the static offset
+    set of its pattern meta (``params``, as for :func:`csr_tile_entries`;
+    :func:`repro.frontends.sparse.pattern_offsets`) — or ``None`` when it
+    runs on the padded per-tile layout: no such set, more than
+    :data:`DIA_MAX_OFFSETS` diagonals, or no row tile to build the
+    diagonal layout with."""
+    from ..frontends.sparse import pattern_offsets
+    get = params.get if hasattr(params, "get") else dict(params).get
+    if get("pattern") is None or dia_layout_tile(rows) is None:
+        return None
+    offsets = pattern_offsets(get("pattern"), rows, get("bandwidth"))
+    if offsets is None or len(offsets) > DIA_MAX_OFFSETS:
+        return None
+    return offsets
+
+
+def dia_layout_tile(rows: int) -> Optional[int]:
+    """Rows per grid step of the pass that builds a diagonal layout from
+    the CSR entries: the largest legal tile up to
+    :data:`DIA_LAYOUT_TILE_ROWS`, or ``None`` when ``rows`` has none."""
+    if rows <= DIA_LAYOUT_TILE_ROWS:
+        return rows
+    return next((t for t in _TILE_ROW_CANDIDATES
+                 if t <= DIA_LAYOUT_TILE_ROWS and rows % t == 0), None)
+
+
+def dia_halo_tiles(offsets, tile_rows: int) -> int:
+    """Neighbour tiles of ``x`` a diagonal-layout spmv reads on each side
+    of its own tile."""
+    return -(-max(abs(d) for d in offsets) // tile_rows)
+
+
 def check_csr_feeds(units, program, feeds) -> None:
     """Refuse CSR feeds that do not fit the per-tile layout their spmv
-    passes were planned with (``units``: an :class:`ExecPlan`'s units).
-    A row tile holding more entries than its padded slots — feeds whose
-    rows are spread differently from the pattern meta the plan was
-    traced with — would otherwise lose the excess entries silently."""
+    passes were planned with (``units``: an :class:`ExecPlan`'s units);
+    a diagonal-layout spmv builds its layout from per-tile windows of
+    :func:`dia_layout_tile` rows.  A row tile holding more entries than
+    its padded slots — feeds whose rows are spread differently from the
+    pattern meta the plan was traced with — would otherwise lose the
+    excess entries silently."""
     import numpy as np
     checked = set()
     for unit in units:
         sp = unit.sp
         for op in (sp.spmv if sp is not None else ()):
             ipn = program.nodes[op].inputs[0]
-            if (ipn, sp.tile_rows) in checked or ipn not in feeds:
+            tile = dia_layout_tile(sp.rows) if op in sp.dia \
+                else sp.tile_rows
+            if (ipn, tile) in checked or ipn not in feeds:
                 continue
-            checked.add((ipn, sp.tile_rows))
+            checked.add((ipn, tile))
             slots = csr_tile_entries(program.nodes[ipn].params, sp.rows,
-                                     sp.tile_rows)
-            starts = np.asarray(feeds[ipn][::sp.tile_rows])
+                                     tile)
+            starts = np.asarray(feeds[ipn][::tile])
             most = int(np.max(np.diff(starts)))
             if most > slots:
                 raise ValueError(
                     f"CSR feed {ipn!r} puts {most} entries in one "
-                    f"{sp.tile_rows}-row tile; the plan was lowered for "
+                    f"{tile}-row tile; the plan was lowered for "
                     f"at most {slots} (its pattern meta): trace the plan "
                     f"for this operand's pattern")
 
@@ -469,6 +525,29 @@ def _spmv_tile_bytes(tile_rows: int, entries: int) -> int:
     return (2 * kernel_block_bytes((entries,))
             + 2 * 2 * kernel_block_bytes((tile_rows,))
             + 3 * tile_rows * entries * KERNEL_ITEMSIZE)
+
+
+def _dia_tile_bytes(tile_rows: int, n_offsets: int, halo: int) -> int:
+    """VMEM of one diagonal-layout spmv in a pass: its double-buffered
+    ``(K, tile)`` diagonals block and ``2·halo + 1`` ``(1, tile)`` tiles
+    of ``x``, their concatenated window, and the running sum with one
+    product."""
+    x_tile = kernel_block_bytes((tile_rows,))
+    return (2 * kernel_block_bytes((n_offsets, tile_rows))
+            + 3 * (2 * halo + 1) * x_tile + 2 * x_tile)
+
+
+def dia_layout_bytes(tile_rows: int, entries: int, n_offsets: int) -> int:
+    """VMEM of the pass building one operand's diagonal layout: its
+    double-buffered column-id and value windows (the tile's ``B`` entries
+    in whole 128-entry rows, one row more than ``B`` fills), ``(1, tile)``
+    slot bounds and ``(K, tile)`` output block, and the one-hot row matrix
+    over the window with its iota and mask temporaries."""
+    window = -(-entries // _LANE) + 1
+    return (2 * 2 * kernel_block_bytes((window, 1, _LANE))
+            + 2 * 2 * kernel_block_bytes((tile_rows,))
+            + 2 * kernel_block_bytes((n_offsets, tile_rows))
+            + 3 * tile_rows * window * _LANE * KERNEL_ITEMSIZE)
 
 
 def _row_bytes(shape) -> int:
@@ -532,7 +611,8 @@ def _segment_group(graph: OpGraph, group) -> list:
         if op.is_einsum and op.spec in STREAM_EINSUMS:
             needs_break = op.inputs[STREAM_EINSUMS[op.spec]] in produced
         if op.spec == "spmv":
-            # x is gathered whole by column index: it must materialize
+            # x is read beyond the tile's own rows (by column index, or
+            # its neighbour tiles): it must materialize
             needs_break = op.inputs[3] in produced
         if not needs_break and graph.tensors[op.output].shape != ():
             needs_break = any(t in late for t in op.inputs)
@@ -655,9 +735,13 @@ def _classify_pass(graph: OpGraph, seg, explicit_bytes: int):
     if any(dict(m).get("pattern") is None for m in metas):
         return "spmv operand carries no CSR pattern meta"
 
+    offsets = [spmv_offsets(m, rows) for m in metas]
+
     def spmv_bytes(t: int) -> int:
         return sum(_spmv_tile_bytes(t, csr_tile_entries(m, rows, t))
-                   for m in metas)
+                   if offs is None else
+                   _dia_tile_bytes(t, len(offs), dia_halo_tiles(offs, t))
+                   for m, offs in zip(metas, offsets))
 
     res_bytes = sum(kernel_block_bytes(graph.tensors[t].shape)
                     for t in resident)
@@ -671,7 +755,9 @@ def _classify_pass(graph: OpGraph, seg, explicit_bytes: int):
                       resident=tuple(resident), reductions=tuple(reductions),
                       vmem_bytes=(2 * (tile * per_row + res_bytes)
                                   + (spmv_bytes(tile) if spmvs else 0)),
-                      spmv=tuple(op.name for op in spmvs))
+                      spmv=tuple(op.name for op in spmvs),
+                      dia=tuple(op.name for op, offs in zip(spmvs, offsets)
+                                if offs is not None))
 
 
 # ---------------------------------------------------------------------------

@@ -20,15 +20,23 @@ kernels, no per-call ``result_type``/``asarray`` conversion.  The pieces:
   e.g. ``nalpha = -alpha``) are computed before the kernel and read from
   SMEM, so tiled ops use them without a pass break; reduction-derived
   scalar epilogues (``beta = rs'/rs``, a norm's ``sqrt``) run after it.
-* CSR SpMV ops run inside stream passes on a padded per-tile entry
-  layout: tile ``t`` owns exactly its own rows' entries, padded to ``B``
-  slots (static, from the operand's pattern meta).  The layout — column
-  ids and values as contiguous per-tile windows, and each row's slot
-  range inside its window — is derived once per dispatch from the CSR
-  leaves; each pass gathers ``values * x[cols]`` in XLA (Mosaic cannot
-  gather by column index in-kernel) and the kernel sums each tile's rows
-  as one MXU product with a one-hot row matrix built from those ranges —
-  no scatter.  Feeds must match the pattern meta the plan was built for
+* CSR SpMV ops run inside stream passes on one of two layouts, both
+  derived once per dispatch from the CSR leaves, outside any rolled loop.
+  Where the operand's pattern meta fixes every row's column offsets to a
+  small static set ``d_k`` (``laplacian5``, ``banded``), the *diagonal*
+  layout: ``(K, n)`` values, row ``k`` holding each row's entry at offset
+  ``d_k`` (zero where it has none), built by one Pallas pass
+  (:func:`_dia_layout_fn`); the kernel reads ``x``'s tile with as many
+  neighbour tiles as the largest offset needs and sums
+  ``diag_k * x[i + d_k]`` over static slices of that window, on the VPU —
+  no gather.  Otherwise the padded *per-tile* layout: tile ``t`` owns
+  exactly its own rows' entries, padded to ``B`` slots (static, from the
+  pattern meta), as column ids and values in contiguous per-tile windows
+  plus each row's slot range inside its window; each pass gathers
+  ``values * x[cols]`` in XLA (Mosaic cannot gather by column index
+  in-kernel) and the kernel sums each tile's rows as one MXU product with
+  a one-hot row matrix built from those ranges — no scatter.  Feeds must
+  match the pattern meta the plan was built for
   (:func:`core.lowering.check_csr_feeds` refuses those that do not).
 * ``block`` units hold whole arrays as single blocks (stencil halos).
 * ``jnp`` units — irregular gathers, >2-operand einsums, working sets
@@ -72,14 +80,17 @@ from __future__ import annotations
 
 import os
 import re
-from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Set, Tuple)
 
 from .. import obs
 from ..testing import faults
 from ..core.lowering import (STREAM_EINSUMS, ExecPlan, GroupKernel,
                              StreamPass, check_csr_feeds, csr_tile_entries,
-                             flatten_units, kernel_block_bytes,
-                             plan_execution, select_group_kernels)
+                             dia_halo_tiles, dia_layout_bytes,
+                             dia_layout_tile, flatten_units,
+                             kernel_block_bytes, plan_execution,
+                             select_group_kernels, spmv_offsets)
 from .base import Executor, plan_groups, plan_program
 from .reference import eval_node
 
@@ -96,13 +107,18 @@ _FEED_COPY_B = obs.registry().counter(
 _UNITS = obs.registry().counter(
     "exec.units", "execution units built at compile, by kind "
     "(stream | block | jnp)")
+_SPMV_LAYOUT = obs.registry().counter(
+    "exec.spmv_layout", "spmv passes each dispatch runs, by the layout its "
+    "operand streams in (layout: dia | csr), per compiled program (scope "
+    "label)")
 
 _BACKEND_PROBE: Optional[str] = None
 
 #: ``jax.named_scope`` names of the device work that runs outside the
-#: kernels: the spmv's ``values * x[cols]`` gather, and the CSR operand's
-#: per-tile layout.  They must not start with ``cello_``, the kernels'
-#: prefix.  A device trace names ops by their HLO instruction alone;
+#: stream kernels: the per-tile spmv's ``values * x[cols]`` gather, and
+#: the CSR operand's layout build (per-tile windows, or the diagonal
+#: layout).  They must not start with ``cello_``, the kernels' prefix.  A
+#: device trace names ops by their HLO instruction alone;
 #: :meth:`_SingleProgram.device_scopes` maps those names back to scopes.
 GATHER_SCOPE = "spmv_gather"
 LAYOUT_SCOPE = "csr_layout"
@@ -116,6 +132,9 @@ _HLO_OP = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?'
 _VMEM_HEADROOM = 8 << 20
 _VMEM_DEFAULT = 16 << 20
 _VMEM_CEILING = 100 << 20
+#: lanes of a vreg: the diagonal layout's pass reads CSR entries in rows
+#: of this many
+_LANES = 128
 
 
 class KernelDtypeError(TypeError):
@@ -236,6 +255,15 @@ def _classify_nodes(nodes) -> Dict[str, str]:
 # kernel builders (one per ExecUnit kind)
 # --------------------------------------------------------------------------
 
+class _Spmv(NamedTuple):
+    """How one spmv of a stream pass reads its operand."""
+    layout: str                      # "dia" | "csr"
+    derived: str                     # env name of its loop-invariant layout
+    x: str
+    offsets: Tuple[int, ...] = ()    # dia: the diagonals' column offsets
+    halo: int = 0                    # dia: x's neighbour tiles each side
+
+
 class _StreamCall:
     """One tile-streaming ``pl.pallas_call`` for a :class:`StreamPass`.
 
@@ -265,24 +293,44 @@ class _StreamCall:
                 bucket.append(name)
 
         tr = sp.tile_rows
-        # per spmv: per-tile entry values and each row's slot range, built
-        # from loop-invariant layouts derived once per dispatch
-        self.spmv: Dict[str, Tuple[str, str]] = {}
+        # per spmv, from loop-invariant layouts derived once per dispatch:
+        # on the diagonal layout its (K, rows) diagonals and x's tile with
+        # its neighbours; on the per-tile layout its entry values and each
+        # row's slot range
+        self.spmv: Dict[str, _Spmv] = {}
         self.tile_in: List[str] = []
+        self.x_shift: Dict[str, int] = {}   # x tile input -> tile offset
         self.derived: Dict[str, Callable] = {}
         for nd in self.nodes:
             for t in nd.inputs:
                 if t not in produced:
                     _want(t, in_names)
             cls = self.classes[nd.name]
-            if nd.op == "spmv":
+            if nd.op == "spmv" and nd.name in sp.dia:
+                ipn = nd.inputs[0]
+                params = program.nodes[ipn].params
+                offsets = spmv_offsets(params, sp.rows)
+                halo = dia_halo_tiles(offsets, tr)
+                lt = dia_layout_tile(sp.rows)
+                lay = f"{ipn}@dia"
+                self.derived[lay] = _dia_layout_fn(
+                    *nd.inputs[:3], lt,
+                    csr_tile_entries(params, sp.rows, lt), offsets)
+                self.spmv[nd.name] = _Spmv("dia", lay, nd.inputs[3],
+                                           offsets, halo)
+                self.shapes[f"{nd.name}@dia"] = (len(offsets), sp.rows)
+                self.tile_in.append(f"{nd.name}@dia")
+                for j in range(-halo, halo + 1):
+                    self.x_shift[f"{nd.name}@x{j}"] = j
+                    self.tile_in.append(f"{nd.name}@x{j}")
+            elif nd.op == "spmv":
                 ipn = nd.inputs[0]
                 entries = csr_tile_entries(program.nodes[ipn].params,
                                            sp.rows, tr)
                 lay = f"{ipn}@t{tr}"
                 self.derived[lay] = _csr_tiles_fn(*nd.inputs[:3], tr,
                                                   entries)
-                self.spmv[nd.name] = (lay, nd.inputs[3])
+                self.spmv[nd.name] = _Spmv("csr", lay, nd.inputs[3])
                 self.shapes[f"{nd.name}@vals"] = \
                     (sp.rows // tr, 1, entries)
                 self.tile_in += [f"{nd.name}@vals", f"{nd.name}@first",
@@ -352,7 +400,11 @@ class _StreamCall:
             for nd in nodes:
                 cls = classes[nd.name]
                 if cls == "tiled":
-                    if nd.op == "spmv":         # one-hot row sum, MXU
+                    if nd.op == "spmv" and self.spmv[nd.name].layout \
+                            == "dia":           # sum of shifted x, VPU
+                        v = _dia_rows(nd.name, self.spmv[nd.name], tref,
+                                      tr)
+                    elif nd.op == "spmv":       # one-hot row sum, MXU
                         vals = tref[f"{nd.name}@vals"][...]     # (1, B)
                         first = tref[f"{nd.name}@first"][...]   # (1, tr)
                         stop = tref[f"{nd.name}@stop"][...]
@@ -395,6 +447,13 @@ class _StreamCall:
             return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
         def tile_spec(name):
+            if name in self.x_shift:    # x's tile or a neighbour, clamped
+                j = self.x_shift[name]
+                return pl.BlockSpec((1, tr), lambda i: (0, jnp.minimum(
+                    jnp.maximum(i + j, 0), n_tiles - 1)))
+            if name.endswith("@dia"):           # (K, tr) diagonals
+                return pl.BlockSpec((self.shapes[name][0], tr),
+                                    lambda i: (0, i))
             if not name.endswith("@vals"):      # rows' slot bounds
                 return pl.BlockSpec((1, tr), lambda i: (0, i))
             return pl.BlockSpec((None, 1, self.shapes[name][-1]),
@@ -437,11 +496,16 @@ class _StreamCall:
             if call is None:
                 call = self._built[dtype] = self._build(dtype)
             tile_args = []
-            for lay, x in self.spmv.values():
-                cols, data, first, stop = (
-                    env[lay] if lay in env else self.derived[lay](env, dtype))
+            for s in self.spmv.values():
+                lay = (env[s.derived] if s.derived in env
+                       else self.derived[s.derived](env, dtype))
+                if s.layout == "dia":
+                    x = jnp.reshape(jnp.asarray(env[s.x], dtype), (1, -1))
+                    tile_args += [lay] + [x] * (2 * s.halo + 1)
+                    continue
+                cols, data, first, stop = lay
                 with jax.named_scope(GATHER_SCOPE):
-                    vals_t = data * jnp.asarray(env[x], dtype)[cols]
+                    vals_t = data * jnp.asarray(env[s.x], dtype)[cols]
                 tile_args += [vals_t, first, stop]
             row = [jnp.reshape(jnp.asarray(env[n], dtype),
                                _row_shape(self.shapes[n]))
@@ -483,6 +547,22 @@ class _StreamCall:
         return self.apply(env, dtype)
 
 
+def _dia_rows(name: str, s: _Spmv, tref, tr: int):
+    """One tile of a diagonal-layout spmv inside its stream kernel:
+    ``Σ_k diag_k * x[i + d_k]``, each term a static slice of the window of
+    ``x``'s tile and its neighbours, summed in the program's dtype."""
+    import jax.numpy as jnp
+    dia = tref[f"{name}@dia"][...]                          # (K, tr)
+    win = jnp.concatenate([tref[f"{name}@x{j}"][...]
+                           for j in range(-s.halo, s.halo + 1)], axis=1)
+    mid = s.halo * tr                       # window column of row 0
+    v = None
+    for k, d in enumerate(s.offsets):
+        term = dia[k:k + 1, :] * win[:, mid + d:mid + d + tr]
+        v = term if v is None else v + term
+    return v
+
+
 def _csr_tiles_fn(indptr: str, indices: str, data: str, tile_rows: int,
                   entries: int) -> Callable:
     """A function building one CSR operand's padded per-tile layout:
@@ -518,6 +598,139 @@ def _csr_tiles_fn(indptr: str, indices: str, data: str, tile_rows: int,
         return (windows(jnp.asarray(env[indices])),
                 windows(jnp.asarray(env[data], dtype)), first, stop)
     return build
+
+
+def _dia_layout_fn(indptr: str, indices: str, data: str, tile_rows: int,
+                   entries: int, offsets: Tuple[int, ...]) -> Callable:
+    """A function building one CSR operand's diagonal layout: ``(K,
+    rows)`` values, row ``k`` holding each row's entries at column offset
+    ``offsets[k]`` (zero where it has none).  One Pallas pass over row
+    tiles, with no per-entry gather or scatter: each tile's entries (at
+    most ``entries``, which :func:`core.lowering.check_csr_feeds` holds
+    feeds to) arrive as one window of whole 128-entry rows of the column
+    ids and values; the one-hot row matrix of the entries' slot ranges
+    gives each entry its row, its column minus that row picks its
+    diagonal, and one MXU product with the same matrix sums the entries
+    into place.  A row holding an entry on none of ``offsets`` reads NaN
+    on every diagonal, so the spmv's answer in that row is NaN, never an
+    answer missing the entry."""
+    operand = re.sub(r"\W", "_", data.rsplit(".", 1)[0])
+    window_rows = -(-entries // _LANES) + 1      # covers any start's offset
+    built: Dict[Any, Callable] = {}
+
+    def build(env, dtype):
+        import jax
+        import jax.numpy as jnp
+        with jax.named_scope(LAYOUT_SCOPE):
+            ip = jnp.asarray(env[indptr])
+            rows = ip.shape[0] - 1
+            # each tile's window starts at the 128-entry row holding its
+            # first entry; slot ranges count from there
+            row0 = ip[:-1].reshape(-1, tile_rows)[:, 0] // _LANES
+            base = jnp.broadcast_to((row0 * _LANES)[:, None], (
+                rows // tile_rows, tile_rows)).reshape(rows)
+            first = (ip[:-1] - base)[None, :]
+            stop = (ip[1:] - base)[None, :]
+
+            def by_rows(a):
+                # (128-entry rows, 1, 128), zero-padded so that every
+                # tile's window lies inside
+                m = -(-a.shape[0] // _LANES) + window_rows
+                return jnp.pad(a, (0, m * _LANES - a.shape[0])).reshape(
+                    m, 1, _LANES)
+
+            call = built.get(dtype)
+            if call is None:
+                call = built[dtype] = _dia_layout_call(
+                    f"cello_dia_{operand}", rows, tile_rows, window_rows,
+                    entries, offsets, dtype)
+            return call(row0, by_rows(jnp.asarray(env[indices])),
+                        by_rows(jnp.asarray(env[data], dtype)), first, stop)
+    return build
+
+
+def _bf16_parts(v):
+    """Float32 ``v`` as three bfloat16 arrays whose float32 sum is ``v``
+    exactly: a product with a 0/1 matrix is then exact in one bfloat16
+    MXU pass per part."""
+    import jax.numpy as jnp
+    hi = v.astype(jnp.bfloat16)
+    rest = v - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return [hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)]
+
+
+def _dia_layout_call(name: str, rows: int, tr: int, window_rows: int,
+                     entries: int, offsets: Tuple[int, ...],
+                     dtype) -> Callable:
+    """The Pallas pass of :func:`_dia_layout_fn`: each tile's first window
+    row (scalar-prefetched), the ``(rows of 128, 1, 128)`` column ids and
+    values, and the slot ranges in; ``(K, rows)`` diagonals out."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    bf16 = jnp.bfloat16
+    n_diag = len(offsets)
+    slots = window_rows * _LANES
+    wide = jnp.dtype(dtype).itemsize > 4      # interpret mode only
+
+    def kernel(row0_ref, cols_ref, vals_ref, first_ref, stop_ref, out_ref):
+        i = pl.program_id(0)
+        slot = lax.broadcasted_iota(jnp.int32, (slots, tr), 0)
+        onehot = ((slot >= first_ref[...])
+                  & (slot < stop_ref[...])).astype(bf16)  # (slots, tr)
+        # each entry's row in the tile, and whether it has one: the rows
+        # [j // 32, j % 32, 1] (exact in bfloat16) against the one-hot
+        j = lax.broadcasted_iota(jnp.int32, (8, tr), 1)
+        r = lax.broadcasted_iota(jnp.int32, (8, tr), 0)
+        lhs = jnp.where(r == 0, j // 32,
+                        jnp.where(r == 1, j % 32, (r == 2).astype(
+                            jnp.int32))).astype(bf16)
+        pos = lax.dot_general(lhs, onehot, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        owned = pos[2:3] > 0.5                              # (1, slots)
+
+        def lanes(ref):                       # (window_rows, 1, 128) ->
+            return jnp.concatenate(           # (1, slots)
+                [ref[k] for k in range(window_rows)], axis=1)
+
+        off = lanes(cols_ref) - (i * tr + (pos[0:1] * 32 + pos[1:2])
+                                 .astype(jnp.int32))
+        vals = lanes(vals_ref)
+        parts = [vals] if wide else _bf16_parts(vals.astype(jnp.float32))
+        picked, hit = [], jnp.zeros(owned.shape, bool)
+        for d in offsets:
+            on = owned & (off == d)
+            hit = hit | on
+            picked += [jnp.where(on, p, jnp.zeros_like(p)) for p in parts]
+        picked.append((owned & ~hit).astype(parts[0].dtype))
+        picked += [jnp.zeros_like(parts[0])] * (-len(picked) % 16)
+        acc = jnp.dot(jnp.concatenate(picked, axis=0),
+                      onehot.astype(dtype) if wide else onehot,
+                      precision=lax.Precision.HIGHEST if wide else None,
+                      preferred_element_type=dtype if wide
+                      else jnp.float32)                      # (R, tr)
+        n = len(parts)
+        diag = jnp.concatenate(
+            [sum(acc[k * n + q:k * n + q + 1] for q in range(n))
+             for k in range(n_diag)], axis=0)
+        stray = acc[n_diag * n:n_diag * n + 1] > 0.5
+        out_ref[...] = jnp.where(stray, jnp.nan, diag).astype(dtype)
+
+    window = pl.BlockSpec(
+        (pl.Element(window_rows), pl.Element(1), pl.Element(_LANES)),
+        lambda i, row0: (row0[i], 0, 0))
+    row = pl.BlockSpec((1, tr), lambda i, row0: (0, i))
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((n_diag, rows), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows // tr,),
+            in_specs=[window, window, row, row],
+            out_specs=pl.BlockSpec((n_diag, tr), lambda i, row0: (0, i))),
+        **_pallas_call_kwargs(name, dtype,
+                              dia_layout_bytes(tr, entries, n_diag), 1))
 
 
 def _accumulate(ref, part, i):
@@ -737,6 +950,15 @@ class _SingleProgram:
         for i in (*pro, *tmpl, *epi):
             _UNITS.inc(backend="pallas", kind=units[i].kind,
                        scope=self._scope)
+        # spmv passes per dispatch, by layout: a rolled body runs n_iters
+        # times
+        self.spmv_layouts: Dict[str, int] = {}
+        for calls, times in ((self._pro, 1), (self._epi, 1),
+                             (self._tmpl, roll.n_iters if roll else 0)):
+            for call in calls:
+                for s in getattr(call, "spmv", {}).values():
+                    self.spmv_layouts[s.layout] = \
+                        self.spmv_layouts.get(s.layout, 0) + times
 
         if roll is not None:
             tmpl_ops = {o for i in tmpl for o in units[i].ops}
@@ -853,6 +1075,9 @@ class _SingleProgram:
                 _FEED_COPY_B.inc(copied, backend="pallas",
                                  scope=self._scope)
         _DISPATCHES.inc(backend="pallas", scope=self._scope)
+        for layout, n in self.spmv_layouts.items():
+            _SPMV_LAYOUT.inc(n, backend="pallas", layout=layout,
+                             scope=self._scope)
         with obs.span("exec.launch"):
             outs = self._jit(*args)
         return dict(zip(self.out_names, outs))
@@ -878,7 +1103,8 @@ class _SingleProgram:
         compilation caches make this a lookup after a run at those
         shapes).  A device trace names each op by its instruction
         (``fusion.6``) and nothing else; this says which are the gather
-        and which the layout build."""
+        and which the layout build (a plan whose spmvs all run on the
+        diagonal layout has no gather)."""
         text = self._jit.lower(*self.leaf_shapes(dtype)).compile().as_text()
         return hlo_scopes(text)
 

@@ -8,6 +8,9 @@ both sides of that contract:
 
 * :func:`pattern_nnz` / :func:`row_counts` — the exact nonzero count of a
   pattern, computed at build time to size the sub-leaves,
+* :func:`pattern_offsets` — the static column offsets of a pattern whose
+  rows all draw from one small set (its diagonals), for the kernels'
+  diagonal layout,
 * :func:`csr_component` — the deterministic values ``make_feeds`` generates
   at feed time (same per-(seed, operand) stream as every other leaf; the
   three sub-leaves of one operand share one stream so they describe one
@@ -46,7 +49,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import math
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -102,6 +105,27 @@ def row_counts(pattern: str, n: int, *, density: Optional[float] = None,
                        1, n)
     raise ValueError(f"unknown sparse pattern {pattern!r}; "
                      f"have {PATTERNS}")
+
+
+def pattern_offsets(pattern: str, n: int, bandwidth: Optional[int] = None
+                    ) -> Optional[Tuple[int, ...]]:
+    """The static set of column offsets ``j − i`` every row of a pattern
+    draws its entries from, ascending, or ``None`` for patterns whose
+    columns follow no such set (``random``, ``skewed``).  With it, ``A x``
+    is ``Σ_k diag_k ⊙ x[i + d_k]``: the diagonal layout the kernels use
+    instead of gathering ``x`` by column index."""
+    if pattern == "laplacian5":
+        g = _grid_side(n)
+        offsets = {-g, -1, 0, 1, g}
+    elif pattern == "banded":
+        row_counts(pattern, n, bandwidth=bandwidth)      # validates
+        offsets = set(range(-bandwidth, bandwidth + 1))
+    elif pattern in PATTERNS:
+        return None
+    else:
+        raise ValueError(f"unknown sparse pattern {pattern!r}; "
+                         f"have {PATTERNS}")
+    return tuple(sorted(d for d in offsets if abs(d) < n))
 
 
 def pattern_nnz(pattern: str, n: int, *, density: Optional[float] = None,

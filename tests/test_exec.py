@@ -699,9 +699,12 @@ class TestDispatchInstruments:
     def test_device_scopes_name_the_gather_and_the_layout(self, tmp_path):
         import re
 
-        from repro.exec.pallas import DEVICE_SCOPES, GATHER_SCOPE
-        traced = Session(cache_dir=tmp_path).trace(workload="cg_sparse",
-                                                   n=256, iters=2)
+        from repro.exec.pallas import (DEVICE_SCOPES, GATHER_SCOPE,
+                                       LAYOUT_SCOPE)
+        # a random pattern runs on the per-tile layout: gather and layout
+        traced = Session(cache_dir=tmp_path).trace(
+            workload="cg_sparse", n=256, iters=2, pattern="random",
+            density=0.02)
         plan = traced.analyze().codesign().lower(backend="pallas")
         scopes = plan.device_scopes()
         assert set(scopes.values()) == set(DEVICE_SCOPES)
@@ -716,6 +719,11 @@ class TestDispatchInstruments:
                    for n, sc in scopes.items() if sc == GATHER_SCOPE)
         # neither scope reads as a kernel name (cello_*)
         assert not any(sc.startswith("cello_") for sc in DEVICE_SCOPES)
+        # the Laplacian runs on the diagonal layout: its build, no gather
+        lap = Session(cache_dir=tmp_path).trace(
+            workload="cg_sparse", n=256, iters=2).analyze().codesign() \
+            .lower(backend="pallas")
+        assert set(lap.device_scopes().values()) == {LAYOUT_SCOPE}
         assert plan.device_scopes(backend="reference") == {}
         dense = Session(cache_dir=tmp_path).trace(
             workload="cg", n=32, iters=2).analyze().codesign() \

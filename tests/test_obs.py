@@ -447,7 +447,33 @@ class TestFacade:
                        "codesign.search_s", "codesign.points",
                        "codesign.cache.hits", "codesign.cache.misses",
                        "exec.compile_s", "exec.dispatch_s",
+                       "exec.spmv_layout",
                        "serve.requests", "serve.e2e_latency_s"):
             assert needed in names
         with pytest.raises(TypeError):
             reg.histogram("session.stage_runs")   # defined as a counter
+
+    @pytest.mark.parametrize("pattern,kw,layout", [
+        ("laplacian5", {}, "dia"), ("random", {"density": 0.2}, "csr")])
+    def test_spmv_layout_counts_every_spmv_of_each_dispatch(
+            self, pattern, kw, layout, tmp_path):
+        """``exec.spmv_layout`` goes up once per spmv each dispatch runs
+        (a rolled loop's body once per iteration), under the layout its
+        operand streams in."""
+        from repro.api import Session
+        from repro.exec import get_backend
+        from repro.frontends import make_feeds
+        traced = Session(cache_dir=tmp_path).trace(
+            workload="cg_sparse", n=64, iters=4, pattern=pattern, **kw)
+        plan = traced.analyze().codesign().lower()
+        spmvs = sum(nd.op == "spmv" for nd in traced.program.nodes.values())
+        prog = get_backend("pallas").compile(plan)
+        counter = obs.registry().counter("exec.spmv_layout")
+        feeds = make_feeds(traced.program, seed=0)
+        for runs in (1, 2):
+            prog(feeds)
+            assert counter.value(backend="pallas", layout=layout,
+                                 scope=prog._scope) == runs * spmvs
+        other = "csr" if layout == "dia" else "dia"
+        assert counter.value(backend="pallas", layout=other,
+                             scope=prog._scope) == 0

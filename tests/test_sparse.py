@@ -203,6 +203,24 @@ class TestGenerators:
             ([0], np.cumsum(row_counts("laplacian5", n)))).astype(np.int32)
         np.testing.assert_array_equal(comp["indptr"], want_ptr)
 
+    @pytest.mark.parametrize("pattern,kw,n", [
+        ("laplacian5", {}, 49), ("laplacian5", {}, 1),
+        ("banded", {"bandwidth": 3}, 30), ("random", {"density": 0.2}, 30),
+        ("skewed", {"density": 0.2}, 30)])
+    def test_pattern_offsets_cover_every_entry(self, pattern, kw, n):
+        from repro.frontends.sparse import pattern_offsets
+        p = Program(f"offs_{pattern}")
+        A = p.sparse_operator("A", (n, n), pattern=pattern, **kw)
+        p.output(p.spmv(A, p.input("x", (n,))))
+        feeds = make_feeds(p, seed=3)
+        offs = pattern_offsets(pattern, n, kw.get("bandwidth"))
+        if pattern in ("random", "skewed"):
+            assert offs is None
+            return
+        assert list(offs) == sorted(set(offs))
+        rows = np.repeat(np.arange(n), np.diff(feeds["A.indptr"]))
+        assert set(feeds["A.indices"] - rows) == set(offs)
+
     def test_dinv_matches_diagonal(self):
         n = 36
         prog = build_workload("jacobi_sparse", n=n, sweeps=1)
@@ -327,6 +345,149 @@ class TestSparseParity:
         # the reference backend has no layout and answers as before
         assert np.all(np.isfinite(np.asarray(
             plan.run(feeds, backend="reference")["x2"])))
+
+
+# ---------------------------------------------------------------------------
+# the diagonal layout: laplacian5 / banded spmv without a gather
+# ---------------------------------------------------------------------------
+
+def _spmv_program(pattern, n, **kw):
+    p = Program(f"dia_{pattern}_{n}")
+    A = p.sparse_operator("A", (n, n), pattern=pattern, **kw)
+    p.output(p.spmv(A, p.input("x", (n,)), name="y"))
+    return p
+
+
+def _csr_product64(feeds, x):
+    """``A @ x`` in float64 straight from the CSR triple."""
+    ip = np.asarray(feeds["A.indptr"])
+    rows = np.repeat(np.arange(len(ip) - 1), np.diff(ip))
+    return np.bincount(rows, weights=np.asarray(feeds["A.data"], np.float64)
+                       * np.asarray(x, np.float64)[feeds["A.indices"]],
+                       minlength=len(ip) - 1)
+
+
+#: (pattern, n, pattern params, rows per tile of the spmv pass): one tile
+#: for all rows, tiles wider than the grid side g (halo of one tile),
+#: as wide as g, narrower than g (a halo of two tiles); banded of
+#: bandwidth 1 and 3
+DIA_CASES = [
+    ("laplacian5", 64, {}, 64),
+    ("laplacian5", 4096, {}, 1024),
+    ("laplacian5", 16384, {}, 128),
+    ("laplacian5", 65536, {}, 128),
+    ("banded", 50, {"bandwidth": 1}, 50),
+    ("banded", 1024, {"bandwidth": 3}, 128),
+]
+
+
+class TestDiagonalLayout:
+    @pytest.mark.parametrize("via", ["pass", "batched"])
+    @pytest.mark.parametrize("pattern,n,kw,tile", DIA_CASES,
+                             ids=[f"{c[0]}-{c[1]}-t{c[3]}"
+                                  for c in DIA_CASES])
+    def test_diagonal_spmv_equals_float64_csr_product(
+            self, pattern, n, kw, tile, via, tmp_path):
+        import dataclasses
+
+        import jax.numpy as jnp
+
+        from repro.exec.pallas import _StreamCall
+        p = _spmv_program(pattern, n, **kw)
+        feeds = make_feeds(p, seed=4)
+        if via == "pass":
+            (gk,) = select_group_kernels(p.to_graph(), [["y"]], 16 << 20)
+            (sp,) = gk.passes
+            assert sp.dia == ("y",)
+            call = _StreamCall(p, dataclasses.replace(sp, tile_rows=tile),
+                               {"y"})
+            env = {k: jnp.asarray(v) for k, v in feeds.items()}
+            for name, build in call.derived.items():
+                env[name] = build(env, jnp.float32)
+            got = [call.apply(env, jnp.float32)["y"]]
+            xs = [feeds["x"]]
+        else:                  # one shared operand, vmapped right sides
+            plan = Session.from_graph(p, cache_dir=tmp_path).analyze() \
+                .codesign().lower()
+            assert all(u.sp.dia == u.sp.spmv for u in plan.exec_plan.units
+                       if u.sp is not None)
+            bp = plan.batched(backend="pallas")
+            xs = [feeds["x"], feeds["x"][::-1].copy()]
+            got = [o["y"] for o in bp.run_many(
+                [{"x": x} for x in xs],
+                {k: feeds[k] for k in bp.shared_leaves})]
+        for x, y in zip(xs, got):
+            np.testing.assert_allclose(np.asarray(y, np.float64),
+                                       _csr_product64(feeds, x),
+                                       rtol=RTOL32, atol=ATOL32)
+
+    def test_cg_sparse_solve_on_the_diagonal_layout(self, tmp_path):
+        traced, plan = _lowered(tmp_path, "cg_sparse", n=4096, iters=4)
+        spmv = [o for u in plan.exec_plan.units if u.sp for o in u.sp.spmv]
+        assert spmv and all(o in u.sp.dia for u in plan.exec_plan.units
+                            if u.sp for o in u.sp.spmv)
+        feeds = make_feeds(traced.program, seed=2)
+        want = evaluate(traced.program, feeds)
+        got = plan.run(feeds, backend="pallas")
+        for k in want:
+            np.testing.assert_allclose(np.asarray(got[k]),
+                                       np.asarray(want[k]),
+                                       rtol=RTOL32, atol=ATOL32, err_msg=k)
+
+    @pytest.mark.parametrize("workload,params,layout", [
+        ("cg_sparse", dict(n=64, iters=3), "dia"),
+        ("cg_sparse", dict(n=50, iters=2, pattern="banded", bandwidth=3),
+         "dia"),
+        ("bicgstab_sparse", dict(n=48, iters=2, pattern="random",
+                                 density=0.1), "csr"),
+        ("jacobi_sparse", dict(n=40, sweeps=2, pattern="skewed",
+                               density=0.15), "csr"),
+    ], ids=["laplacian5", "banded", "random", "skewed"])
+    def test_each_pattern_lowers_to_its_layout(self, workload, params,
+                                               layout, tmp_path):
+        from repro import obs
+        from repro.exec import get_backend
+        traced, plan = _lowered(tmp_path, workload, **params)
+        passes = [u.sp for u in plan.exec_plan.units
+                  if u.sp is not None and u.sp.spmv]
+        assert passes
+        for sp in passes:
+            assert sp.dia == (sp.spmv if layout == "dia" else ())
+        text = plan.explain()
+        assert f":{layout}" in text
+        assert (":csr" if layout == "dia" else ":dia") not in text
+        prog = get_backend("pallas").compile(plan)
+        assert set(prog.spmv_layouts) == {layout}
+        prog(make_feeds(traced.program, seed=1))
+        counter = obs.registry().counter("exec.spmv_layout")
+        assert counter.value(backend="pallas", layout=layout,
+                             scope=prog._scope) \
+            == prog.spmv_layouts[layout] > 0
+
+    def test_an_entry_off_the_diagonals_reads_nan_never_a_short_row(
+            self, tmp_path):
+        """A Laplacian feed with one entry moved off the operand's
+        diagonals still fits the per-tile windows, so the feed check
+        passes it; the spmv's answer in that row is NaN, and every other
+        row is the product."""
+        n, row = 4096, 2 * 64 + 5               # an interior grid row
+        p = _spmv_program("laplacian5", n)
+        plan = Session.from_graph(p, cache_dir=tmp_path).analyze() \
+            .codesign().lower()
+        feeds = make_feeds(p, seed=6)
+        ip = feeds["A.indptr"]
+        k = ip[row] + 3                         # the row's (row, row+1)
+        assert feeds["A.indices"][k] == row + 1
+        feeds["A.indices"][k] = row + 2         # off every diagonal
+        y = np.asarray(plan.run(feeds, backend="pallas")["y"])
+        assert np.isnan(y[row])
+        rest = np.arange(n) != row
+        np.testing.assert_allclose(y[rest], _csr_product64(feeds,
+                                                           feeds["x"])[rest],
+                                   rtol=RTOL32, atol=ATOL32)
+        # the reference applies the CSR as given
+        assert np.isfinite(np.asarray(
+            plan.run(feeds, backend="reference")["y"])).all()
 
 
 # ---------------------------------------------------------------------------

@@ -84,10 +84,53 @@ def _assert_every_kernel_compiled(prog, text):
     assert want <= calls, sorted(want - calls)
 
 
+def _while_bodies(text):
+    """The HLO text of every computation a ``while`` loop of a compiled
+    module runs: its bodies and whatever they call, transitively."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{\s*$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    calls = re.compile(r"(?:body|calls|to_apply|condition|branch_computations"
+                       r"|called_computations)=\{?([%\w.\-, ]+)")
+
+    def callees(comp):
+        return {c.strip().lstrip("%") for line in comps.get(comp, ())
+                for m in calls.finditer(line) for c in m.group(1).split(",")}
+
+    todo = [m.group(1) for line in text.splitlines()
+            for m in re.finditer(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo.extend(callees(c))
+    return "\n".join(line for c in seen for line in comps.get(c, ()))
+
+
+#: the dense n=8192 plan's passes, as (tile rows, planned VMEM bytes,
+#: resident operands, spmv) -> count: the sparse layouts leave them alone
+DENSE_8192_PASSES = {(256, 17334272, 1, False): 1,
+                     (256, 17350656, 1, False): 1,
+                     (256, 17383424, 1, False): 31,
+                     (1024, 196608, 0, False): 63,
+                     (1024, 393216, 0, False): 1}
+
+
 def test_dense_cg_n8192_compiles(topo, mosaic):
+    from collections import Counter
+
     from jax.sharding import SingleDeviceSharding
     traced, plan = _plan("cg", n=8192, iters=32)
     assert all(u.kind == "stream" for u in plan.exec_plan.units)
+    assert Counter((u.sp.tile_rows, u.sp.vmem_bytes, len(u.sp.resident),
+                    bool(u.sp.spmv))
+                   for u in plan.exec_plan.units) == DENSE_8192_PASSES
     prog = get_backend("pallas").compile(plan)
     args = _shapes(traced.program, prog.leaf_names,
                    SingleDeviceSharding(topo.devices[0]))
@@ -98,18 +141,26 @@ def test_dense_cg_n8192_compiles(topo, mosaic):
 def test_sparse_cg_n1m_compiles(topo, mosaic):
     from jax.sharding import SingleDeviceSharding
     traced, plan = _plan("cg_sparse", n=1 << 20, iters=32)
-    # every unit streams, the spmv ones on the padded per-tile layout
+    # every unit streams, the spmv ones on the diagonal layout (the
+    # Laplacian's rows all draw from the offsets -1024, -1, 0, 1, 1024)
     assert all(u.kind == "stream" for u in plan.exec_plan.units)
     spmv_ops = {nd.name for nd in traced.program.nodes.values()
                 if nd.op == "spmv"}
     assert spmv_ops
     assert spmv_ops <= {o for u in plan.exec_plan.units
-                        for o in u.sp.spmv}
+                        for o in u.sp.dia}
     prog = get_backend("pallas").compile(plan)
+    assert prog.spmv_layouts == {"dia": 33}
     args = _shapes(traced.program, prog.leaf_names,
                    SingleDeviceSharding(topo.devices[0]))
     compiled = prog._jit.lower(*args).compile()
-    _assert_every_kernel_compiled(prog, compiled.as_text())
+    text = compiled.as_text()
+    _assert_every_kernel_compiled(prog, text)
+    assert "cello_dia_A" in text                 # the layout's own pass
+    # nothing in the CG loop gathers x by column index
+    body = _while_bodies(text)
+    assert "cello_stream_Ap" in body
+    assert not re.search(r"\sgather\(", body)
 
 
 def test_batched_core_b8_compiles(topo, mosaic):
@@ -122,6 +173,23 @@ def test_batched_core_b8_compiles(topo, mosaic):
     compiled = bp._build().lower(shared, batched).compile()
     prog = get_backend("pallas").compile(plan)      # same units, unbatched
     _assert_every_kernel_compiled(prog, compiled.as_text())
+
+
+def test_batched_sparse_core_b8_compiles(topo, mosaic):
+    """A batch of right-hand sides against one Laplacian: the diagonal
+    layout is built once, unbatched, and the vmapped spmv kernels share
+    it."""
+    from jax.sharding import SingleDeviceSharding
+    traced, plan = _plan("cg_sparse", n=65536, iters=8)
+    bp = plan.batched()
+    one = SingleDeviceSharding(topo.devices[0])
+    shared = _shapes(traced.program, bp.shared_leaves, one)
+    batched = _shapes(traced.program, bp.batched_leaves, one, batch=8)
+    text = bp._build().lower(shared, batched).compile().as_text()
+    prog = get_backend("pallas").compile(plan)
+    assert prog.spmv_layouts == {"dia": 9}
+    _assert_every_kernel_compiled(prog, text)
+    assert "%cello_dia_A" in text and "vmap_cello_dia" not in text
 
 
 def test_sharded_cg_4_devices_compiles(topo, mosaic, monkeypatch):
