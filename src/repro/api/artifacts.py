@@ -287,6 +287,31 @@ class CompiledPlan:
         from ..serve import BatchedPlan                  # lazy: pulls in jax
         return BatchedPlan(self, backend=backend, donate=donate_val)
 
+    def feed_shardings(self) -> Dict[str, Any]:
+        """``{leaf: jax.sharding.NamedSharding}``: how the executable of a
+        plan lowered with ``mesh=K`` (K > 1) lays out each leaf on the
+        mesh it runs on — row blocks over the mesh axis for row-sharded
+        leaves, replicated for the rest.  A feed larger than one device
+        is built in place on these (``docs/distributed.md``); a
+        row-sharded device feed laid out otherwise is refused at
+        dispatch.  Raises ``ValueError`` for a plan that runs on no
+        device mesh (no ``mesh=``, one shard, or the host-simulated mesh
+        of the ``reference`` backend)."""
+        if self.sharded is None or self.sharded.n_shards < 2:
+            raise ValueError("feed_shardings() needs a plan lowered with "
+                             "mesh=K, K > 1; this plan runs on one device")
+        if self.trace is None or self.trace.program is None:
+            raise ValueError("feed_shardings() needs a frontend-traced "
+                             "plan")
+        from ..exec import get_backend                   # lazy: pulls in jax
+        fn = get_backend(self.backend).compiled(self)
+        shardings = getattr(fn, "feed_shardings", None)
+        if shardings is None:
+            raise ValueError(f"backend {self.backend!r} runs this plan on no "
+                             f"device mesh (it simulates the mesh on the "
+                             f"host)")
+        return shardings()
+
     # -- introspection --------------------------------------------------
     def device_scopes(self, dtype: str = "float32", *,
                       backend: Optional[str] = None) -> Dict[str, str]:

@@ -304,7 +304,11 @@ def decode_graph(cfg: ArchConfig, batch: int, kv_len: int) -> OpGraph:
 # Tiles are planned for Mosaic (the TPU kernel compiler): rank-1 vectors
 # enter kernels as ``(1, n)`` rows, so a row tile is a multiple of 128
 # lanes unless one tile covers the whole pass, and every kernel's
-# double-buffered working set fits one kernel's VMEM budget.
+# double-buffered working set fits one kernel's VMEM budget.  A pass whose
+# whole rows fit no tile (a dense matvec at n >= 32768: 128 rows of 65536
+# columns are 32 MiB) is *column-blocked*: its grid also walks column
+# tiles of the matrix and of the contraction's right-hand side, and the
+# row tile's product accumulates across them (``StreamPass.tile_cols``).
 
 #: einsum specs the tile-streamer lowers: LHS streams row tiles, RHS stays
 #: resident (spec -> index of the resident operand)
@@ -314,6 +318,11 @@ REDUCE_EINSUMS = ("a,a->",)
 
 #: lane-aligned row tiles, largest first
 _TILE_ROW_CANDIDATES = (1024, 512, 256, 128)
+#: widest column tile of a column-blocked pass: a ``(256, 8192)`` float32
+#: block (8 MiB) is the whole-row block of the n=8192 matvec, whose rows
+#: DMA as 32 KiB segments; narrower tiles are taken only where this one
+#: fits no row tile
+WIDE_TILE_COLS = 8192
 _LANE, _SUBLANE = 128, 8
 #: element width kernels are planned for: TPU kernels run fp32 (an fp64
 #: program is refused before it reaches Mosaic — see ``repro.exec.pallas``)
@@ -378,11 +387,21 @@ class StreamPass:
     rows: int                       # streamed leading-dim length
     tile_rows: int                  # rows per grid step (divides ``rows``)
     resident: Tuple[str, ...]       # operands held in VMEM across all tiles
+    #                                 (column-blocked: streamed per column
+    #                                 tile instead)
     reductions: Tuple[str, ...]     # rank-0 accumulators in this pass
     vmem_bytes: int = 0             # planned double-buffered working set
     spmv: Tuple[str, ...] = ()      # CSR spmv ops
     dia: Tuple[str, ...] = ()       # those on the diagonal layout (the
     #                                 rest: the per-tile entry layout)
+    tile_cols: Optional[int] = None  # matrix columns per grid step of a
+    #                                 column-blocked pass (None: whole rows)
+
+    def tiling(self) -> str:
+        """``rows/tile`` as ``explain()`` prints it, ``/<cols>c`` added
+        for a column-blocked pass."""
+        cols = f"/{self.tile_cols}c" if self.tile_cols else ""
+        return f"{self.rows}r/{self.tile_rows}t{cols}"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -403,7 +422,7 @@ class GroupKernel:
                 sp = (" spmv=" + "+".join(
                     f"{o}:{'dia' if o in p.dia else 'csr'}"
                     for o in p.spmv)) if p.spmv else ""
-                bits.append(f"{p.rows}r/{p.tile_rows}t{res}{red}{sp}")
+                bits.append(f"{p.tiling()}{res}{red}{sp}")
             tag = " | ".join(bits)
             n = len(self.passes)
             label = ("pallas-spmv" if any(p.spmv for p in self.passes)
@@ -748,16 +767,72 @@ def _classify_pass(graph: OpGraph, seg, explicit_bytes: int):
     budget = _kernel_budget(explicit_bytes)
     tile = _pick_tile_rows(rows, per_row, res_bytes, budget,
                            spmv_bytes if spmvs else None)
-    if tile is None:
-        return (f"no {rows}-row tiling fits {budget >> 20} MiB of VMEM "
-                f"({res_bytes >> 20} MiB resident)")
+    tile_cols = None
+    if tile is not None:
+        vmem = (2 * (tile * per_row + res_bytes)
+                + (spmv_bytes(tile) if spmvs else 0))
+    else:
+        block = _column_block(graph, ops, streamed_seen, resident, rows,
+                              budget)
+        if block is None:
+            return (f"no {rows}-row tiling fits {budget >> 20} MiB of VMEM "
+                    f"({res_bytes >> 20} MiB resident)")
+        tile, tile_cols, vmem = block
     return StreamPass(ops=tuple(seg), rows=rows, tile_rows=tile,
                       resident=tuple(resident), reductions=tuple(reductions),
-                      vmem_bytes=(2 * (tile * per_row + res_bytes)
-                                  + (spmv_bytes(tile) if spmvs else 0)),
+                      vmem_bytes=vmem,
                       spmv=tuple(op.name for op in spmvs),
                       dia=tuple(op.name for op, offs in zip(spmvs, offsets)
-                                if offs is not None))
+                                if offs is not None),
+                      tile_cols=tile_cols)
+
+
+def _column_block(graph: OpGraph, ops, streamed, resident, rows: int,
+                  budget: int) -> Optional[Tuple[int, int, int]]:
+    """``(tile_rows, tile_cols, vmem_bytes)`` of a column-blocked pass, or
+    ``None`` where the pass cannot be blocked: it must hold an ``ab,b->a``
+    contraction, no other contraction and no spmv, and every matrix it
+    streams must be such a contraction's left operand, all of one width.
+
+    Each grid step reads a ``(tile_rows, tile_cols)`` block of every
+    matrix and the matching ``(1, tile_cols)`` block of every right-hand
+    side, and adds the row tile's partial product into a ``(1,
+    tile_rows)`` VMEM accumulator; the pass's vectors move with the row
+    tile only.  The column tile is the widest lane-aligned divisor of the
+    width up to :data:`WIDE_TILE_COLS` with which some row tile fits
+    ``budget``, and the row tile the largest that then fits, so the
+    working set no longer grows with the width."""
+    if any(op.spec == "spmv" or (op.is_einsum and op.spec != "ab,b->a"
+                                 and op.spec not in REDUCE_EINSUMS)
+           for op in ops):
+        return None
+    matvecs = [op for op in ops if op.is_einsum and op.spec == "ab,b->a"]
+    mats = {op.inputs[0] for op in matvecs}
+    if not matvecs or any(len(graph.tensors[t].shape) > 1
+                          for t in streamed if t not in mats):
+        return None
+    widths = {graph.tensors[t].shape[1] for t in mats}
+    if len(widths) != 1:
+        return None
+    (cols,) = widths
+    vec_row = sum(_row_bytes(graph.tensors[t].shape)
+                  for t in streamed if t not in mats)
+
+    def accumulators(tr: int) -> int:
+        return len(matvecs) * kernel_block_bytes((tr,))
+
+    widest = min(cols, WIDE_TILE_COLS)
+    tiles = {t for t in (_LANE << k for k in range(widest.bit_length()))
+             if t <= widest and cols % t == 0}
+    if cols <= WIDE_TILE_COLS:
+        tiles.add(cols)
+    for tc in sorted(tiles, reverse=True):
+        per_row = vec_row + len(mats) * tc * KERNEL_ITEMSIZE
+        rhs = len(resident) * kernel_block_bytes((tc,))
+        tr = _pick_tile_rows(rows, per_row, rhs, budget, accumulators)
+        if tr is not None:
+            return tr, tc, 2 * (tr * per_row + rhs) + accumulators(tr)
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -795,7 +870,7 @@ class ExecUnit:
     def describe(self) -> str:
         extra = ""
         if self.sp is not None:
-            extra = f" {self.sp.rows}r/{self.sp.tile_rows}t"
+            extra = f" {self.sp.tiling()}"
             if self.sp.resident:
                 extra += f" res={'+'.join(self.sp.resident)}"
         if self.fused > 1:
@@ -928,7 +1003,8 @@ def _unit_matches(program, sigma: Dict[str, str], ua: ExecUnit,
     if (ua.sp is None) != (ub.sp is None):
         return False
     if ua.sp is not None and (ua.sp.rows != ub.sp.rows
-                              or ua.sp.tile_rows != ub.sp.tile_rows):
+                              or ua.sp.tile_rows != ub.sp.tile_rows
+                              or ua.sp.tile_cols != ub.sp.tile_cols):
         return False
     for o, o2 in zip(ua.ops, ub.ops):
         if sigma.get(o) != o2:
@@ -1208,6 +1284,8 @@ def _localize_tile(tile_rows: int, rows_loc: int) -> int:
 
 
 def _localize_pass(sp: StreamPass, n_shards: int) -> StreamPass:
+    """The pass on one shard's row block; a column-blocked pass keeps its
+    column tile, since a shard holds whole rows of the global width."""
     rows_loc = sp.rows // n_shards
     return dataclasses.replace(
         sp, rows=rows_loc, tile_rows=_localize_tile(sp.tile_rows, rows_loc))

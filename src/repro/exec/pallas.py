@@ -20,6 +20,12 @@ kernels, no per-call ``result_type``/``asarray`` conversion.  The pieces:
   e.g. ``nalpha = -alpha``) are computed before the kernel and read from
   SMEM, so tiled ops use them without a pass break; reduction-derived
   scalar epilogues (``beta = rs'/rs``, a norm's ``sqrt``) run after it.
+  A *column-blocked* pass (``StreamPass.tile_cols``: whole rows of its
+  matrix fit no tile) runs on a ``(row tiles, column tiles)`` grid: each
+  step streams a ``(tile, tile_cols)`` matrix block and the right-hand
+  side's ``(1, tile_cols)`` block, the row tile's product accumulates in
+  VMEM scratch, and the rest of the pass runs at the last column step.
+  Its kernel is named ``cello_wide_<first op>``.
 * CSR SpMV ops run inside stream passes on one of two layouts, both
   derived once per dispatch from the CSR leaves, outside any rolled loop.
   Where the operand's pattern meta fixes every row's column offsets to a
@@ -111,6 +117,10 @@ _SPMV_LAYOUT = obs.registry().counter(
     "exec.spmv_layout", "spmv passes each dispatch runs, by the layout its "
     "operand streams in (layout: dia | csr), per compiled program (scope "
     "label)")
+_MATVEC_TILING = obs.registry().counter(
+    "exec.matvec_tiling", "dense contraction passes each dispatch runs, by "
+    "how their matrix is tiled (tiling: rows | blocked), per compiled "
+    "program (scope label)")
 
 _BACKEND_PROBE: Optional[str] = None
 
@@ -363,6 +373,14 @@ class _StreamCall:
         self.needed = needed
         self._built: Dict[Any, Callable] = {}
 
+    @property
+    def kernel_name(self) -> str:
+        """``cello_wide_<first op>`` for a column-blocked pass (its device
+        ops are told apart from whole-row ones by the name),
+        ``cello_stream_<first op>`` otherwise."""
+        kind = "wide" if self.sp.tile_cols else "stream"
+        return f"cello_{kind}_{self.sp.ops[0]}"
+
     def _build(self, dtype):
         import jax
         import jax.numpy as jnp
@@ -370,13 +388,32 @@ class _StreamCall:
         from jax.experimental import pallas as pl
         from jax.experimental.pallas import tpu as pltpu
 
-        tr = self.sp.tile_rows
+        tr, tc = self.sp.tile_rows, self.sp.tile_cols
         n_tiles = self.sp.rows // tr
         nodes, classes = self.nodes, self.classes
         ins = self.stream_in + self.tile_in + self.res_in + self.scalar_in
         n_in = len(ins)
+        outs = self.red_out + self.stream_out
         stream_out_set = set(self.stream_out)
         hi = lax.Precision.HIGHEST
+        # a column-blocked pass walks (row tile, column tile); its
+        # contractions accumulate each row tile's product in VMEM scratch
+        # and everything else runs once the last column tile is in
+        matvecs = [nd for nd in nodes if classes[nd.name] == "tiled"
+                   and nd.op in ("matmul", "einsum")] if tc else []
+        n_cols = (self.shapes[self.res_in[0]][0] // tc) if tc else 1
+
+        def contract(nd, val, rref):
+            spec = nd.param("spec")
+            rhs = STREAM_EINSUMS[spec]
+            lhs = val(nd.inputs[1 - rhs])
+            right = rref[nd.inputs[rhs]][...]
+            if spec == "ab,b->a":       # row x (1, m) . A_tile^T
+                return lax.dot_general(
+                    right, lhs, (((1,), (1,)), ((), ())),
+                    precision=hi, preferred_element_type=dtype)
+            return jnp.dot(lhs, right, precision=hi,
+                           preferred_element_type=dtype)
 
         def kernel(*refs):
             i = pl.program_id(0)
@@ -387,7 +424,9 @@ class _StreamCall:
                 {n: next(it) for n in names}
                 for names in (self.stream_in, self.tile_in, self.res_in,
                               self.scalar_in))
-            oref = dict(zip(self.red_out + self.stream_out, refs[n_in:]))
+            oref = dict(zip(outs, refs[n_in:n_in + len(outs)]))
+            acc = dict(zip((nd.name for nd in matvecs),
+                           refs[n_in + len(outs):]))
             tiles: Dict[str, Any] = {}
 
             def val(name):                 # streamed tile or SMEM scalar
@@ -397,52 +436,71 @@ class _StreamCall:
                     tiles[name] = sref[name][...]
                 return tiles[name]
 
-            for nd in nodes:
-                cls = classes[nd.name]
-                if cls == "tiled":
-                    if nd.op == "spmv" and self.spmv[nd.name].layout \
-                            == "dia":           # sum of shifted x, VPU
-                        v = _dia_rows(nd.name, self.spmv[nd.name], tref,
-                                      tr)
-                    elif nd.op == "spmv":       # one-hot row sum, MXU
-                        vals = tref[f"{nd.name}@vals"][...]     # (1, B)
-                        first = tref[f"{nd.name}@first"][...]   # (1, tr)
-                        stop = tref[f"{nd.name}@stop"][...]
-                        slot = lax.broadcasted_iota(
-                            jnp.int32, (vals.shape[-1], tr), 0)
-                        onehot = (slot >= first) & (slot < stop)
-                        v = jnp.dot(vals, onehot.astype(dtype),
-                                    precision=hi,
-                                    preferred_element_type=dtype)
-                    elif nd.op in ("matmul", "einsum"):
-                        spec = nd.param("spec")
-                        rhs = STREAM_EINSUMS[spec]
-                        lhs = val(nd.inputs[1 - rhs])
-                        right = rref[nd.inputs[rhs]][...]
-                        if spec == "ab,b->a":   # row x (1, m) . A_tile^T
-                            v = lax.dot_general(
-                                right, lhs, (((1,), (1,)), ((), ())),
-                                precision=hi, preferred_element_type=dtype)
-                        else:
-                            v = jnp.dot(lhs, right, precision=hi,
+            def row_tile():
+                for nd in nodes:
+                    cls = classes[nd.name]
+                    if cls == "tiled":
+                        if nd.name in acc:      # the accumulated product
+                            v = acc[nd.name][...]
+                        elif nd.op == "spmv" and self.spmv[nd.name].layout \
+                                == "dia":       # sum of shifted x, VPU
+                            v = _dia_rows(nd.name, self.spmv[nd.name], tref,
+                                          tr)
+                        elif nd.op == "spmv":   # one-hot row sum, MXU
+                            vals = tref[f"{nd.name}@vals"][...]   # (1, B)
+                            first = tref[f"{nd.name}@first"][...]
+                            stop = tref[f"{nd.name}@stop"][...]
+                            slot = lax.broadcasted_iota(
+                                jnp.int32, (vals.shape[-1], tr), 0)
+                            onehot = (slot >= first) & (slot < stop)
+                            v = jnp.dot(vals, onehot.astype(dtype),
+                                        precision=hi,
                                         preferred_element_type=dtype)
-                    else:
-                        v = eval_node(nd, [val(t) for t in nd.inputs])
-                    tiles[nd.name] = v
-                    if nd.name in stream_out_set:
-                        oref[nd.name][...] = v.astype(dtype)
-                elif cls == "reduce":
-                    a = val(nd.inputs[0])
-                    b = a if nd.op == "norm" else val(nd.inputs[1])
-                    _accumulate(oref[nd.name], jnp.sum(a * b), i)
+                        elif nd.op in ("matmul", "einsum"):
+                            v = contract(nd, val, rref)
+                        else:
+                            v = eval_node(nd, [val(t) for t in nd.inputs])
+                        tiles[nd.name] = v
+                        if nd.name in stream_out_set:
+                            oref[nd.name][...] = v.astype(dtype)
+                    elif cls == "reduce":
+                        a = val(nd.inputs[0])
+                        b = a if nd.op == "norm" else val(nd.inputs[1])
+                        _accumulate(oref[nd.name], jnp.sum(a * b), i)
+
+            if not tc:
+                row_tile()
+                return
+            j = pl.program_id(1)
+
+            @pl.when(j == 0)
+            def _():
+                for ref in acc.values():
+                    ref[...] = jnp.zeros_like(ref)
+
+            for nd in matvecs:
+                acc[nd.name][...] += contract(nd, val, rref)
+            tiles.clear()       # the row tile's values load at its end
+
+            @pl.when(j == n_cols - 1)
+            def _():
+                row_tile()
+
+        def rows_map(f):
+            """An index map of a block that moves with the row tile."""
+            return (lambda i, j: f(i)) if tc else f
 
         def stream_spec(shape):
             if len(shape) == 1:
-                return pl.BlockSpec((1, tr), lambda i: (0, i))
+                return pl.BlockSpec((1, tr), rows_map(lambda i: (0, i)))
+            if tc:                              # a (tr, tc) matrix block
+                return pl.BlockSpec((tr, tc), lambda i, j: (i, j))
             return pl.BlockSpec((tr,) + shape[1:],
                                 lambda i: (i,) + (0,) * (len(shape) - 1))
 
         def full_spec(shape):
+            if tc:                  # the right-hand side's column tile
+                return pl.BlockSpec((1, tc), lambda i, j: (0, j))
             shape = _row_shape(shape)
             return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
@@ -459,7 +517,7 @@ class _StreamCall:
             return pl.BlockSpec((None, 1, self.shapes[name][-1]),
                                 lambda i: (i, 0, 0))
 
-        smem = pl.BlockSpec((1, 1), lambda i: (0, 0),
+        smem = pl.BlockSpec((1, 1), rows_map(lambda i: (0, 0)),
                             memory_space=pltpu.SMEM)
         in_specs = ([stream_spec(self.shapes[n]) for n in self.stream_in]
                     + [tile_spec(n) for n in self.tile_in]
@@ -473,11 +531,13 @@ class _StreamCall:
                      + [jax.ShapeDtypeStruct(_row_shape(self.shapes[n]),
                                              dtype)
                         for n in self.stream_out])
+        grid = (n_tiles, n_cols) if tc else (n_tiles,)
         return pl.pallas_call(
-            kernel, grid=(n_tiles,), in_specs=in_specs,
+            kernel, grid=grid, in_specs=in_specs,
             out_specs=out_specs, out_shape=out_shape,
-            **_pallas_call_kwargs(f"cello_stream_{self.sp.ops[0]}", dtype,
-                                  self.sp.vmem_bytes, 1))
+            scratch_shapes=[pltpu.VMEM((1, tr), dtype) for _ in matvecs],
+            **_pallas_call_kwargs(self.kernel_name, dtype,
+                                  self.sp.vmem_bytes, len(grid)))
 
     # -- drivers --------------------------------------------------------
     def apply(self, env: Dict[str, Any], dtype) -> Dict[str, Any]:
@@ -527,6 +587,15 @@ class _StreamCall:
             keep = (self.needed | set(self.red_out)
                     | {nd.name for nd in self.eager})
         return {n: v for n, v in vals.items() if n in keep}
+
+    @property
+    def matvec_tiling(self) -> Optional[str]:
+        """``blocked`` (column-blocked) or ``rows`` (whole-row tiles) for a
+        pass holding a dense contraction, ``None`` for any other pass."""
+        if not any(nd.op in ("matmul", "einsum")
+                   and self.classes[nd.name] == "tiled" for nd in self.nodes):
+            return None
+        return "blocked" if self.sp.tile_cols else "rows"
 
     @property
     def finalize_nodes(self):
@@ -950,15 +1019,12 @@ class _SingleProgram:
         for i in (*pro, *tmpl, *epi):
             _UNITS.inc(backend="pallas", kind=units[i].kind,
                        scope=self._scope)
-        # spmv passes per dispatch, by layout: a rolled body runs n_iters
-        # times
-        self.spmv_layouts: Dict[str, int] = {}
-        for calls, times in ((self._pro, 1), (self._epi, 1),
-                             (self._tmpl, roll.n_iters if roll else 0)):
-            for call in calls:
-                for s in getattr(call, "spmv", {}).values():
-                    self.spmv_layouts[s.layout] = \
-                        self.spmv_layouts.get(s.layout, 0) + times
+        # spmv and dense contraction passes per dispatch, by layout and
+        # tiling: a rolled body runs n_iters times
+        self.spmv_layouts = pass_counts(
+            self, lambda c: [s.layout for s in getattr(c, "spmv",
+                                                       {}).values()])
+        self.matvec_tilings = pass_counts(self, _tiling_of)
 
         if roll is not None:
             tmpl_ops = {o for i in tmpl for o in units[i].ops}
@@ -1078,6 +1144,7 @@ class _SingleProgram:
         for layout, n in self.spmv_layouts.items():
             _SPMV_LAYOUT.inc(n, backend="pallas", layout=layout,
                              scope=self._scope)
+        count_matvec_tilings(self)
         with obs.span("exec.launch"):
             outs = self._jit(*args)
         return dict(zip(self.out_names, outs))
@@ -1107,6 +1174,34 @@ class _SingleProgram:
         diagonal layout has no gather)."""
         text = self._jit.lower(*self.leaf_shapes(dtype)).compile().as_text()
         return hlo_scopes(text)
+
+
+def _tiling_of(call) -> List[str]:
+    tiling = getattr(call, "matvec_tiling", None)
+    return [] if tiling is None else [tiling]
+
+
+def pass_counts(prog, labels: Callable[[Any], List[str]]) -> Dict[str, int]:
+    """``{label: passes}`` one dispatch of ``prog`` (a single-device or
+    sharded program) runs, from the labels ``labels(call)`` of each of its
+    calls: prologue and epilogue once, a rolled body once per
+    iteration."""
+    out: Dict[str, int] = {}
+    n_iters = prog.roll.n_iters if prog.roll is not None else 0
+    for calls, times in ((prog._pro, 1), (prog._epi, 1),
+                         (prog._tmpl, n_iters)):
+        for call in calls:
+            for label in labels(call):
+                out[label] = out.get(label, 0) + times
+    return out
+
+
+def count_matvec_tilings(prog) -> None:
+    """Add one dispatch's dense contraction passes to
+    ``exec.matvec_tiling``."""
+    for tiling, n in prog.matvec_tilings.items():
+        _MATVEC_TILING.inc(n, backend="pallas", tiling=tiling,
+                           scope=prog._scope)
 
 
 def _own_feeds(args: list) -> Tuple[list, int]:

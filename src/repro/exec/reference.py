@@ -32,17 +32,30 @@ def csr_row_ids(indptr, nnz: int):
 
 
 def eval_node(node, ins: List[Any]):
-    """Reference rule for one expression op (``ins`` in operand order)."""
+    """Reference rule for one expression op (``ins`` in operand order).
+
+    Contractions through XLA run at ``Precision.HIGHEST``: on a TPU the
+    default precision multiplies float32 operands in bfloat16 passes,
+    below the precision a float32 program states.  Other platforms
+    compute float32 contractions in full precision either way, so their
+    results do not change.  A ``matmul`` of two NumPy arrays stays on the
+    host's BLAS, as it always ran."""
     import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+    hi = lax.Precision.HIGHEST
     op = node.op
     if op == "matmul":
-        return ins[0] @ ins[1]
+        if isinstance(ins[0], np.ndarray) and isinstance(ins[1], np.ndarray):
+            return ins[0] @ ins[1]
+        return jnp.matmul(ins[0], ins[1], precision=hi)
     if op == "einsum":
-        return jnp.einsum(node.param("spec"), *ins)
+        return jnp.einsum(node.param("spec"), *ins, precision=hi)
     if op == "dot":
-        return jnp.dot(ins[0], ins[1])
+        return jnp.dot(ins[0], ins[1], precision=hi)
     if op == "norm":
-        return jnp.sqrt(jnp.dot(jnp.ravel(ins[0]), jnp.ravel(ins[0])))
+        v = jnp.ravel(ins[0])
+        return jnp.sqrt(jnp.dot(v, v, precision=hi))
     if op == "add":
         return ins[0] + ins[1]
     if op == "sub":

@@ -43,19 +43,40 @@ contracts FMAs), so sharded pallas results carry the same documented
 tolerance as single-device pallas vs reference
 (``docs/execution_backends.md``).  Feed donation is disabled for sharded
 programs (the replicated CSR operands outlive their first read).
+
+A ``ShardedProgram`` runs on one mesh (``launch.mesh.make_solver_mesh``,
+built once per shard count and shared) and says how each leaf is laid out
+on it (:meth:`ShardedProgram.feed_shardings`).  A row-sharded feed given
+as a device array must already be laid out so: the dispatch refuses any
+other layout rather than let ``jit`` reshard it, which for an operator
+larger than one chip would pass it through one device.  Host arrays are
+placed row block by row block.
 """
 from __future__ import annotations
 
 import dataclasses
 from types import SimpleNamespace
-from typing import Any, Dict, List, Set
+from typing import Any, Callable, Dict, List, Set
 
 from .. import obs
 from ..launch.mesh import make_solver_mesh
 from .base import plan_program
 from .pallas import (_DISPATCHES, _TRACES, _UNITS, _StreamCall,
-                     _unit_needed)
+                     _unit_needed, count_matvec_tilings, pass_counts,
+                     _tiling_of)
 from .reference import csr_row_ids, eval_node
+
+_COLLECTIVE_B = obs.registry().counter(
+    "exec.collective_bytes", "bytes each shard receives through the "
+    "collectives of one dispatch (op: all_gather | psum | ppermute; "
+    "all_gather and psum count the other shards' parts, ppermute the "
+    "block it delivers), counted from the traced program, per compiled "
+    "program (scope label)", unit="B")
+
+
+class FeedShardingError(ValueError):
+    """A row-sharded feed is a device array laid out otherwise than the
+    plan runs it."""
 
 
 # --------------------------------------------------------------------------
@@ -89,7 +110,8 @@ def _localize_csr(env: Dict[str, Any], lay, axis: str) -> None:
     env[lay.data] = lax.dynamic_slice(dv, (e0,), (pad,))
 
 
-def _stencil_shard(node, ins: List[Any], axis: str, n_shards: int):
+def _stencil_shard(node, ins: List[Any], axis: str, n_shards: int,
+                   note: Callable[[str, Any], None]):
     """The 5-point stencil rule on one row block: interior columns roll
     locally, the two boundary rows arrive from the mesh neighbours
     (circular, matching ``jnp.roll``'s wrap).  Term order matches
@@ -102,6 +124,8 @@ def _stencil_shard(node, ins: List[Any], axis: str, n_shards: int):
     bwd = [(j, (j - 1) % n_shards) for j in range(n_shards)]
     prev_last = lax.ppermute(u[-1:, :], axis, fwd)    # shard j-1's last row
     next_first = lax.ppermute(u[:1, :], axis, bwd)    # shard j+1's first row
+    note("ppermute", prev_last)
+    note("ppermute", next_first)
     down = jnp.concatenate([prev_last, u[:-1, :]], axis=0)   # roll(u, 1, 0)
     up = jnp.concatenate([u[1:, :], next_first], axis=0)     # roll(u, -1, 0)
     out = 0.25 * (down + up + jnp.roll(u, 1, 1) + jnp.roll(u, -1, 1))
@@ -304,7 +328,8 @@ class _InlineUnit:
     grid, which is exactly what sharding removes.)"""
 
     def __init__(self, view, ops, needed: Set[str], halo: Set[str],
-                 axis: str, n_shards: int):
+                 axis: str, n_shards: int,
+                 collectives: "_Collectives"):
         from .pallas import _group_io
 
         self.nodes = [view.nodes[o] for o in ops]
@@ -317,31 +342,67 @@ class _InlineUnit:
         self.halo = halo
         self.axis = axis
         self.n_shards = n_shards
+        self.coll = collectives
 
     def apply(self, env: Dict[str, Any], dtype=None) -> Dict[str, Any]:
         import jax.numpy as jnp
         from jax import lax
+        coll = self.coll
         vals = {n: env[n] for n in self.in_names}
         for nd in self.nodes:
             for t in nd.inputs:
                 if t not in vals:           # gathered in-unit product
-                    vals[t] = lax.all_gather(vals[t[:-2]], self.axis,
-                                             tiled=True)
+                    vals[t] = coll.all_gather(vals[t[:-2]])
             if nd.name in self.halo:
                 vals[nd.name] = _stencil_shard(
                     nd, [vals[t] for t in nd.inputs], self.axis,
-                    self.n_shards)
+                    self.n_shards, coll.note)
             elif nd.op == "norm":
                 x = jnp.ravel(vals[nd.inputs[0]])
-                vals[nd.name] = jnp.sqrt(lax.psum(jnp.dot(x, x), self.axis))
+                vals[nd.name] = jnp.sqrt(coll.psum(jnp.dot(
+                    x, x, precision=lax.Precision.HIGHEST)))
             elif nd.op == "dot" or (nd.op in ("matmul", "einsum")
                                     and nd.shape == ()):
-                vals[nd.name] = lax.psum(
-                    eval_node(nd, [vals[t] for t in nd.inputs]), self.axis)
+                vals[nd.name] = coll.psum(
+                    eval_node(nd, [vals[t] for t in nd.inputs]))
             else:
                 vals[nd.name] = eval_node(nd,
                                           [vals[t] for t in nd.inputs])
         return {n: vals[n] for n in self.out_names}
+
+
+class _Collectives:
+    """The shard body's collectives.  Each notes, into ``sink`` (the tally
+    of the trace in progress), the bytes a shard receives by it: the
+    other shards' parts for ``all_gather`` and ``psum``, the delivered
+    block for ``ppermute``."""
+
+    def __init__(self, axis: str, n_shards: int):
+        self.axis = axis
+        self.n_shards = n_shards
+        self.sink: Dict[str, int] = {}
+
+    def note(self, op: str, block, parts: int = 1) -> None:
+        self.sink[op] = (self.sink.get(op, 0)
+                         + parts * block.size * block.dtype.itemsize)
+
+    def all_gather(self, v):
+        from jax import lax
+        self.note("all_gather", v, self.n_shards - 1)
+        return lax.all_gather(v, self.axis, tiled=True)
+
+    def psum(self, v):
+        from jax import lax
+        self.note("psum", v, self.n_shards - 1)
+        return lax.psum(v, self.axis)
+
+
+def _float_dtype(vals):
+    """The dtype a program resolves from its leaves: the promoted type of
+    the floating ones (float32 without any)."""
+    import jax.numpy as jnp
+    floats = [v.dtype for v in vals if jnp.issubdtype(v.dtype, jnp.floating)]
+    return jnp.result_type(*floats) if floats else jnp.dtype(jnp.float32)
 
 
 class ShardedProgram:
@@ -350,7 +411,10 @@ class ShardedProgram:
 
     Structure mirrors :class:`~repro.exec.pallas._SingleProgram` — the
     localized units trace inside a single jit (rolled loops as
-    ``lax.fori_loop``), and ``stats`` counts one dispatch per solve."""
+    ``lax.fori_loop``), and ``stats`` counts one dispatch per solve.
+    Each dispatch adds its dense contraction passes to
+    ``exec.matvec_tiling`` and the bytes its collectives bring each shard
+    to ``exec.collective_bytes`` (tallied when the program traced)."""
 
     def __init__(self, plan):
         program = plan_program(plan)
@@ -377,6 +441,7 @@ class ShardedProgram:
 
         view = _local_view(program, sharded)
         halo = set(sharded.halo)
+        self._coll = _Collectives(sharded.axis, sharded.n_shards)
 
         def build(i):
             u = units[i]
@@ -384,7 +449,7 @@ class ShardedProgram:
                 return _StreamCall(view, u.sp, needed[i],
                                    defer_finalize=True)
             return _InlineUnit(view, u.ops, needed[i], halo,
-                               sharded.axis, sharded.n_shards)
+                               sharded.axis, sharded.n_shards, self._coll)
 
         self._pro = [build(i) for i in pro]
         self._tmpl = [build(i) for i in tmpl]
@@ -396,6 +461,10 @@ class ShardedProgram:
         for i in (*pro, *tmpl, *epi):
             _UNITS.inc(backend="pallas", kind=units[i].kind,
                        scope=self._scope)
+        self.matvec_tilings = pass_counts(self, _tiling_of)
+        # {dtype name: {op: bytes a shard receives per dispatch}}, from
+        # the trace at that dtype
+        self._collective_bytes: Dict[str, Dict[str, int]] = {}
 
         if roll is not None:
             tmpl_ops = {o for i in tmpl for o in units[i].ops}
@@ -415,14 +484,40 @@ class ShardedProgram:
                                  for sl in roll.slots]
 
         import jax
-        mesh = make_solver_mesh(sharded.n_shards, axis=sharded.axis)
+        from jax.sharding import NamedSharding
+        self.mesh = make_solver_mesh(sharded.n_shards, axis=sharded.axis)
+        self._shardings = {leaf: NamedSharding(self.mesh, spec)
+                           for leaf, spec in zip(self.leaf_names, in_specs)}
+        self._row_sharded = set(sharded.sharded) & set(self.leaf_names)
         # no donation: the replicated CSR triples and gathered operands
         # outlive their first read inside the shard body
         # the replication check cannot see through pallas calls mixed
         # with collectives, so it stays off
         self._jit = jax.jit(jax.shard_map(
-            self._traced, mesh=mesh, in_specs=tuple(in_specs),
+            self._traced, mesh=self.mesh, in_specs=tuple(in_specs),
             out_specs=tuple(out_specs), check_vma=False))
+
+    def feed_shardings(self) -> Dict[str, Any]:
+        """``{leaf: NamedSharding}`` on this program's mesh: row blocks
+        over the mesh axis for row-sharded leaves, replicated for the
+        rest.  A caller builds a large operand in place on these
+        (``docs/distributed.md``)."""
+        return dict(self._shardings)
+
+    def _check_feeds(self, args) -> None:
+        """Refuse a row-sharded device feed laid out otherwise than
+        :meth:`feed_shardings` says (``jit`` would reshard it)."""
+        import jax
+        for leaf, v in zip(self.leaf_names, args):
+            want = self._shardings[leaf]
+            if (leaf in self._row_sharded and isinstance(v, jax.Array)
+                    and not v.sharding.is_equivalent_to(want, v.ndim)):
+                raise FeedShardingError(
+                    f"feed {leaf!r} is laid out as {v.sharding}, but the "
+                    f"plan runs it in row blocks over mesh axis "
+                    f"{self.sharded.axis!r} ({want}); build it in place "
+                    f"on plan.feed_shardings()[{leaf!r}] or pass a host "
+                    f"array (docs/distributed.md)")
 
     @property
     def stats(self) -> Dict[str, int]:
@@ -436,17 +531,15 @@ class ShardedProgram:
     # -- per-unit driver (inside the shard_map trace) -------------------
     def _run_call(self, call, env: Dict[str, Any], dtype) -> None:
         import jax.numpy as jnp
-        from jax import lax
 
-        axis = self.sharded.axis
         for n in call.in_names:
             if n.endswith("@g") and n not in env:
-                env[n] = lax.all_gather(env[n[:-2]], axis, tiled=True)
+                env[n] = self._coll.all_gather(env[n[:-2]])
         out = call.apply(env, dtype)
         if isinstance(call, _StreamCall) and call.defer:
             norm = call.norm_reductions
             for n in call.red_out:
-                v = lax.psum(out[n], axis)
+                v = self._coll.psum(out[n])
                 out[n] = jnp.sqrt(v) if n in norm else v
             env.update(out)
             # the pass's scalar chain (eager + epilogue), replayed on the
@@ -461,9 +554,9 @@ class ShardedProgram:
     def _traced(self, *leaf_vals):
         import jax.numpy as jnp
         _TRACES.inc(backend="pallas", scope=self._scope)
-        float_dts = [v.dtype for v in leaf_vals
-                     if jnp.issubdtype(v.dtype, jnp.floating)]
-        dtype = jnp.result_type(*float_dts) if float_dts else jnp.float32
+        dtype = _float_dtype(leaf_vals)
+        tally: Dict[str, int] = {}
+        self._coll.sink = tally
         env: Dict[str, Any] = {}
         for name, v in zip(self.leaf_names, leaf_vals):
             env[name] = (jnp.asarray(v, dtype)
@@ -476,8 +569,11 @@ class ShardedProgram:
             from jax import lax
             slots = self.roll.slots
             base = {n: env[n] for n in self._tmpl_ext}
+            per_iter: Dict[str, int] = {}
 
             def body(_, carry):
+                per_iter.clear()            # one iteration's collectives
+                self._coll.sink = per_iter
                 env_l = dict(base)
                 for sl, v in zip(slots, carry):
                     if sl.read is not None:
@@ -493,17 +589,30 @@ class ShardedProgram:
             carry = lax.fori_loop(0, self.roll.n_iters, body, carry)
             for sl, v in zip(slots, carry):
                 env[sl.final] = v
+            for op, b in per_iter.items():
+                tally[op] = tally.get(op, 0) + b * self.roll.n_iters
+            self._coll.sink = tally
         for call in self._epi:
             self._run_call(call, env, dtype)
+        self._collective_bytes[jnp.dtype(dtype).name] = tally
         return tuple(env[o] for o in self.out_names)
 
     # -- the dispatch ---------------------------------------------------
     def __call__(self, feeds: Dict[str, Any]) -> Dict[str, Any]:
+        import jax.numpy as jnp
         args = []
         for leaf in self.leaf_names:
             if leaf not in feeds:
                 raise KeyError(f"feeds missing leaf {leaf!r}")
             args.append(feeds[leaf])
+        with obs.span("exec.feed_check"):
+            self._check_feeds(args)
         _DISPATCHES.inc(backend="pallas", scope=self._scope)
-        outs = self._jit(*args)
+        count_matvec_tilings(self)
+        with obs.span("exec.launch"):
+            outs = self._jit(*args)
+        sent = self._collective_bytes.get(jnp.dtype(_float_dtype(args)).name,
+                                          {})
+        for op, b in sent.items():
+            _COLLECTIVE_B.inc(b, backend="pallas", op=op, scope=self._scope)
         return dict(zip(self.out_names, outs))

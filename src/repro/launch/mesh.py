@@ -2,6 +2,8 @@
 never touches jax device state)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 from jax.sharding import Mesh
 
@@ -26,8 +28,13 @@ def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
     return Mesh(np.array(devs).reshape(data, model), ("data", "model"))
 
 
+@functools.lru_cache(maxsize=None)
 def make_solver_mesh(n_shards: int, *, axis: str = "shards") -> Mesh:
-    """1-D mesh for row-block sharded solver plans (``partition_plan``).
+    """1-D mesh for row-block sharded solver plans (``partition_plan``),
+    over the first ``n_shards`` devices; built once per ``(n_shards,
+    axis)`` and shared by every program of the process, so the shardings
+    a plan hands out (``CompiledPlan.feed_shardings``) name the mesh its
+    executable runs on.
 
     On CPU hosts the device count is 1 unless forced:
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (how CI runs

@@ -3,6 +3,7 @@ and the compressed cross-pod all-reduce under shard_map — plus the HPC
 side: co-designed DAGs partitioned across a device mesh
 (``Session.lower(mesh=...)``, ``core.lowering.partition_plan``)."""
 import json
+import pathlib
 import subprocess
 import sys
 
@@ -296,7 +297,10 @@ for wl, params in [("cg", dict(n=256, iters=4)),
     plan = sess.lower(cd, config=ExecConfig(backend="pallas", mesh=8))
     from repro.exec.base import get_backend
     prog = get_backend("pallas").compile(plan)   # the stats live per program
-    got = prog(feeds)
+    # device feeds go in laid out as the plan runs them
+    import jax
+    sh = plan.feed_shardings()
+    got = prog({k: jax.device_put(v, sh[k]) for k, v in feeds.items()})
     rel = max(float(np.max(np.abs(np.asarray(got[k]) - np.asarray(ref[k]))
                            / (np.abs(np.asarray(ref[k])) + 1e-6)))
               for k in ref)
@@ -311,3 +315,158 @@ print(json.dumps(out))
         assert r["rel"] < 2e-3, (wl, r)
         assert r["stats"]["dispatches"] == 1, (wl, r)
         assert r["stats"]["traces"] == 1, (wl, r)
+
+
+# ---------------------------------------------------------------------------
+# an operator fed in place: column-blocked passes on a mesh, the leaves'
+# shardings, the refusal of a mis-sharded feed, and the per-dispatch
+# counters (4 forced host devices, in a subprocess)
+# ---------------------------------------------------------------------------
+
+MESH_FEED_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys; sys.path.insert(0, "src")
+import dataclasses, json
+import numpy as np
+import jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+import repro.core.lowering as lowering
+from repro import obs
+from repro.api import Session
+from repro.exec import get_backend
+from repro.frontends import make_feeds
+
+N, ITERS, K = 1024, 6, 4
+lowering.KERNEL_VMEM_BYTES = 512 << 10       # 128 whole rows do not fit
+sess = Session(use_cache=False)
+t = sess.trace(workload="cg", n=N, iters=ITERS)
+cd = sess.codesign(t)
+feeds = make_feeds(t.program, seed=5)
+ref = sess.lower(cd, backend="reference", mesh=K).run(
+    {k: jnp.asarray(v) for k, v in feeds.items()})
+out = {"ref": {k: np.asarray(v).tolist() for k, v in ref.items()}}
+for variant in ("cols-divisor", "cols-whole-row"):
+    plan = sess.lower(cd, backend="pallas", mesh=K)
+    if variant == "cols-whole-row":
+        local = plan.sharded.local
+        units = tuple(dataclasses.replace(
+            u, sp=dataclasses.replace(u.sp, tile_cols=N))
+            if u.sp is not None and u.sp.tile_cols else u
+            for u in local.units)
+        plan = dataclasses.replace(plan, sharded=dataclasses.replace(
+            plan.sharded, local=dataclasses.replace(local, units=units)))
+    sh = plan.feed_shardings()
+    prog = get_backend("pallas").compiled(plan)
+    placed = {k: jax.device_put(v, sh[k]) for k, v in feeds.items()}
+    got = [prog(placed) for _ in range(2)]
+    host = prog(feeds)                       # host arrays: placed by jit
+    rec = {"tile_cols": sorted({u.sp.tile_cols for u in
+                                plan.sharded.local.units
+                                if u.sp is not None and u.sp.tile_cols}),
+           "got": {k: np.asarray(v).tolist() for k, v in got[1].items()},
+           "host_same": all(np.array_equal(np.asarray(host[k]),
+                                           np.asarray(got[1][k]))
+                            for k in host),
+           "shardings": {k: [str(a) for a in v.spec] for k, v in sh.items()},
+           "one_mesh": all(v.mesh is prog.mesh for v in sh.values()),
+           "leaves": sorted(nd.name for nd in t.program.leaves()),
+           "gathered": len(plan.sharded.gathered),
+           "reduced": len(plan.sharded.reduced),
+           "scope": prog._scope}
+    refused = {}
+    for name, bad in (
+            ("one device", jnp.asarray(feeds["A"])),
+            ("columns", jax.device_put(feeds["A"], NamedSharding(
+                prog.mesh, P(None, "shards"))))):
+        try:
+            prog(dict(placed, A=bad))
+            refused[name] = None
+        except Exception as e:
+            refused[name] = [type(e).__name__, str(e)]
+    rec["refused"] = refused
+    snap = obs.registry().snapshot()
+    rec["counters"] = {
+        name: [[c["labels"], c["value"]] for c in snap[name]["cells"]
+               if c["labels"].get("scope") == prog._scope]
+        for name in ("exec.matvec_tiling", "exec.collective_bytes",
+                     "exec.dispatches")}
+    out[variant] = rec
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def mesh_feed_run():
+    res = subprocess.run([sys.executable, "-c", MESH_FEED_SCRIPT],
+                         cwd=str(pathlib.Path(__file__).resolve().parents[1]),
+                         capture_output=True, text=True, timeout=900)
+    assert res.returncode == 0, res.stderr[-3000:]
+    return json.loads(res.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("variant", ["cols-divisor", "cols-whole-row"])
+def test_column_blocked_mesh_matches_sharded_reference(mesh_feed_run,
+                                                       variant):
+    """Column-blocked passes on each shard's row block, fed in place,
+    match the bitwise sharded oracle at the float32 tolerances
+    (docs/execution_backends.md); a host feed gives the same answer."""
+    rec = mesh_feed_run[variant]
+    assert rec["tile_cols"] == ([1024] if variant == "cols-whole-row"
+                                else [256])
+    for k, want in mesh_feed_run["ref"].items():
+        np.testing.assert_allclose(np.asarray(rec["got"][k]),
+                                   np.asarray(want), rtol=2e-4, atol=1e-5,
+                                   err_msg=k)
+    assert rec["host_same"]
+
+
+def test_feed_shardings_name_every_leaf_on_one_mesh(mesh_feed_run):
+    rec = mesh_feed_run["cols-divisor"]
+    assert sorted(rec["shardings"]) == rec["leaves"] == ["A", "b", "x0"]
+    assert all(spec == ["shards"] for spec in rec["shardings"].values())
+    assert rec["one_mesh"]
+
+
+def test_a_mis_sharded_feed_is_refused(mesh_feed_run):
+    for variant in ("cols-divisor", "cols-whole-row"):
+        refused = mesh_feed_run[variant]["refused"]
+        for how, err in refused.items():
+            assert err is not None, how
+            assert err[0] == "FeedShardingError"
+            assert "feed_shardings()['A']" in err[1]
+
+
+def test_mesh_counters_count_per_dispatch(mesh_feed_run):
+    """Three dispatches: ``exec.matvec_tiling`` counts each one's 7
+    column-blocked matvecs, ``exec.collective_bytes`` the bytes each
+    shard receives (the other 3 shards' rows of every gathered vector,
+    their partials of every reduction); refused feeds dispatch nothing."""
+    rec = mesh_feed_run["cols-divisor"]
+    counters = {name: {tuple(sorted(lab.items())): v for lab, v in cells}
+                for name, cells in rec["counters"].items()}
+    scope = ("scope", rec["scope"])
+    base = (("backend", "pallas"), scope)
+
+    def value(name, **labels):
+        key = tuple(sorted(base + tuple(labels.items())))
+        return counters[name].get(key, 0)
+    assert value("exec.dispatches") == 3
+    assert value("exec.matvec_tiling", tiling="blocked") == 3 * 7
+    assert value("exec.matvec_tiling", tiling="rows") == 0
+    assert value("exec.collective_bytes", op="all_gather") == \
+        3 * rec["gathered"] * (1024 - 256) * 4
+    assert value("exec.collective_bytes", op="psum") == \
+        3 * rec["reduced"] * 3 * 4
+    assert rec["gathered"] == 7
+
+
+def test_feed_shardings_need_a_device_mesh():
+    sess = Session()
+    t = sess.trace(workload="cg", n=128, iters=2)
+    cd = sess.codesign(t)
+    for plan in (sess.lower(cd, backend="pallas"),
+                 sess.lower(cd, backend="pallas", mesh=1),
+                 sess.lower(cd, mesh=4)):           # reference: simulated
+        with pytest.raises(ValueError, match="mesh"):
+            plan.feed_shardings()

@@ -631,6 +631,132 @@ class TestTileBudget:
                 assert sp.rows % sp.tile_rows == 0
 
 
+def _cg64(feeds, iters):
+    """A float64 NumPy CG of the ``cg`` workload's recurrence on its own
+    feeds: ``(x, r)`` after ``iters`` iterations."""
+    a, b, x = (np.asarray(feeds[k], np.float64) for k in ("A", "b", "x0"))
+    r = b - a @ x
+    p, rs = r.copy(), r @ r
+    for _ in range(iters):
+        ap = a @ p
+        alpha = rs / (p @ ap)
+        x, r = x + alpha * p, r - alpha * ap
+        rs, rs_old = r @ r, rs
+        p = r + rs / rs_old * p
+    return x, r
+
+
+class TestColumnBlockedMatvec:
+    """A pass whose whole rows fit no tile walks column tiles too, and
+    accumulates each row tile's product across them."""
+
+    N, ITERS = 1024, 6
+
+    def _plans(self, tmp_path, monkeypatch, whole_row_cols):
+        import dataclasses
+        import repro.core.lowering as lowering
+        traced = Session(cache_dir=tmp_path).trace(
+            workload="cg", n=self.N, iters=self.ITERS)
+        rows_plan = traced.analyze().codesign().lower(backend="pallas")
+        # 512 KiB: 128 rows of 1024 columns (1 MiB double-buffered) fit
+        # no more
+        monkeypatch.setattr(lowering, "KERNEL_VMEM_BYTES", 512 << 10)
+        plan = Session(use_cache=False).trace(
+            workload="cg", n=self.N, iters=self.ITERS).analyze() \
+            .codesign().lower(backend="pallas")
+        if whole_row_cols:              # one column step per row tile
+            ep = plan.exec_plan
+            units = tuple(dataclasses.replace(
+                u, sp=dataclasses.replace(u.sp, tile_cols=self.N))
+                if u.sp is not None and u.sp.tile_cols else u
+                for u in ep.units)
+            plan = dataclasses.replace(
+                plan, exec_plan=dataclasses.replace(ep, units=units))
+        return plan, rows_plan
+
+    @pytest.mark.parametrize("whole_row_cols", [False, True],
+                             ids=["cols-divisor", "cols-whole-row"])
+    def test_blocked_cg_matches_float64_and_whole_rows(
+            self, tmp_path, monkeypatch, whole_row_cols):
+        from repro import obs
+        plan, rows_plan = self._plans(tmp_path, monkeypatch,
+                                      whole_row_cols)
+        graph = plan.trace.graph
+        matvec_units = [u for u in plan.exec_plan.units
+                        if any(graph.ops[o].spec == "ab,b->a"
+                               for o in u.ops)]
+        assert matvec_units and all(u.kind == "stream"
+                                    for u in matvec_units)
+        for u in matvec_units:
+            assert u.sp.tile_cols and self.N % u.sp.tile_cols == 0
+            assert (u.sp.tile_cols == self.N) == whole_row_cols
+            assert u.sp.tile_rows % 128 == 0
+        assert not any(u.sp.tile_cols for u in rows_plan.exec_plan.units
+                       if u.sp is not None)
+        if not whole_row_cols:
+            assert f"/{matvec_units[0].sp.tile_cols}c" in plan.explain()
+        feeds = make_feeds(plan.trace.program, seed=3)
+        prog = get_backend("pallas").compile(plan)
+        assert prog.matvec_tilings == {"blocked": self.ITERS + 1}
+        got = prog(feeds)
+        out_x, out_r = f"x{self.ITERS}", f"r{self.ITERS}"
+        x64, r64 = _cg64(feeds, self.ITERS)
+        np.testing.assert_allclose(np.asarray(got[out_x], np.float64), x64,
+                                   rtol=RTOL, atol=ATOL)
+        np.testing.assert_allclose(np.asarray(got[out_r], np.float64), r64,
+                                   rtol=RTOL, atol=ATOL)
+        rows = rows_plan.run(feeds)
+        for k in (out_x, out_r):
+            np.testing.assert_allclose(np.asarray(got[k]),
+                                       np.asarray(rows[k]),
+                                       rtol=RTOL, atol=ATOL, err_msg=k)
+        counter = obs.registry().counter("exec.matvec_tiling")
+        assert counter.value(backend="pallas", tiling="blocked",
+                             scope=prog._scope) == self.ITERS + 1
+        assert counter.value(backend="pallas", tiling="rows",
+                             scope=prog._scope) == 0
+
+    @pytest.mark.parametrize("n,mesh", [(32768, None), (65536, None),
+                                        (65536, 4), (131072, 8)])
+    def test_no_matvec_falls_back_at_any_size(self, n, mesh):
+        plan = Session(use_cache=False).trace(workload="cg", n=n, iters=4) \
+            .analyze().codesign().lower(backend="pallas", mesh=mesh)
+        graph = plan.trace.graph
+        for gk in plan.group_kernels:
+            if any(graph.ops[o].spec == "ab,b->a" for o in gk.ops):
+                assert gk.kind == "stream", gk.describe()
+        units = (plan.sharded.local if mesh else plan.exec_plan).units
+        wide = [u.sp for u in units if u.sp is not None and u.sp.tile_cols]
+        assert len(wide) == 5       # Ax0, Ap0, and Ap1..Ap3
+        for sp in wide:
+            assert n % sp.tile_cols == 0 and sp.tile_cols >= 2048
+            assert sp.vmem_bytes <= 32 << 20
+        assert "c res=" in plan.explain()
+
+    def test_a_pass_fitting_whole_rows_keeps_them(self):
+        plan = Session(use_cache=False).trace(workload="cg", n=8192,
+                                              iters=4) \
+            .analyze().codesign().lower(backend="pallas")
+        assert not any(u.sp.tile_cols for u in plan.exec_plan.units)
+
+    def test_reference_contracts_at_highest_precision(self):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.exec.reference import eval_node
+        prog = build_workload("cg", n=8, iters=1)
+        kinds = {"matmul", "einsum", "dot", "norm"}
+        nodes = [nd for nd in prog.nodes.values() if nd.op in kinds]
+        assert {nd.op for nd in nodes} >= {"matmul", "dot"}
+        for nd in nodes:
+            args = [jnp.ones(prog.nodes[t].shape, jnp.float32)
+                    for t in nd.inputs]
+            text = str(jax.make_jaxpr(
+                lambda *a, nd=nd: eval_node(nd, list(a)))(*args))
+            assert "precision=(Precision.HIGHEST, Precision.HIGHEST)" \
+                in text, (nd.op, text)
+
+
 # ---------------------------------------------------------------------------
 # the dispatch's instruments: child spans, copied feed bytes, host time,
 # and the device scopes a trace cannot name

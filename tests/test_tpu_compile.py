@@ -69,14 +69,14 @@ def _shapes(program, names, sharding, batch=None):
 
 def _assert_every_kernel_compiled(prog, text):
     """Each stream/block unit the program traces is a Mosaic custom call
-    (kernels are named ``cello_<kind>_<first op>``, ``vmap_``-prefixed
-    when batched).  A pass whose values nothing reads emits no kernel."""
+    (kernels are named ``cello_<kind>_<first op>``, ``cello_wide_`` for a
+    column-blocked pass, ``vmap_``-prefixed when batched).  A pass whose values nothing reads emits no kernel."""
     from repro.exec.pallas import _BlockCall, _StreamCall
     calls = {m for line in text.splitlines() if "tpu_custom_call" in line
              for m in re.findall(r"%(?:vmap_)?(cello_\w+?)_?(?:\.\d+)? = ",
                                  line)}
     units = (*prog._pro, *prog._tmpl, *prog._epi)
-    want = {"cello_stream_" + c.sp.ops[0] for c in units
+    want = {c.kernel_name for c in units
             if isinstance(c, _StreamCall) and (c.red_out or c.stream_out)}
     want |= {"cello_block_" + c.nodes[0].name for c in units
              if isinstance(c, _BlockCall)}
@@ -190,6 +190,62 @@ def test_batched_sparse_core_b8_compiles(topo, mosaic):
     assert prog.spmv_layouts == {"dia": 9}
     _assert_every_kernel_compiled(prog, text)
     assert "%cello_dia_A" in text and "vmap_cello_dia" not in text
+
+
+def _sharded_compile(topo, monkeypatch, traced, plan):
+    """The sharded program of ``plan`` compiled for the described chips,
+    every leaf at its traced shape on the mesh of its in_spec."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding
+
+    import repro.exec.sharded as sharded_mod
+    devices = np.array(topo.devices[:plan.sharded.n_shards])
+    # the executor builds its mesh from jax.devices(); hand it the
+    # described chips instead
+    monkeypatch.setattr(sharded_mod, "make_solver_mesh",
+                        lambda n, axis="shards": Mesh(devices[:n], (axis,)))
+    prog = sharded_mod.ShardedProgram(plan)
+    mesh = Mesh(devices, (plan.sharded.axis,))
+    _, in_specs, _, _ = sharded_mod._partition_specs(traced.program,
+                                                     plan.sharded)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for a, spec in zip(_shapes(traced.program, prog.leaf_names,
+                                       None), in_specs)]
+    return prog, prog._jit.lower(*args).compile()
+
+
+def test_sharded_dense_cg_n65536_mesh4_compiles(topo, mosaic, monkeypatch):
+    """A dense operator larger than one chip (16 GiB in float32), 4 GiB a
+    chip: every matvec pass is column-blocked (no jnp fallback holds a
+    contraction), each kernel reaches Mosaic within the scoped VMEM
+    limit, and the exchanges are collectives."""
+    from repro.core.lowering import KERNEL_VMEM_BYTES
+    from repro.exec.pallas import _vmem_limit
+    traced, plan = _plan("cg", mesh=4, n=65536, iters=50)
+    for units in (plan.exec_plan.units, plan.sharded.local.units):
+        assert all(u.kind == "stream" or all(
+            traced.program.nodes[o].op not in ("matmul", "einsum")
+            for o in u.ops) for u in units)
+    wide = [u.sp for u in plan.sharded.local.units
+            if u.kind == "stream" and u.sp.tile_cols]
+    assert wide and all(sp.rows == 16384 and 65536 % sp.tile_cols == 0
+                        and sp.tile_cols >= 2048 for sp in wide)
+    assert "/8192c" in plan.explain()
+    assert all(u.sp.vmem_bytes <= KERNEL_VMEM_BYTES
+               and u.sp.vmem_bytes < _vmem_limit(u.sp.vmem_bytes)
+               for u in plan.sharded.local.units if u.kind == "stream")
+    prog, compiled = _sharded_compile(topo, monkeypatch, traced, plan)
+    assert prog.matvec_tilings == {"blocked": 51}
+    text = compiled.as_text()
+    _assert_every_kernel_compiled(prog, text)
+    assert "cello_wide_Ap" in text
+    assert "all-gather" in text and "all-reduce" in text
+    # the operator's shard is the program's one large argument: 4 GiB a
+    # chip, and no copy of it
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < (4 << 30) + (8 << 20)
+    assert mem.temp_size_in_bytes < (64 << 20)
 
 
 def test_sharded_cg_4_devices_compiles(topo, mosaic, monkeypatch):
