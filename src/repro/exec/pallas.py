@@ -44,6 +44,14 @@ kernels, no per-call ``result_type``/``asarray`` conversion.  The pieces:
   a one-hot row matrix built from those ranges — no scatter.  Feeds must
   match the pattern meta the plan was built for
   (:func:`core.lowering.check_csr_feeds` refuses those that do not).
+* Under ``jax.vmap`` (the pure core :class:`serve.BatchedPlan` batches)
+  a stream pass whose matrix and diagonal-layout operands are unbatched
+  runs as one *lane kernel*, ``cello_lanes_<first op>``: the same grid,
+  each matrix tile read once and contracted with the ``(B, n)`` block of
+  every lane's right-hand side, vectors in ``(B, tile)`` blocks, scalars
+  and reductions in per-lane ``(B, 1)`` VMEM cells.  Other passes (a
+  per-tile CSR spmv) take ``jax.vmap`` of the single-RHS kernel, which
+  reads the operand once per lane.  The single dispatch never takes it.
 * ``block`` units hold whole arrays as single blocks (stencil halos).
 * ``jnp`` units — irregular gathers, >2-operand einsums, working sets
   over one kernel's VMEM — inline the reference rules straight into the
@@ -281,13 +289,26 @@ class _StreamCall:
     per-shard reduction partials: no ``sqrt`` on norm accumulators, no
     epilogue — the sharded program combines partials with ``psum`` and
     replays the epilogue (:attr:`finalize_nodes`) inside the
-    ``shard_map`` trace."""
+    ``shard_map`` trace.
+
+    With ``lanes=True`` (the pure core a batched plan vmaps) the kernel
+    call gets a batching rule: vmapped over B lanes while its matrix and
+    diagonal-layout operands are unbatched, the pass runs as one *lane
+    kernel*, ``cello_lanes_<first op>``, on the same grid, reading each
+    matrix tile once and contracting it with the ``(B, n)`` block of every
+    lane's resident vector; vectors move as ``(B, tile)`` blocks, scalars
+    and reductions as per-lane ``(B, 1)`` VMEM cells.  Any other
+    combination (a per-tile CSR spmv, a batched matrix, a contraction with
+    a matrix right-hand side) runs ``jax.vmap`` of the single-RHS kernel,
+    one read per lane.  :attr:`batched_read` records which one the
+    last batched trace took."""
 
     def __init__(self, program, sp: StreamPass, needed: Set[str], *,
-                 defer_finalize: bool = False):
+                 defer_finalize: bool = False, lanes: bool = False):
         self.nodes = [program.nodes[o] for o in sp.ops]
         self.sp = sp
         self.defer = defer_finalize
+        self.lanes = lanes
         produced = {nd.name for nd in self.nodes}
         self.shapes = {n: tuple(program.nodes[n].shape)
                        for nd in self.nodes for n in (*nd.inputs, nd.name)}
@@ -372,6 +393,28 @@ class _StreamCall:
                            and nd.name in needed]
         self.needed = needed
         self._built: Dict[Any, Callable] = {}
+        # the lane kernel: every streamed value a vector, every matrix a
+        # whole-row contraction's left operand, no per-tile CSR spmv (its
+        # gathered values differ per lane)
+        self._lane_kernel_fits = (
+            all(s.layout == "dia" for s in self.spmv.values())
+            and all(len(self.shapes[nd.name]) == 1 for nd in self.nodes
+                    if self.classes[nd.name] == "tiled")
+            and all(len(self.shapes[t]) <= 1 or (
+                nd.op in ("matmul", "einsum")
+                and nd.param("spec") == "ab,b->a" and t == nd.inputs[0])
+                    for nd in self.nodes for t in nd.inputs))
+        # the values the kernel call reads, once each: its inputs, and
+        # each spmv's layout and x; of them, the operands every lane reads
+        # alike: matrices and diagonals
+        self._lane_names = list(dict.fromkeys(
+            [*stream_in, *res_in, *scalar_in,
+             *(n for s in self.spmv.values() for n in (s.derived, s.x))]))
+        self._lane_shared = ({n for n in self.stream_in + self.res_in
+                              if len(self.shapes[n]) == 2}
+                             | {s.derived for s in self.spmv.values()})
+        self.batched_read: Optional[str] = (
+            "shared" if self.red_out or self.stream_out else None)
 
     @property
     def kernel_name(self) -> str:
@@ -381,7 +424,15 @@ class _StreamCall:
         kind = "wide" if self.sp.tile_cols else "stream"
         return f"cello_{kind}_{self.sp.ops[0]}"
 
-    def _build(self, dtype):
+    @property
+    def lanes_kernel_name(self) -> str:
+        """The lane kernel's name, ``cello_lanes_<first op>``."""
+        return f"cello_lanes_{self.sp.ops[0]}"
+
+    def _build(self, dtype, lanes: Optional[int] = None):
+        """The single-RHS kernel, or with ``lanes=B`` the lane kernel:
+        every vector a ``(B, ...)`` block, scalars and reductions per-lane
+        ``(B, 1)`` VMEM cells, matrices and diagonals as before."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -396,6 +447,12 @@ class _StreamCall:
         outs = self.red_out + self.stream_out
         stream_out_set = set(self.stream_out)
         hi = lax.Precision.HIGHEST
+        rb = lanes or 1                 # rows of every vector block
+
+        def kshape(shape):
+            """The kernel-side shape of an array at this build's lanes."""
+            s = _row_shape(shape)
+            return (rb,) + s[1:] if len(shape) < 2 else s
         # a column-blocked pass walks (row tile, column tile); its
         # contractions accumulate each row tile's product in VMEM scratch
         # and everything else runs once the last column tile is in
@@ -429,9 +486,10 @@ class _StreamCall:
                            refs[n_in + len(outs):]))
             tiles: Dict[str, Any] = {}
 
-            def val(name):                 # streamed tile or SMEM scalar
-                if name in cref:
-                    return cref[name][0, 0]
+            def val(name):                 # streamed tile or scalar
+                if name in cref:            # SMEM, or per lane (B, 1)
+                    return (cref[name][0, 0] if lanes is None
+                            else cref[name][...])
                 if name not in tiles:
                     tiles[name] = sref[name][...]
                 return tiles[name]
@@ -466,7 +524,11 @@ class _StreamCall:
                     elif cls == "reduce":
                         a = val(nd.inputs[0])
                         b = a if nd.op == "norm" else val(nd.inputs[1])
-                        _accumulate(oref[nd.name], jnp.sum(a * b), i)
+                        if lanes is None:
+                            _accumulate(oref[nd.name], jnp.sum(a * b), i)
+                        else:           # each lane's tile partial
+                            _accumulate(oref[nd.name], jnp.sum(
+                                a * b, axis=1, keepdims=True), i, ...)
 
             if not tc:
                 row_tile()
@@ -492,7 +554,7 @@ class _StreamCall:
 
         def stream_spec(shape):
             if len(shape) == 1:
-                return pl.BlockSpec((1, tr), rows_map(lambda i: (0, i)))
+                return pl.BlockSpec((rb, tr), rows_map(lambda i: (0, i)))
             if tc:                              # a (tr, tc) matrix block
                 return pl.BlockSpec((tr, tc), lambda i, j: (i, j))
             return pl.BlockSpec((tr,) + shape[1:],
@@ -500,14 +562,14 @@ class _StreamCall:
 
         def full_spec(shape):
             if tc:                  # the right-hand side's column tile
-                return pl.BlockSpec((1, tc), lambda i, j: (0, j))
-            shape = _row_shape(shape)
+                return pl.BlockSpec((rb, tc), lambda i, j: (0, j))
+            shape = kshape(shape)
             return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
         def tile_spec(name):
             if name in self.x_shift:    # x's tile or a neighbour, clamped
                 j = self.x_shift[name]
-                return pl.BlockSpec((1, tr), lambda i: (0, jnp.minimum(
+                return pl.BlockSpec((rb, tr), lambda i: (0, jnp.minimum(
                     jnp.maximum(i + j, 0), n_tiles - 1)))
             if name.endswith("@dia"):           # (K, tr) diagonals
                 return pl.BlockSpec((self.shapes[name][0], tr),
@@ -517,32 +579,55 @@ class _StreamCall:
             return pl.BlockSpec((None, 1, self.shapes[name][-1]),
                                 lambda i: (i, 0, 0))
 
-        smem = pl.BlockSpec((1, 1), rows_map(lambda i: (0, 0)),
-                            memory_space=pltpu.SMEM)
+        if lanes is None:
+            cell = pl.BlockSpec((1, 1), rows_map(lambda i: (0, 0)),
+                                memory_space=pltpu.SMEM)
+        else:
+            cell = pl.BlockSpec((rb, 1), rows_map(lambda i: (0, 0)))
         in_specs = ([stream_spec(self.shapes[n]) for n in self.stream_in]
                     + [tile_spec(n) for n in self.tile_in]
                     + [full_spec(self.shapes[n]) for n in self.res_in]
-                    + [smem] * len(self.scalar_in))
-        out_specs = ([smem] * len(self.red_out)
+                    + [cell] * len(self.scalar_in))
+        out_specs = ([cell] * len(self.red_out)
                      + [stream_spec(self.shapes[n])
                         for n in self.stream_out])
-        out_shape = ([jax.ShapeDtypeStruct((1, 1), dtype)
+        out_shape = ([jax.ShapeDtypeStruct(kshape(()), dtype)
                       for _ in self.red_out]
-                     + [jax.ShapeDtypeStruct(_row_shape(self.shapes[n]),
-                                             dtype)
+                     + [jax.ShapeDtypeStruct(kshape(self.shapes[n]), dtype)
                         for n in self.stream_out])
         grid = (n_tiles, n_cols) if tc else (n_tiles,)
+        name, vmem = self.kernel_name, self.sp.vmem_bytes
+        if lanes is not None:
+            name, vmem = self.lanes_kernel_name, vmem + self._lanes_vmem(rb)
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs,
             out_specs=out_specs, out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((1, tr), dtype) for _ in matvecs],
-            **_pallas_call_kwargs(self.kernel_name, dtype,
-                                  self.sp.vmem_bytes, len(grid)))
+            scratch_shapes=[pltpu.VMEM((rb, tr), dtype) for _ in matvecs],
+            **_pallas_call_kwargs(name, dtype, vmem, len(grid)))
+
+    def _lanes_vmem(self, lanes: int) -> int:
+        """VMEM the lane kernel plans beyond the single-RHS one: vector
+        blocks of ``lanes`` rows (the same bytes up to 8, one sublane
+        tile) and the per-lane scalar and reduction cells, all double
+        buffered, plus the wider accumulators."""
+        tr, tc = self.sp.tile_rows, self.sp.tile_cols
+
+        def more(cols: int) -> int:
+            return (kernel_block_bytes((lanes, cols))
+                    - kernel_block_bytes((cols,)))
+
+        cells = len(self.scalar_in) + len(self.red_out)
+        tiles = (sum(len(self.shapes[n]) == 1 for n in self.stream_in)
+                 + len(self.x_shift) + len(self.stream_out))
+        res = sum(more(tc or self.shapes[n][0]) for n in self.res_in)
+        accs = sum(nd.op in ("matmul", "einsum") for nd in self.nodes
+                   if tc and self.classes[nd.name] == "tiled")
+        return (2 * (cells * kernel_block_bytes((lanes, 1))
+                     + tiles * more(tr) + res) + accs * more(tr))
 
     # -- drivers --------------------------------------------------------
     def apply(self, env: Dict[str, Any], dtype) -> Dict[str, Any]:
         """Run (or trace) this pass over ``env`` at a resolved ``dtype``."""
-        import jax
         import jax.numpy as jnp
         vals: Dict[str, Any] = {}
 
@@ -552,31 +637,12 @@ class _StreamCall:
         for nd in self.eager:
             vals[nd.name] = eval_node(nd, [get(t) for t in nd.inputs])
         if self.red_out or self.stream_out:
-            call = self._built.get(dtype)
-            if call is None:
-                call = self._built[dtype] = self._build(dtype)
-            tile_args = []
-            for s in self.spmv.values():
-                lay = (env[s.derived] if s.derived in env
-                       else self.derived[s.derived](env, dtype))
-                if s.layout == "dia":
-                    x = jnp.reshape(jnp.asarray(env[s.x], dtype), (1, -1))
-                    tile_args += [lay] + [x] * (2 * s.halo + 1)
-                    continue
-                cols, data, first, stop = lay
-                with jax.named_scope(GATHER_SCOPE):
-                    vals_t = data * jnp.asarray(env[s.x], dtype)[cols]
-                tile_args += [vals_t, first, stop]
-            row = [jnp.reshape(jnp.asarray(env[n], dtype),
-                               _row_shape(self.shapes[n]))
-                   for n in self.stream_in + self.res_in]
-            args = (row[:len(self.stream_in)] + tile_args
-                    + row[len(self.stream_in):]
-                    + [jnp.reshape(jnp.asarray(get(n), dtype), (1, 1))
-                       for n in self.scalar_in])
-            outs = call(*args)
-            for n, v in zip(self.red_out + self.stream_out, outs):
-                vals[n] = jnp.reshape(v, self.shapes[n])
+            if self.lanes:
+                outs = self._with_lanes(dtype)(
+                    *(get(n) for n in self._lane_names))
+            else:
+                outs = self._run(env, get, dtype)
+            vals.update(zip(self.red_out + self.stream_out, outs))
         if not self.defer:
             for n in self.norm_reductions:
                 vals[n] = jnp.sqrt(vals[n])
@@ -587,6 +653,98 @@ class _StreamCall:
             keep = (self.needed | set(self.red_out)
                     | {nd.name for nd in self.eager})
         return {n: v for n, v in vals.items() if n in keep}
+
+    def _kernel(self, dtype, lanes: Optional[int] = None):
+        call = self._built.get((dtype, lanes))
+        if call is None:
+            call = self._built[(dtype, lanes)] = self._build(dtype, lanes)
+        return call
+
+    def _run(self, env: Dict[str, Any], get, dtype) -> tuple:
+        """The single-RHS kernel over ``env``: its outputs at their own
+        shapes, reductions first."""
+        import jax
+        import jax.numpy as jnp
+        tile_args = []
+        for s in self.spmv.values():
+            lay = (env[s.derived] if s.derived in env
+                   else self.derived[s.derived](env, dtype))
+            if s.layout == "dia":
+                x = jnp.reshape(jnp.asarray(env[s.x], dtype), (1, -1))
+                tile_args += [lay] + [x] * (2 * s.halo + 1)
+                continue
+            cols, data, first, stop = lay
+            with jax.named_scope(GATHER_SCOPE):
+                vals_t = data * jnp.asarray(env[s.x], dtype)[cols]
+            tile_args += [vals_t, first, stop]
+        row = [jnp.reshape(jnp.asarray(env[n], dtype),
+                           _row_shape(self.shapes[n]))
+               for n in self.stream_in + self.res_in]
+        args = (row[:len(self.stream_in)] + tile_args
+                + row[len(self.stream_in):]
+                + [jnp.reshape(jnp.asarray(get(n), dtype), (1, 1))
+                   for n in self.scalar_in])
+        outs = self._kernel(dtype)(*args)
+        return tuple(jnp.reshape(v, self.shapes[n])
+                     for n, v in zip(self.red_out + self.stream_out, outs))
+
+    def _with_lanes(self, dtype) -> Callable:
+        """:meth:`_run` over :attr:`_lane_names`' values, with a batching
+        rule: the lane kernel where it fits and every shared operand is
+        unbatched, else ``jax.vmap`` of the single-RHS kernel."""
+        fn = self._built.get((dtype, "vmap"))
+        if fn is not None:
+            return fn
+        import jax
+        from jax.custom_batching import custom_vmap
+        names = self._lane_names
+        n_out = len(self.red_out) + len(self.stream_out)
+
+        def single(*vals):
+            env = dict(zip(names, vals))
+            return self._run(env, env.__getitem__, dtype)
+
+        def rule(axis_size, in_batched, *vals):
+            batched = dict(zip(names, in_batched))
+            if self._lane_kernel_fits and not any(
+                    b for n in self._lane_shared
+                    for b in jax.tree_util.tree_leaves(batched[n])):
+                self.batched_read = "shared"
+                outs = self._run_lanes(axis_size, dict(zip(names, vals)),
+                                       dtype)
+            else:
+                self.batched_read = "per_lane"
+                axes = [jax.tree_util.tree_map(lambda b: 0 if b else None,
+                                               b) for b in in_batched]
+                outs = jax.vmap(single, in_axes=axes)(*vals)
+            return tuple(outs), (True,) * n_out
+
+        fn = self._built[(dtype, "vmap")] = custom_vmap(single)
+        fn.def_vmap(rule)
+        return fn
+
+    def _run_lanes(self, lanes: int, env: Dict[str, Any], dtype) -> tuple:
+        """The lane kernel over ``env``, whose per-lane values carry a
+        leading ``lanes`` axis (or none, and are broadcast to every lane):
+        its outputs with the lanes axis, reductions first."""
+        import jax.numpy as jnp
+
+        def lane(n):
+            return jnp.broadcast_to(jnp.asarray(env[n], dtype),
+                                    (lanes,) + self.shapes[n])
+
+        tile_args = []
+        for s in self.spmv.values():            # diagonal layouts only
+            tile_args += [env[s.derived]] + [lane(s.x)] * (2 * s.halo + 1)
+        row = [jnp.asarray(env[n], dtype) if n in self._lane_shared
+               else lane(n) for n in self.stream_in + self.res_in]
+        args = (row[:len(self.stream_in)] + tile_args
+                + row[len(self.stream_in):]
+                + [jnp.reshape(lane(n), (lanes, 1))
+                   for n in self.scalar_in])
+        outs = self._kernel(dtype, lanes)(*args)
+        return tuple(jnp.reshape(v, (lanes,) + self.shapes[n])
+                     for n, v in zip(self.red_out + self.stream_out, outs))
 
     @property
     def matvec_tiling(self) -> Optional[str]:
@@ -802,16 +960,18 @@ def _dia_layout_call(name: str, rows: int, tr: int, window_rows: int,
                               dia_layout_bytes(tr, entries, n_diag), 1))
 
 
-def _accumulate(ref, part, i):
+def _accumulate(ref, part, i, at=(0, 0)):
+    """Sum each row tile's ``part`` into ``ref[at]`` in row-tile order: an
+    SMEM scalar, or (``at=...``) a lane kernel's ``(B, 1)`` VMEM cell."""
     from jax.experimental import pallas as pl
 
     @pl.when(i == 0)
     def _():
-        ref[0, 0] = part
+        ref[at] = part
 
     @pl.when(i > 0)
     def _():
-        ref[0, 0] = ref[0, 0] + part
+        ref[at] = ref[at] + part
 
 
 def _group_io(program, nodes, needed: Set[str]):
@@ -913,9 +1073,9 @@ class _JnpCall:
         return dict(zip(self.out_names, outs))
 
 
-def _build_call(program, unit, needed: Set[str]):
+def _build_call(program, unit, needed: Set[str], lanes: bool = False):
     if unit.kind == "stream":
-        return _StreamCall(program, unit.sp, needed)
+        return _StreamCall(program, unit.sp, needed, lanes=lanes)
     if unit.kind == "block":
         return _BlockCall(program, unit.ops, needed)
     return _JnpCall(program, unit.ops, needed)
@@ -980,9 +1140,12 @@ class _SingleProgram:
     counts traces (Python body executions under jit) and device dispatches
     (calls of the one jitted function) — the one-dispatch guarantee is
     ``dispatches == runs`` with ``traces`` staying at 1 per dtype.
+
+    ``lanes=True`` builds the pure core a batched plan vmaps: its stream
+    passes carry the lane kernel's batching rule (:class:`_StreamCall`).
     """
 
-    def __init__(self, plan):
+    def __init__(self, plan, *, lanes: bool = False):
         program = plan_program(plan)
         groups = plan_groups(plan)
         kernels = _plan_kernels(plan, groups)
@@ -1005,10 +1168,9 @@ class _SingleProgram:
             epi = range(roll.stop, len(units))
         else:
             pro, tmpl, epi = range(len(units)), (), ()
-        self._pro = [_build_call(program, units[i], needed[i]) for i in pro]
-        self._tmpl = [_build_call(program, units[i], needed[i])
-                      for i in tmpl]
-        self._epi = [_build_call(program, units[i], needed[i]) for i in epi]
+        self._pro, self._tmpl, self._epi = (
+            [_build_call(program, units[i], needed[i], lanes) for i in part]
+            for part in (pro, tmpl, epi))
         self.roll = roll
         self.leaf_names = [nd.name for nd in program.leaves()]
         self.out_names = list(program.outputs)
@@ -1125,6 +1287,15 @@ class _SingleProgram:
         outs = self._traced(*[feeds[n] for n in self.leaf_names])
         return dict(zip(self.out_names, outs))
 
+    def batched_passes(self) -> Dict[str, int]:
+        """``{shared | per_lane: stream passes}`` one batched dispatch of
+        this pure core runs, by whether each pass read its streamed
+        operand once for all lanes (the lane kernel, or a pass no lane
+        input reaches) or once per lane, as the last vmapped trace
+        decided."""
+        return pass_counts(self, lambda c: [c.batched_read] if getattr(
+            c, "batched_read", None) else [])
+
     # -- the dispatch ---------------------------------------------------
     def __call__(self, feeds: Dict[str, Any]) -> Dict[str, Any]:
         for leaf in self.leaf_names:
@@ -1174,6 +1345,19 @@ class _SingleProgram:
         diagonal layout has no gather)."""
         text = self._jit.lower(*self.leaf_shapes(dtype)).compile().as_text()
         return hlo_scopes(text)
+
+
+class _PureCore:
+    """:meth:`PallasExecutor.compile_pure`'s callable: a lane-batching
+    program's :meth:`~_SingleProgram.pure`, with its
+    :meth:`~_SingleProgram.batched_passes` beside it."""
+
+    def __init__(self, program: _SingleProgram):
+        self.program = program
+        self.batched_passes = program.batched_passes
+
+    def __call__(self, feeds: Dict[str, Any]) -> Dict[str, Any]:
+        return self.program.pure(feeds)
 
 
 def _tiling_of(call) -> List[str]:
@@ -1266,8 +1450,9 @@ class PallasExecutor(Executor):
                 "mesh-sharded plans have no pure (vmap-composable) core; "
                 "serve/batch them unsharded or run() them directly")
         # the single program's traced core, without the dispatch driver
-        # (donation, counters, its own jit): composable under vmap
-        return _SingleProgram(plan).pure
+        # (donation, counters, its own jit): composable under vmap, where
+        # each stream pass reads a shared operand once for all lanes
+        return _PureCore(_SingleProgram(plan, lanes=True))
 
 
 class PerUnitPallasExecutor(Executor):

@@ -13,6 +13,15 @@ over a leading batch axis:
 * **input leaves are batched** — ``in_axes=0``: each request contributes
   one row of ``b``, ``x0``, ... stacked on a new leading axis.
 
+Under the ``pallas`` backend each stream pass of the pure core reads a
+shared operand once for all lanes: vmapped with its matrix (or diagonal
+layout) unbatched it runs as one lane kernel, ``cello_lanes_<first op>``,
+contracting each tile of ``A`` with every lane's vector; a pass the lane
+kernel does not cover (a per-tile CSR spmv, whose gathered values differ
+per lane) runs ``jax.vmap`` of the single-RHS kernel, one read per lane.
+The counter ``exec.batched_pass`` (``lanes: shared | per_lane``) counts
+each dispatch's passes by which one ran.
+
 The vmapped callable is wrapped in one ``jax.jit``, so a ``run_batch()`` is
 exactly one device dispatch regardless of batch size — the serving-layer
 image of the PR-4 single-program guarantee, and ``stats`` mirrors its
@@ -25,7 +34,9 @@ Numerics: under the ``reference`` backend the vmapped solve matches the
 workloads (``cg_sparse``); dense matvecs lower to a batched contraction
 whose summation order may differ in the last ulps — see
 ``docs/serving.md`` for the measured tolerance policy.  Pallas plans match
-within the tolerances already documented in ``docs/execution_backends.md``.
+within the tolerances already documented in ``docs/execution_backends.md``:
+a lane kernel computes each lane's rows from the same tiles in the same
+order as the single-RHS kernel, and no value crosses lanes.
 """
 from __future__ import annotations
 
@@ -46,6 +57,10 @@ _BP_TRACES = obs.registry().counter(
 _BP_DISPATCHES = obs.registry().counter(
     "serve.batch_dispatches", "BatchedPlan coalesced-batch device "
     "dispatches, per plan (scope label)")
+_BATCHED_PASS = obs.registry().counter(
+    "exec.batched_pass", "stream passes each batched dispatch runs, by "
+    "whether the pass read its streamed operand once for all lanes or once "
+    "per lane (lanes: shared | per_lane), per plan (scope label)")
 _H2D_B = obs.registry().counter(
     "serve.h2d_bytes", "bytes of host (non-device) arrays passed into "
     "batched dispatches, shared and batched leaves, per plan (scope "
@@ -95,6 +110,9 @@ class BatchedPlan:
         self._scope = obs.next_scope("batched")
         self._jit = None        # built lazily: importing jax is deferred
         self._jit_one = None
+        # {shared | per_lane: passes} a dispatch runs, decided as the
+        # batched program traces (empty for a backend without passes)
+        self._passes: Dict[str, int] = {}
 
     @property
     def stats(self) -> Dict[str, int]:
@@ -116,8 +134,15 @@ class BatchedPlan:
     def _build(self):
         import jax
         vmapped = jax.vmap(self._one, in_axes=(None, 0))
+        passes = getattr(self._single, "batched_passes", dict)
+
+        def traced(shared_vals, batched_vals):
+            out = vmapped(shared_vals, batched_vals)
+            self._passes = passes()
+            return out
+
         kwargs = {"donate_argnums": (1,)} if self.donate else {}
-        return jax.jit(vmapped, **kwargs)
+        return jax.jit(traced, **kwargs)
 
     # -- execution -------------------------------------------------------
     def run_batch(self, feeds: Mapping[str, Any]) -> Dict[str, Any]:
@@ -174,6 +199,9 @@ class BatchedPlan:
             # dispatch itself
             faults.check("serve.dispatch", backend=self.backend)
             out = dict(self._jit(shared_vals, batched_vals))
+        for lanes, n in self._passes.items():
+            _BATCHED_PASS.inc(n, backend=self.backend, lanes=lanes,
+                              scope=self._scope)
         _LAUNCH_S.observe(time.perf_counter() - t0, backend=self.backend,
                           scope=self._scope,
                           traced=self.stats["traces"] != traces)

@@ -447,7 +447,7 @@ class TestFacade:
                        "codesign.search_s", "codesign.points",
                        "codesign.cache.hits", "codesign.cache.misses",
                        "exec.compile_s", "exec.dispatch_s",
-                       "exec.spmv_layout",
+                       "exec.spmv_layout", "exec.batched_pass",
                        "serve.requests", "serve.e2e_latency_s"):
             assert needed in names
         with pytest.raises(TypeError):

@@ -70,13 +70,16 @@ def _shapes(program, names, sharding, batch=None):
 def _assert_every_kernel_compiled(prog, text):
     """Each stream/block unit the program traces is a Mosaic custom call
     (kernels are named ``cello_<kind>_<first op>``, ``cello_wide_`` for a
-    column-blocked pass, ``vmap_``-prefixed when batched).  A pass whose values nothing reads emits no kernel."""
+    column-blocked pass, ``cello_lanes_`` for a batched pass that read its
+    operand once for all lanes, ``vmap_``-prefixed when batched).  A pass
+    whose values nothing reads emits no kernel."""
     from repro.exec.pallas import _BlockCall, _StreamCall
     calls = {m for line in text.splitlines() if "tpu_custom_call" in line
              for m in re.findall(r"%(?:vmap_)?(cello_\w+?)_?(?:\.\d+)? = ",
                                  line)}
     units = (*prog._pro, *prog._tmpl, *prog._epi)
-    want = {c.kernel_name for c in units
+    want = {c.lanes_kernel_name if c.lanes and c.batched_read == "shared"
+            else c.kernel_name for c in units
             if isinstance(c, _StreamCall) and (c.red_out or c.stream_out)}
     want |= {"cello_block_" + c.nodes[0].name for c in units
              if isinstance(c, _BlockCall)}
@@ -170,15 +173,35 @@ def test_batched_core_b8_compiles(topo, mosaic):
     one = SingleDeviceSharding(topo.devices[0])
     shared = _shapes(traced.program, bp.shared_leaves, one)
     batched = _shapes(traced.program, bp.batched_leaves, one, batch=8)
-    compiled = bp._build().lower(shared, batched).compile()
-    prog = get_backend("pallas").compile(plan)      # same units, unbatched
-    _assert_every_kernel_compiled(prog, compiled.as_text())
+    text = bp._build().lower(shared, batched).compile().as_text()
+    # every pass reads the operand once for all 8 lanes
+    prog = bp._single.program
+    assert set(prog.batched_passes()) == {"shared"}
+    _assert_every_kernel_compiled(prog, text)
+    assert "cello_lanes_Ap" in text and "cello_stream_" not in text
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 4, 8])
+def test_batched_dense_core_served_widths_compile(topo, mosaic, lanes):
+    """The served n=8192 plan at each padded batch width: one lane kernel
+    per pass, within the scoped VMEM limit."""
+    from jax.sharding import SingleDeviceSharding
+    traced, plan = _plan("cg", n=8192, iters=32)
+    bp = plan.batched()
+    one = SingleDeviceSharding(topo.devices[0])
+    shared = _shapes(traced.program, bp.shared_leaves, one)
+    batched = _shapes(traced.program, bp.batched_leaves, one, batch=lanes)
+    text = bp._build().lower(shared, batched).compile().as_text()
+    prog = bp._single.program
+    assert prog.batched_passes() == {"shared": 96}
+    _assert_every_kernel_compiled(prog, text)
+    assert "cello_stream_" not in text
 
 
 def test_batched_sparse_core_b8_compiles(topo, mosaic):
     """A batch of right-hand sides against one Laplacian: the diagonal
-    layout is built once, unbatched, and the vmapped spmv kernels share
-    it."""
+    layout is built once, unbatched, and each lane kernel reads it once
+    for all lanes."""
     from jax.sharding import SingleDeviceSharding
     traced, plan = _plan("cg_sparse", n=65536, iters=8)
     bp = plan.batched()
@@ -186,8 +209,9 @@ def test_batched_sparse_core_b8_compiles(topo, mosaic):
     shared = _shapes(traced.program, bp.shared_leaves, one)
     batched = _shapes(traced.program, bp.batched_leaves, one, batch=8)
     text = bp._build().lower(shared, batched).compile().as_text()
-    prog = get_backend("pallas").compile(plan)
+    prog = bp._single.program
     assert prog.spmv_layouts == {"dia": 9}
+    assert set(prog.batched_passes()) == {"shared"}
     _assert_every_kernel_compiled(prog, text)
     assert "%cello_dia_A" in text and "vmap_cello_dia" not in text
 
